@@ -484,16 +484,22 @@ def main(argv=None) -> int:
             cmd.append("--multitenancy")
         if args.overrides:
             cmd.append(f"--overrides.path={args.overrides}")
-        env = {**os.environ, "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
+        # the environment passes through untouched: the server child is
+        # the one process that touches jax, on whatever device jax finds
+        # (it refuses to start on no chip unless JAX_PLATFORMS=cpu)
+        env = dict(os.environ)
         if args.chaos:
             env["TEMPO_CHAOS"] = args.chaos
         proc = subprocess.Popen(cmd, env=env)
         target = f"http://127.0.0.1:{port}"
-        for _ in range(100):
+        for _ in range(600):
+            if proc.poll() is not None:
+                sys.exit(f"soak: self-hosted server exited {proc.returncode} "
+                         "before becoming ready")
             try:
                 urllib.request.urlopen(target + "/ready", timeout=1)
                 break
-            except Exception:
+            except OSError:
                 time.sleep(0.2)
 
     # vulture sidecar: black-box probes of every read path WHILE the
@@ -526,12 +532,20 @@ def main(argv=None) -> int:
         vthread.start()
 
     try:
+        dev = None
+        if proc is not None:
+            with urllib.request.urlopen(target + "/status/kernels") as r:
+                dev = json.load(r)["device"]
+            print(f"soak: self-hosted server on platform={dev['platform']} "
+                  f"device_kind={dev['device_kind']!r} count={dev['count']}",
+                  file=sys.stderr)
         soak = Soak(target, args.writers, args.readers, tenants=tenants,
                     zipf=args.zipf, live_tail=args.live_tail,
                     query_target=args.query_target,
                     repeat_zipf=args.repeat_zipf)
         report = soak.run(args.duration, max_write_p95_s=args.write_p95,
                           max_search_p95_s=args.search_p95)
+        report["device"] = dev  # None = remote target, not asked
         if vult is not None:
             vstop.set()
             vthread.join(timeout=30)

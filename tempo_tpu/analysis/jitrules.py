@@ -9,7 +9,7 @@ runtime test can see breaking until a production trace does:
     TempoKernelCompileStorm alert pages on, after the fact);
   * jitted bodies must not synchronize with the host: one `.item()` in
     a kernel turns an async dispatch into a blocking round trip per
-    call, which on a high-latency link erases the batching win;
+    call, which erases the batching win;
   * jitted bodies trace with jnp; stray `np.` calls either break the
     trace or silently constant-fold a value that should be dynamic.
 

@@ -32,15 +32,13 @@ def _cut_kernels():
     """The device block-cut kernel module (ops/blockcut) when the cut
     router picks the device engine, else None -- the host code inline
     below IS each kernel's registered twin, so both paths are
-    bit-identical. Lazy so block/ imports without jax."""
+    bit-identical. Lazy so block/ imports without jax; a jax-less
+    install is the only failure that means "cut on the host"."""
     try:
         from ..ops import blockcut
-
-        if blockcut.cut_engine() == "device":
-            return blockcut
-    except Exception:
-        pass
-    return None
+    except ImportError:
+        return None
+    return blockcut if blockcut.cut_engine() == "device" else None
 
 
 def _attr_row(dictb: DictBuilder, value) -> tuple[int, int, int, float, int, float]:

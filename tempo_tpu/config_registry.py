@@ -90,9 +90,6 @@ KNOBS: dict[str, tuple[str, str, str]] = {
         "bool", "1",
         "copy untouched blocks' compressed bytes verbatim during "
         "compaction (0 = always re-encode)"),
-    "TEMPO_COMPILE_CACHE_DIR": (
-        "path", "",
-        "persistent XLA compile cache directory ('' = in-memory only)"),
     "TEMPO_COSTMODEL": (
         "bool", "1", "per-(op, bucket) device cost capture (0 = off)"),
     "TEMPO_COSTMODEL_MEMORY": (

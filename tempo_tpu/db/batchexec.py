@@ -245,10 +245,26 @@ def _run_search_group(key, items: list, mesh_fn=None) -> list:
         return [_seq_or_exc(items[0])]
     try:
         return _run_search_group_fused(items, mesh_fn)
-    except Exception:
+    except Exception as e:
         TEL.record_routing("search_batch", "fallback", "fused_error",
                            n=len(items))
+        _log_fused_error("search", e)
         return [_seq_or_exc(it) for it in items]
+
+
+def _log_fused_error(kind: str, e: Exception) -> None:
+    """A fused launch that fails (a compile refusal included) still
+    degrades to per-item execution, but never silently: the error and
+    its traceback are logged, each distinct error once per log window
+    (the error text is the log shim's repeat key; repeats are counted)."""
+    import traceback
+
+    from ..util.log import get_logger
+
+    get_logger("batchexec").error(
+        f"fused {kind} launch failed, running items one by one: "
+        f"{type(e).__name__}: {e}",
+        traceback="".join(traceback.format_exception(e)))
 
 
 def _seq_or_exc(it: _SearchItem):
@@ -420,9 +436,10 @@ def _run_find_group(key, items: list) -> list:
         return [_find_seq_or_exc(items[0])]
     try:
         return _run_find_group_fused(items)
-    except Exception:
+    except Exception as e:
         TEL.record_routing("find_batch", "fallback", "fused_error",
                            n=len(items))
+        _log_fused_error("find", e)
         return [_find_seq_or_exc(it) for it in items]
 
 

@@ -2,8 +2,10 @@
 
 `python -m tempo_tpu.fleet.harness --out FLEET_SCALE.json` builds the
 N-frontend x M-querier x K-ingester topology as real OS processes over
-gossip membership (the way dryrun_multichip emits MULTICHIP.json) and
-runs two certifications:
+gossip membership and runs two certifications. It is a CPU harness:
+every role is started with JAX_PLATFORMS=cpu, because several
+kernel-launching roles share this host and a chip belongs to one
+process (on a chip host the operator gives the chip to one role).
 
 1. **QPS scaling 1 -> 4 queriers.**  Every querier worker runs at
    concurrency 1 and every search job carries chaos-injected replica

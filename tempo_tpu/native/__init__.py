@@ -28,9 +28,9 @@ def _load():
         return _LIB
     _TRIED = True
     if not os.path.exists(_SO):
-        try:  # one silent build attempt; fallbacks cover failure
+        try:  # one build attempt; fallbacks cover failure
             subprocess.run(["make", "-C", _NATIVE_DIR], capture_output=True, timeout=120)
-        except Exception:
+        except (OSError, subprocess.SubprocessError):
             pass
     for attempt in (0, 1):
         if not os.path.exists(_SO):
@@ -47,8 +47,15 @@ def _load():
             try:
                 subprocess.run(["make", "-C", _NATIVE_DIR, "-B"],
                                capture_output=True, timeout=120)
-            except Exception:
+            except (OSError, subprocess.SubprocessError):
                 break
+    if _LIB is None:
+        from ..util.log import get_logger
+
+        get_logger("native").warning(
+            "native library %s unavailable (build it with `make -C native`): "
+            "codecs, hashing and WAL scans run on the pure-Python fallbacks",
+            os.path.normpath(_SO))
     return _LIB
 
 
@@ -158,6 +165,13 @@ def _bind(lib):
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> dict:
+    """Which codec path this process runs, for the status JSON: the
+    native library and the file it was loaded from, or the fallbacks."""
+    ok = available()
+    return {"available": ok, "path": os.path.normpath(_SO) if ok else ""}
 
 
 # -------------------------------------------------------------- ring tokens

@@ -11,13 +11,13 @@ from __future__ import annotations
 
 import numpy as np
 
-# persistent XLA compilation cache (TEMPO_COMPILE_CACHE_DIR): enabled at
-# import of THE module every kernel imports, so it covers the first
-# compile of any entry point (app, CLI, bench, tests) that honors the
-# env var. A no-op when the var is unset or the app already enabled it.
-from ..util.costmodel import maybe_enable_compile_cache_from_env
+# persistent XLA compilation cache: enabled at import of THE module
+# every kernel imports, so it covers the first compile of any entry
+# point (app, CLI, bench, tests) -- in JAX_COMPILATION_CACHE_DIR when
+# set, else <checkout>/.jax_cache. A no-op once the app enabled it.
+from ..util.costmodel import enable_default_compile_cache
 
-maybe_enable_compile_cache_from_env()
+enable_default_compile_cache()
 
 MIN_BUCKET = 1024
 PAD_I32 = np.int32(-(2**31))  # sentinel for code/int columns (never a valid code)

@@ -77,7 +77,7 @@ def _lookup_blocks_kernel(ids: jnp.ndarray, queries: jnp.ndarray, n_valid: jnp.n
 def _device_ids(blk) -> tuple[jnp.ndarray, int]:
     """Padded (T,4) device copy of a block's sorted id codes, cached on
     the (immutable) block object: repeated finds skip the host->device
-    upload, which dominates per-lookup latency on a high-latency link."""
+    upload, which would otherwise dominate per-lookup latency."""
     cached = getattr(blk, "_dev_ids", None)
     a = blk.trace_index["trace.id_codes"]
     n = int(a.shape[0])
@@ -103,10 +103,10 @@ def _ids_void(blk) -> np.ndarray:
 
 def lookup_ids_blocks_host(blocks: list, query_codes: np.ndarray) -> np.ndarray:
     """Host engine: ONE vectorized searchsorted per block over the void16
-    id index. O(Q log T) with zero device round trips -- on a single chip
-    behind a high-latency link this beats the kernel by the full
-    dispatch+fetch RTT; the device kernel's value is mesh sharding
-    (parallel/find.py) and fused multi-block batches at scale."""
+    id index. O(Q log T) with zero device round trips -- on a single
+    chip this beats the kernel by the full dispatch+fetch RTT; the device
+    kernel's value is mesh sharding (parallel/find.py) and fused
+    multi-block batches at scale."""
     B, q = len(blocks), query_codes.shape[0]
     out = np.full((B, q), -1, dtype=np.int32)
     if B == 0 or q == 0:

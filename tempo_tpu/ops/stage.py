@@ -437,7 +437,7 @@ def upload_stage(blk: BackendBlock, plan: StagePlan, staged: StagedBlock,
 
     t0_wall = _time.time()
     # ONE batched transfer for the whole block: per-array device_puts
-    # each pay a full link round trip on a high-latency tunnel
+    # each pay their own dispatch + link round trip
     staged.cols = dict(zip(padded, jax.device_put(list(padded.values()))))
     # telemetry: upload volume + padding waste (padded vs real rows
     # summed per column -- columns live on different axes)

@@ -22,13 +22,8 @@ def smap(f, mesh, in_specs, out_specs):
     """shard_map with the varying-axes check off: our kernels mix
     replicated operands (queries, predicate operands) with device-varying
     shards inside fori_loops, which the strict vma check rejects."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    except (TypeError, AttributeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _factor(n: int) -> tuple[int, int]:

@@ -15,6 +15,8 @@ import argparse
 import json
 import os
 import re
+import signal
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -105,9 +107,10 @@ class AppConfig:
     # querier.search.external_endpoints, querier.go:401-458)
     search_external_endpoints: str = ""
     search_external_hedge_after_s: float = 4.0
-    # persistent XLA compilation cache dir ("" = TEMPO_COMPILE_CACHE_DIR
-    # env, or off): restarts deserialize compiled kernels from disk
-    # instead of re-paying the first-compile storm (util/costmodel)
+    # persistent XLA compilation cache dir for config-file deployments;
+    # applies only while JAX_COMPILATION_CACHE_DIR is unset ("" = the
+    # fixed <checkout>/.jax_cache): restarts deserialize compiled
+    # kernels from disk instead of re-paying the first-compile storm
     compile_cache_dir: str = ""
     # measured-crossover CostLedger artifact ("" = TEMPO_COST_LEDGER
     # env, else <storage_path>/cost_ledger.json): find/live-search/
@@ -177,15 +180,23 @@ class App:
                 "peers to reach it"
             )
         # device cost plane wiring BEFORE the first TempoDB (it seeds
-        # routing from the ledger at init): persistent compile cache +
-        # the measured-crossover CostLedger artifact. Explicit env vars
-        # win over the storage-path default -- the operator aimed them.
+        # routing from the ledger at init): the device this process
+        # got, persistent compile cache + the measured-crossover
+        # CostLedger artifact. Explicit env vars win over the
+        # storage-path default -- the operator aimed them.
         from ..util import costledger, costmodel
 
+        # every target but the distributor launches kernels (block cut,
+        # live engine, search/find/metrics, compaction bloom, generator
+        # reduce): resolve the backend now so a missing chip stops the
+        # start instead of serving from the host unannounced
+        self.device = (costmodel.resolve_device()
+                       if cfg.target != "distributor"
+                       else costmodel.device_identity())
         if cfg.compile_cache_dir:
             costmodel.enable_compile_cache(cfg.compile_cache_dir)
         else:
-            costmodel.maybe_enable_compile_cache_from_env()
+            costmodel.enable_default_compile_cache()
         if not os.environ.get(costledger.LEDGER_ENV, ""):
             costledger.configure(
                 cfg.cost_ledger_path
@@ -1219,6 +1230,18 @@ def _kernel_status(app: App) -> dict:
     from ..util.kerneltel import TEL
 
     out = TEL.snapshot()
+    # which device and which codec path this process actually runs on:
+    # a run whose numbers do not say is unusable (ROADMAP A0/A3)
+    from .. import native
+    from ..util import costmodel
+    from ..util.linkcost import measured_link_rtt_ms
+
+    out["device"] = {**app.device,
+                     "peaks": costmodel.DEVICE_PEAKS.get(
+                         app.device["device_kind"], "unknown"),
+                     "link_rtt_ms": measured_link_rtt_ms()}
+    out["native"] = native.status()
+    out["compile_cache"] = costmodel.compile_cache_stats()
     out["staged_cache"] = staged_cache_stats()
     out["staged_cache"]["budget_note"] = (
         "device HBM budget for staged block columns (ops/stage)")
@@ -1553,8 +1576,9 @@ def main(argv=None):
                     help="tenant the app's own query timelines ship into "
                          "('' = off); inspect with tempo-cli self-trace")
     ap.add_argument("--compile-cache.dir", dest="compile_cache_dir", default=None,
-                    help="persistent XLA compilation cache directory "
-                         "(default: TEMPO_COMPILE_CACHE_DIR env, else off)")
+                    help="persistent XLA compilation cache directory; "
+                         "JAX_COMPILATION_CACHE_DIR wins when set "
+                         "(default: <checkout>/.jax_cache)")
     ap.add_argument("--cost-ledger.path", dest="cost_ledger_path", default=None,
                     help="measured-crossover CostLedger artifact (default: "
                          "TEMPO_COST_LEDGER env, else "
@@ -1624,9 +1648,21 @@ def main(argv=None):
     cfg = AppConfig(**base)
     if not cfg.advertise_addr:
         cfg.advertise_addr = f"http://127.0.0.1:{cfg.http_port}"
-    app = App(cfg)
+    from ..util.costmodel import NoAcceleratorError
+
+    try:
+        app = App(cfg)
+    except NoAcceleratorError as e:
+        sys.exit(f"tempo-tpu target={cfg.target}: {e}")
     app.start()
-    print(f"tempo-tpu target={cfg.target} listening on :{cfg.http_port}")
+    dev = app.device
+    print(f"tempo-tpu target={cfg.target} listening on :{cfg.http_port} "
+          f"device={dev['platform']} kind={dev['device_kind']!r} "
+          f"count={dev['count']}", flush=True)
+    # SIGTERM is how supervisors stop the process: drain like ^C and
+    # exit 0, so the chip is released for the next process
+    signal.signal(signal.SIGTERM, lambda *_: threading.Thread(
+        target=app.stop, daemon=True).start())
     try:
         app.serve_http()
     except KeyboardInterrupt:

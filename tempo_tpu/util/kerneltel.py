@@ -13,9 +13,9 @@ ops/ and parallel/:
     operational signal (evictions mean 256+ live program shapes).
   * per-op device wall-time histograms. When sync timing is on the
     observer calls block_until_ready, so the histogram records true
-    device time rather than Python dispatch; on a high-latency link that
-    sync would cost a full RTT per kernel, so the default follows the
-    measured link (util/linkcost): sync when RTT <= SYNC_RTT_MS,
+    device time rather than Python dispatch; the sync costs a link
+    round trip per kernel, so the default follows the measured link
+    (util/linkcost): sync when RTT <= SYNC_RTT_MS,
     dispatch-only otherwise. TEMPO_KERNELTEL_SYNC=0|1 overrides.
   * host->device transfer bytes + padding-waste rows per staging call
     (ops/stage), plus staged-cache hit/miss counters.
@@ -398,12 +398,9 @@ class KernelTelemetry:
             if env in ("0", "1"):
                 self._sync = env == "1"
             else:
-                try:
-                    from .linkcost import link_rtt_ms
+                from .linkcost import link_rtt_ms
 
-                    self._sync = link_rtt_ms() <= SYNC_RTT_MS
-                except Exception:
-                    self._sync = False
+                self._sync = link_rtt_ms() <= SYNC_RTT_MS
         return self._sync
 
     # ----------------------------------------------------------- kernels

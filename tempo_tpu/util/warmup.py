@@ -8,16 +8,15 @@ started with `--warmup.shapes` replays that corpus through registered
 warmup builders BEFORE serving: each builder compiles a canonical
 program of that op at that bucket, which (a) populates the in-process
 jit caches and (b) pulls the persistent XLA compilation cache
-(TEMPO_COMPILE_CACHE_DIR) off disk ahead of the first query, so the
-first-query p99 stops paying the compile storm.
+(JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache) off disk ahead
+of the first query, so the first-query p99 stops paying the compile
+storm.
 
 Builders are canonical, not exhaustive: the filter builder compiles a
 single-predicate program per row bucket -- real queries with other
 tree shapes still compile on first use, but the dominant storm (the
 per-bucket base programs, and with the disk cache every previously
-seen program) is paid before the listen socket opens. The
-`first_query_compile_p99_ms` bench row carries a warmup-on leg
-measuring exactly this.
+seen program) is paid before the listen socket opens.
 """
 
 from __future__ import annotations

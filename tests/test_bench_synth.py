@@ -7,14 +7,13 @@ from pathlib import Path
 
 import numpy as np
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-from bench import synth_block  # noqa: E402
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))  # bench.py
 
-from tempo_tpu.backend import MemBackend
-from tempo_tpu.block import build_block_from_traces
-from tempo_tpu.block.reader import BackendBlock, open_block
-from tempo_tpu.db.search import SearchRequest, search_block
-from tempo_tpu.util.testdata import make_traces
+from tempo_tpu.backend import MemBackend  # noqa: E402
+from tempo_tpu.block import build_block_from_traces  # noqa: E402
+from tempo_tpu.block.reader import BackendBlock, open_block  # noqa: E402
+from tempo_tpu.db.search import SearchRequest, search_block  # noqa: E402
+from tempo_tpu.util.testdata import make_traces, synth_block  # noqa: E402
 
 
 def test_synth_block_matches_builder_columns():

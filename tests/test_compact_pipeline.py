@@ -20,6 +20,7 @@ max_block_bytes must cut the batch on its own, never batch with more.
 from __future__ import annotations
 
 import shutil
+import threading
 
 import pytest
 
@@ -183,6 +184,9 @@ def test_pipeline_on_zstd_shim_and_without_native(tmp_path, monkeypatch):
     from tempo_tpu.util import zstdshim
 
     monkeypatch.setattr(colio, "zstandard", zstdshim)
+    # this thread may hold a real-zstd decompressor cached by an earlier
+    # test: it must not read the shim's frames
+    monkeypatch.setattr(colio, "_DCTX_LOCAL", threading.local())
     monkeypatch.setattr(dictionary, "zstandard", zstdshim)
     monkeypatch.setattr(native, "_LIB", None)
     monkeypatch.setattr(native, "_TRIED", True)

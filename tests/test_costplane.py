@@ -533,16 +533,17 @@ def test_status_cost_endpoint_and_metrics_families(tmp_path):
         db.close()
 
 
-def test_compile_cache_counts_disk_hits(tmp_path):
-    """TEMPO_COMPILE_CACHE_DIR: enabling the persistent cache registers
-    the jax.monitoring listener; clearing the in-process jit caches and
-    re-running the same program must deserialize from disk and count a
-    hit -- the counter that splits restart-warm compiles from fresh
-    XLA work."""
+def test_compile_cache_counts_disk_hits(tmp_path, monkeypatch):
+    """Enabling the persistent cache registers the jax.monitoring
+    listener; clearing the in-process jit caches and re-running the
+    same program must deserialize from disk and count a hit -- the
+    counter that splits restart-warm compiles from fresh XLA work."""
     import jax
 
     from tempo_tpu.util import costmodel
 
+    monkeypatch.delenv(costmodel.JAX_CACHE_ENV, raising=False)
+    jax.config.update("jax_enable_compilation_cache", True)  # off in tests
     assert costmodel.enable_compile_cache(str(tmp_path / "cc"))
     try:
         h0 = costmodel.compile_cache_stats()["disk_hits"]
@@ -560,5 +561,6 @@ def test_compile_cache_counts_disk_hits(tmp_path):
     finally:
         # tmp_path is reaped: the rest of the suite must not keep
         # reading a vanishing cache dir
+        jax.config.update("jax_enable_compilation_cache", False)
         costmodel.disable_compile_cache()
         assert not costmodel.compile_cache_stats()["enabled"]

@@ -3,7 +3,9 @@ separate OS processes over a shared ring-KV directory and storage path.
 
 The analog of the reference's TestMicroservicesWithKVStores
 (integration/e2e/e2e_test.go:130) -- real process boundaries, HTTP
-data plane, file-KV control plane.
+data plane, file-KV control plane. A CPU harness: every role runs with
+JAX_PLATFORMS=cpu, since several kernel-launching roles share the host
+and a chip belongs to one process.
 """
 
 import json

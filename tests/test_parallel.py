@@ -399,9 +399,9 @@ def test_graft_dryrun_scale_shape(capsys):
 
 
 def test_graft_dryrun_subprocess_fallback(monkeypatch):
-    """When the in-process virtual-device switch is impossible (private
-    jax API moved), the dryrun still runs via a fresh subprocess
-    configured purely through public env vars."""
+    """When the CPU backend (asked for explicitly) has fewer devices
+    than the dryrun wants, it re-runs in a fresh subprocess with that
+    many virtual CPU devices."""
     import sys
     from pathlib import Path
 
@@ -413,6 +413,28 @@ def test_graft_dryrun_subprocess_fallback(monkeypatch):
         graft.dryrun_multichip(8, scale=False)  # --no-scale flag plumbing
     finally:
         sys.path.pop(0)
+
+
+def test_graft_dryrun_refuses_to_leave_the_chips(monkeypatch):
+    """Asking an accelerator backend for more devices than it has is an
+    error, and the CPU route is taken only when the CPU was asked for:
+    the dryrun never quietly swaps real chips for virtual devices."""
+    import sys
+    from pathlib import Path
+
+    import jax
+    import pytest
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    try:
+        import __graft_entry__ as graft
+    finally:
+        sys.path.pop(0)
+    assert graft._force_virtual_devices(len(jax.devices())) is True
+    assert graft._force_virtual_devices(len(jax.devices()) + 1) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="tpu backend has"):
+        graft._force_virtual_devices(len(jax.devices()) + 1)
 
 
 def test_graft_entry_compiles():
