@@ -1,0 +1,8 @@
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+# the tests read traces through jax.profiler: the CPU backend, never a chip
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
