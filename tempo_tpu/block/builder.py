@@ -317,8 +317,15 @@ class BlockBuilder:
         n_spans = len(self.sp_trace_sid)
         n_traces = len(self.tr_ids)
         dictionary, remap = self.dictb.finalize()
+        from ..util.kerneltel import TEL
+
         kern = _cut_kernels()
-        rm_arr = kern.remap_codes_device if kern is not None else apply_remap
+        remap_fn = kern.remap_codes_device if kern is not None else apply_remap
+
+        def rm_arr(col, remap):  # one stage per code column (~30 a block)
+            with TEL.stage("cut:remap", rows=len(col)):
+                return remap_fn(col, remap)
+
         rm = lambda lst: rm_arr(np.asarray(lst, dtype=np.int32), remap)  # noqa: E731
 
         start_ns = np.asarray(self.sp_start_ns, dtype=np.uint64)
@@ -409,7 +416,9 @@ class BlockBuilder:
             tcols[f"{prefix}.str_id"] = rm_arr(tcols[f"{prefix}.str_id"], remap)
             cols.update(tcols)
 
-        axes, col_axis, row_groups = self._compute_row_groups(cols, start_ms, dur_us, kern)
+        with TEL.stage("cut:rowgroups", spans=n_spans):
+            axes, col_axis, row_groups = self._compute_row_groups(
+                cols, start_ms, dur_us, kern)
 
         m = self.meta
         m.total_traces = n_traces
@@ -426,11 +435,12 @@ class BlockBuilder:
                 bloom = ShardedBloom.for_estimated_items(max(self.estimated_traces, n_traces))
             else:
                 bloom = ShardedBloom.for_estimated_items(max(n_traces, 1))
-            if kern is not None and self.tr_ids:
-                bloom.words = kern.bloom_bits_device(bloom.words, self.tr_ids,
-                                                     bloom.shard_bits)
-            else:
-                bloom.add_many(self.tr_ids)
+            with TEL.stage("cut:bloom", traces=n_traces):
+                if kern is not None and self.tr_ids:
+                    bloom.words = kern.bloom_bits_device(bloom.words, self.tr_ids,
+                                                         bloom.shard_bits)
+                else:
+                    bloom.add_many(self.tr_ids)
         m.bloom_shards = bloom.n_shards
         m.bloom_shard_bits = bloom.shard_bits
 
@@ -655,7 +665,12 @@ def build_block_from_traces(
     compaction_level: int = 0,
     codec: str = "zstd",
 ) -> BlockMeta:
+    from ..util.kerneltel import TEL
+
     b = BlockBuilder(tenant, block_id, row_group_spans, compaction_level=compaction_level)
-    for tid, t in sorted(traces, key=lambda p: p[0]):
-        b.add_trace(tid, t)
-    return write_block(backend, b.finalize(), codec=codec)
+    with TEL.stage("cut:build", traces=len(traces)):
+        for tid, t in sorted(traces, key=lambda p: p[0]):
+            b.add_trace(tid, t)
+    fin = b.finalize()
+    with TEL.stage("cut:write", spans=fin.meta.total_spans):
+        return write_block(backend, fin, codec=codec)

@@ -874,12 +874,10 @@ class ColumnPack:
         from ..util.kerneltel import TEL
 
         t_run = _time.perf_counter()
-        t0 = t_run
-        self.fetch_ranges(cf)
-        TEL.record_stream_stage("fetch", _time.perf_counter() - t0)
-        t0 = _time.perf_counter()
-        self.decode_fetched(cf)
-        TEL.record_stream_stage("decompress", _time.perf_counter() - t0)
+        with TEL.stage("stream:fetch"):
+            self.fetch_ranges(cf)
+        with TEL.stage("stream:decompress"):
+            self.decode_fetched(cf)
         TEL.record_stream_run(_time.perf_counter() - t_run)
 
     # ------------------------------------------------- staged cold reads

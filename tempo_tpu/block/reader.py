@@ -252,7 +252,17 @@ class BackendBlock:
 
     def materialize_traces(self, sids: list[int]) -> list[Trace]:
         """Reconstruct full wire traces for the given trace indexes,
-        reading only the row-group chunks that cover their span rows."""
+        reading only the row-group chunks that cover their span rows.
+        The one site that serves search verify, find and metrics."""
+        from ..util.kerneltel import TEL
+
+        with TEL.stage("rows:materialize", rows=len(sids),
+                       block=self.meta.block_id[:8]) as st:
+            out = self._materialize_traces(sids)
+            st.attrs["spans"] = sum(t.span_count() for t in out if t is not None)
+        return out
+
+    def _materialize_traces(self, sids: list[int]) -> list[Trace]:
         span_off = self.trace_index["trace.span_off"]
         d = self.dictionary
         _, _, _, scope_name, scope_version = self._res_tables

@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -150,31 +149,31 @@ class BatchExecutor:
     def _lead(self, key, g: _Group) -> None:
         from ..util.kerneltel import TEL
 
-        t0 = time.monotonic()
-        t0_wall = time.time()
-        # lone-query fast path: only hold the window open when another
-        # SUBMITTER is inside the executor (each counts once in
-        # _inflight no matter how many items it carries; the leader
-        # itself is one). Purely sequential traffic therefore never
-        # pays the window; a concurrent burst's stragglers group with
-        # each other while the first arrival's launch is in flight.
-        if self.window_s > 0:
-            with self._lock:
-                others = self._inflight > 1
-            if others:
-                g.full.wait(self.window_s)
-        with self._lock:
-            g.closed = True
-            if self._groups.get(key) is g:
-                del self._groups[key]
-            items = list(g.items)
-        wait_s = time.monotonic() - t0
         # timeline: the admission window this leader held open (zero-
         # length on the lone-query fast path), with its final occupancy
-        TEL.child_span("batch-window", t0_wall, t0_wall + wait_s,
-                       {"executor": self.name, "occupancy": len(items)})
+        with TEL.stage("batch-window", executor=self.name) as win:
+            # lone-query fast path: only hold the window open when another
+            # SUBMITTER is inside the executor (each counts once in
+            # _inflight no matter how many items it carries; the leader
+            # itself is one). Purely sequential traffic therefore never
+            # pays the window; a concurrent burst's stragglers group with
+            # each other while the first arrival's launch is in flight.
+            if self.window_s > 0:
+                with self._lock:
+                    others = self._inflight > 1
+                if others:
+                    g.full.wait(self.window_s)
+            with self._lock:
+                g.closed = True
+                if self._groups.get(key) is g:
+                    del self._groups[key]
+                items = list(g.items)
+            win.attrs["occupancy"] = len(items)
+        wait_s = win.seconds
         try:
-            results = self.runner(key, items)
+            with TEL.stage("batch:launch", executor=self.name,
+                           occupancy=len(items)):
+                results = self.runner(key, items)
             if not isinstance(results, list) or len(results) != len(items):
                 raise RuntimeError(
                     f"batch runner returned {len(results) if isinstance(results, list) else type(results)} "
@@ -282,7 +281,6 @@ def _mesh_batch_enabled() -> bool:
 
 
 def _run_search_group_fused(items: list, mesh_fn=None) -> list:
-    import time as _time
 
     from ..ops.multiquery import (
         _p2,
@@ -300,41 +298,39 @@ def _run_search_group_fused(items: list, mesh_fn=None) -> list:
     shape = items[0].lowered.shape
     q_b = _p2(len(items), lo=1)
     io0 = blk.pack.bytes_read
-    t0w = _time.time()
-    staged = stage_block(blk, items[0].needed + ["trace.start_ms"],
-                         groups=items[0].groups_range)
-    if mq_bytes_estimate(shape, q_b, staged.n_spans_b) > _mq_budget_bytes():
-        TEL.record_routing("search_batch", "fallback", "mq_budget",
-                           n=len(items))
-        return [_seq_or_exc(it) for it in items]
-    progs = pack_queries([it.lowered for it in items], q_b)
-    lowered = [it.lowered for it in items]
-    # >1 chip attached: the window leader lowers the whole group to ONE
-    # Q-programs x sharded-rows mesh launch (parallel/multiquery), so
-    # the admission window amortizes across every chip instead of
-    # competing with sp-sharding for the executor. Shape-ineligible
-    # buckets and TEMPO_MESH_BATCH=0 keep the single-chip fused launch.
-    mesh = mesh_fn() if mesh_fn is not None else None
-    engine = "device"
-    if mesh is not None and _mesh_batch_enabled():
-        from ..parallel.multiquery import mesh_batch_eligible, mesh_eval_multiquery
+    with TEL.stage("batch:eval", block=blk.meta.block_id[:8],
+                   occupancy=len(items)) as st:
+        staged = stage_block(blk, items[0].needed + ["trace.start_ms"],
+                             groups=items[0].groups_range)
+        if mq_bytes_estimate(shape, q_b, staged.n_spans_b) > _mq_budget_bytes():
+            TEL.record_routing("search_batch", "fallback", "mq_budget",
+                               n=len(items))
+            return [_seq_or_exc(it) for it in items]
+        progs = pack_queries([it.lowered for it in items], q_b)
+        lowered = [it.lowered for it in items]
+        # >1 chip attached: the window leader lowers the whole group to ONE
+        # Q-programs x sharded-rows mesh launch (parallel/multiquery), so
+        # the admission window amortizes across every chip instead of
+        # competing with sp-sharding for the executor. Shape-ineligible
+        # buckets and TEMPO_MESH_BATCH=0 keep the single-chip fused launch.
+        mesh = mesh_fn() if mesh_fn is not None else None
+        engine = "device"
+        if mesh is not None and _mesh_batch_enabled():
+            from ..parallel.multiquery import mesh_batch_eligible, mesh_eval_multiquery
 
-        if mesh_batch_eligible(mesh, staged):
-            tm, counts = mesh_eval_multiquery(mesh, lowered, staged, progs)
-            engine = "mesh"
+            if mesh_batch_eligible(mesh, staged):
+                tm, counts = mesh_eval_multiquery(mesh, lowered, staged, progs)
+                engine = "mesh"
+            else:
+                tm, counts = eval_multiquery(lowered, staged, progs)
         else:
             tm, counts = eval_multiquery(lowered, staged, progs)
-    else:
-        tm, counts = eval_multiquery(lowered, staged, progs)
+        st.attrs.update(engine=engine, bucket=staged.n_spans_b)
     key_dev = staged.cols["trace.start_ms"]
     nt = blk.meta.total_traces
     TEL.record_routing("search_batch", engine,
                        "mesh_batched" if engine == "mesh" else "coalesced",
                        n=len(items))
-    TEL.child_span(
-        f"batch:{blk.meta.block_id[:8]}", t0w, _time.time(),
-        {"engine": engine, "bucket": staged.n_spans_b,
-         "occupancy": len(items)})
 
     responses: list = []
     if nt == 0:
@@ -446,49 +442,51 @@ def _run_find_group(key, items: list) -> list:
 def _run_find_group_fused(items: list) -> list:
     from ..block import schema as S
     from ..ops.find import lookup_ids_blocks_cached
+    from ..util.kerneltel import TEL
     from ..wire.combine import combine_traces
 
     db = items[0].db
     metas, pool = items[0].metas, db.pool
-    blocks = [db.open_block(m) for m in metas]
     ids = [it.trace_id.rjust(16, b"\x00") for it in items]
     # a block survives the gate if ANY id in the window may be present;
     # the bisection compare is exact, so ids the bloom would have pruned
     # for a given block simply miss (-1) there
-    if pool is not None:
-        gates = list(pool.map(
-            lambda b: any(b.bloom_test(it.trace_id) for it in items), blocks))
-    else:
-        gates = [any(b.bloom_test(it.trace_id) for it in items)
-                 for b in blocks]
-    keep = [b for b, ok in zip(blocks, gates) if ok]
+    with TEL.stage("find:bloom", blocks=len(metas), ids=len(items)):
+        blocks = [db.open_block(m) for m in metas]
+        if pool is not None:
+            gates = list(pool.map(
+                lambda b: any(b.bloom_test(it.trace_id) for it in items), blocks))
+        else:
+            gates = [any(b.bloom_test(it.trace_id) for it in items)
+                     for b in blocks]
+        keep = [b for b, ok in zip(blocks, gates) if ok]
     if not keep:
         return [None] * len(items)
     query = np.asarray([S.trace_id_to_codes(i) for i in ids], dtype=np.int32)
-    if db.mesh.devices.size > 1:
-        from ..parallel.find import sharded_find_rows
+    with TEL.stage("find:lookup", blocks=len(keep), ids=len(items)):
+        if db.mesh.devices.size > 1:
+            from ..parallel.find import sharded_find_rows
 
-        codes = (list(pool.map(lambda b: b.trace_index["trace.id_codes"], keep))
-                 if pool is not None
-                 else [b.trace_index["trace.id_codes"] for b in keep])
-        sids = sharded_find_rows(db.mesh, codes, query)  # (B, Q)
-    else:
-        if pool is not None:  # overlap the id-index reads
-            list(pool.map(lambda b: b.trace_index, keep))
-        sids = lookup_ids_blocks_cached(keep, query)  # (B, Q)
+            codes = (list(pool.map(lambda b: b.trace_index["trace.id_codes"], keep))
+                     if pool is not None
+                     else [b.trace_index["trace.id_codes"] for b in keep])
+            sids = sharded_find_rows(db.mesh, codes, query)  # (B, Q)
+        else:
+            if pool is not None:  # overlap the id-index reads
+                list(pool.map(lambda b: b.trace_index, keep))
+            sids = lookup_ids_blocks_cached(keep, query)  # (B, Q)
     per_block: dict[int, list[tuple[int, int]]] = {}
     for bi in range(sids.shape[0]):
         for qi in range(sids.shape[1]):
             if sids[bi, qi] >= 0:
                 per_block.setdefault(bi, []).append((qi, int(sids[bi, qi])))
     found: list[list] = [[] for _ in items]
-    for bi, pairs in per_block.items():
-        traces = keep[bi].materialize_traces([row for _, row in pairs])
-        for (qi, _), tr in zip(pairs, traces):
-            if tr is not None:
-                found[qi].append(tr)
-    from ..util.kerneltel import TEL
-
+    with TEL.stage("find:fetch", hits=sum(len(p) for p in per_block.values())):
+        for bi, pairs in per_block.items():
+            traces = keep[bi].materialize_traces([row for _, row in pairs])
+            for (qi, _), tr in zip(pairs, traces):
+                if tr is not None:
+                    found[qi].append(tr)
     TEL.record_demux("find", len(items))
     return [combine_traces(f) if f else None for f in found]
 

@@ -226,30 +226,28 @@ class LiveEngine:
                 tag_codes.append(c)
 
         TEL.record_routing("search_live", engine, reason)
-        t0 = time.perf_counter()
-        t0_wall = time.time()
-        if engine == "device":
-            mask = eval_live_device(snap, tag_codes, name_codes,
-                                    req.start, req.end, req.min_duration_ms)
-
-            def selector(k):
-                sids, _, n_match = select_topk_device(
-                    mask, snap.dev["key_s"], mask, k)
-                return sids, n_match
-        else:
-            hmask = eval_live_host(snap, tag_codes, name_codes,
-                                   req.start, req.end, req.min_duration_ms)
-
-            def selector(k):
-                sids, _, n_match = select_topk_host(
-                    hmask, snap.key_s, np.zeros_like(snap.key_s), k)
-                return sids, n_match
-
-        resp = self._collect(snap, groups, req, q, selector)
-        self._observe_engine(engine, rows, time.perf_counter() - t0)
         # timeline: the ingester live-head leg with its routing verdict
-        TEL.child_span("live:search", t0_wall, time.time(),
-                       {"engine": engine, "reason": reason, "rows": rows})
+        with TEL.stage("live:search", engine=engine, reason=reason,
+                       rows=rows) as st:
+            if engine == "device":
+                mask = eval_live_device(snap, tag_codes, name_codes,
+                                        req.start, req.end, req.min_duration_ms)
+
+                def selector(k):
+                    sids, _, n_match = select_topk_device(
+                        mask, snap.dev["key_s"], mask, k)
+                    return sids, n_match
+            else:
+                hmask = eval_live_host(snap, tag_codes, name_codes,
+                                       req.start, req.end, req.min_duration_ms)
+
+                def selector(k):
+                    sids, _, n_match = select_topk_host(
+                        hmask, snap.key_s, np.zeros_like(snap.key_s), k)
+                    return sids, n_match
+
+            resp = self._collect(snap, groups, req, q, selector)
+        self._observe_engine(engine, rows, st.seconds)
         return resp
 
     def _collect(self, snap, groups, req: SearchRequest, q, selector) -> SearchResponse:

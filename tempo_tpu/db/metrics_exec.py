@@ -558,14 +558,23 @@ def metrics_block(blk: BackendBlock, q: MetricsQuery, req: MetricsRequest,
     b_off, nb, t0_rel = _block_axis(blk, req)
     if nb == 0:
         return
-    import time as _time
-
     from ..util.kerneltel import TEL
 
-    t0_wall = _time.time()
+    with TEL.stage("block:metrics", block=blk.meta.block_id[:8]) as st:
+        _metrics_block(blk, q, req, resp, mode, planned, b_off, nb, t0_rel,
+                       st.attrs)
+
+
+def _metrics_block(blk, q, req, resp, mode, planned, b_off, nb, t0_rel,
+                   span_attrs: dict) -> None:
+    """metrics_block's body; fills `span_attrs` (engine, bucket,
+    compile, reason) for the block's stage."""
+    from ..util.kerneltel import TEL
+
     io0 = blk.pack.bytes_read
     if planned is None:
-        planned = plan_metrics_filter(q, blk.dictionary)
+        with TEL.stage("plan:compile", block=blk.meta.block_id[:8]):
+            planned = plan_metrics_filter(q, blk.dictionary)
     if planned.prune:
         return
     groups = None if mode == "exact" else resolve_groups(blk, q.agg.by)
@@ -587,9 +596,7 @@ def metrics_block(blk: BackendBlock, q: MetricsQuery, req: MetricsRequest,
         TEL.record_routing("metrics", "exact", exact_reason)
         _metrics_block_exact(blk, q, req, resp, planned, b_off, nb)
         resp.inspected_bytes += blk.pack.bytes_read - io0
-        TEL.child_span(f"block:{blk.meta.block_id[:8]}", t0_wall, _time.time(),
-                       {"engine": "exact", "reason": exact_reason,
-                        "compile": False})
+        span_attrs.update(engine="exact", reason=exact_reason, compile=False)
         return
     gid, labels = groups
     if not labels:
@@ -623,9 +630,9 @@ def metrics_block(blk: BackendBlock, q: MetricsQuery, req: MetricsRequest,
             query, staged, operands, gid, val, pres,
             t0_rel, req.step_ms, nb, len(labels))
         info = TEL.last_launch()
-        span_attrs = {"engine": "device", "bucket": staged.n_spans_b,
-                      "compile": bool(info and info[0] == "timeseries"
-                                      and info[2])}
+        span_attrs.update(engine="device", bucket=staged.n_spans_b,
+                          compile=bool(info and info[0] == "timeseries"
+                                       and info[2]))
     else:
         from ..ops.timeseries import eval_timeseries_host
 
@@ -647,13 +654,10 @@ def metrics_block(blk: BackendBlock, q: MetricsQuery, req: MetricsRequest,
         outs = eval_timeseries_host(
             query, cols, operands, n_spans, blk.meta.total_traces,
             gid, val, pres, t0_rel, req.step_ms, nb, len(labels))
-        span_attrs = {"engine": "host", "bucket": int(n_spans),
-                      "compile": False}
+        span_attrs.update(engine="host", bucket=int(n_spans), compile=False)
     _outs_to_series(outs, q.agg.fn, labels, b_off, resp)
     resp.inspected_spans += n_spans
     resp.inspected_bytes += blk.pack.bytes_read - io0
-    TEL.child_span(f"block:{blk.meta.block_id[:8]}", t0_wall, _time.time(),
-                   span_attrs)
 
 
 # ------------------------------------------------------------ exact path

@@ -231,15 +231,18 @@ def _plan_for_block(blk: BackendBlock, req: SearchRequest, allow_struct: bool = 
     # struct nodes need the block to carry the parent-row column
     # (pre-upgrade blocks don't)
     allow_struct = allow_struct and blk.pack.has("span.parent_idx")
-    return plan_search_request(
-        blk.dictionary,
-        req.tags,
-        query=req.query,
-        min_duration_ms=req.min_duration_ms,
-        max_duration_ms=req.max_duration_ms,
-        start_rel_ms=start_rel,
-        allow_struct=allow_struct,
-    )
+    from ..util.kerneltel import TEL
+
+    with TEL.stage("plan:compile", block=blk.meta.block_id[:8]):  # parse + plan
+        return plan_search_request(
+            blk.dictionary,
+            req.tags,
+            query=req.query,
+            min_duration_ms=req.min_duration_ms,
+            max_duration_ms=req.max_duration_ms,
+            start_rel_ms=start_rel,
+            allow_struct=allow_struct,
+        )
 
 
 # --------------------------------------------------- candidate selection
@@ -279,13 +282,16 @@ def _verify_candidates(blk: BackendBlock, req: SearchRequest, sids, needs_verify
 
     t0_wall = _time.time()
     q = parse(req.query)
-    traces = blk.materialize_traces([int(s) for s in sids])
-    out = np.asarray(
-        [s for s, tr in zip(sids, traces) if tr is not None and trace_matches(q, tr)],
-        dtype=np.int64,
-    )
+    traces = blk.materialize_traces([int(s) for s in sids])  # rows:materialize
+    with TEL.stage("verify:eval", rows=int(len(sids))):
+        out = np.asarray(
+            [s for s, tr in zip(sids, traces) if tr is not None and trace_matches(q, tr)],
+            dtype=np.int64,
+        )
     # timeline + cost: the exact-verify leg (conservative device mask ->
-    # host re-check) of this block's evaluation
+    # host re-check) of this block's evaluation. A retroactive LEAF: the
+    # two stages inside it are its siblings, so its self time stays the
+    # whole leg (benchmarks' verify_ms_per_search)
     TEL.child_span("verify", t0_wall, _time.time(),
                    {"block": blk.meta.block_id[:8],
                     "rows": int(len(sids)), "kept": int(out.shape[0])})
@@ -360,24 +366,27 @@ def _collect_topk(blk: BackendBlock, req: SearchRequest, needs_verify: bool,
     nt = blk.meta.total_traces
     if nt == 0:
         return []
-    k = min(k_bucket(max(2 * limit, 32)), nt)
-    out: list = []
-    seen: set[int] = set()
-    while True:
-        sids, cnts, n_match = selector(k)
-        fresh = [(int(s), int(c)) for s, c in zip(sids, cnts) if int(s) not in seen]
-        seen.update(s for s, _ in fresh)
-        if fresh:
-            ok = _verify_candidates(
-                blk, req, np.asarray([s for s, _ in fresh], dtype=np.int64), needs_verify
-            )
-            okset = {int(s) for s in ok}
-            out.extend(
-                _candidates(blk, req, [s for s, _ in fresh if s in okset], dict(fresh))
-            )
-        if len(out) >= limit or len(seen) >= n_match or k >= nt:
-            return [_materialize(c) for c in out] if materialize else out
-        k = min(k_bucket(k * 4), nt)
+    from ..util.kerneltel import TEL
+
+    with TEL.stage("topk:collect", block=blk.meta.block_id[:8], limit=limit):
+        k = min(k_bucket(max(2 * limit, 32)), nt)
+        out: list = []
+        seen: set[int] = set()
+        while True:
+            sids, cnts, n_match = selector(k)
+            fresh = [(int(s), int(c)) for s, c in zip(sids, cnts) if int(s) not in seen]
+            seen.update(s for s, _ in fresh)
+            if fresh:
+                ok = _verify_candidates(
+                    blk, req, np.asarray([s for s, _ in fresh], dtype=np.int64), needs_verify
+                )
+                okset = {int(s) for s in ok}
+                out.extend(
+                    _candidates(blk, req, [s for s, _ in fresh if s in okset], dict(fresh))
+                )
+            if len(out) >= limit or len(seen) >= n_match or k >= nt:
+                return [_materialize(c) for c in out] if materialize else out
+            k = min(k_bucket(k * 4), nt)
 
 
 # ---------------------------------------------------- per-block search
@@ -555,76 +564,71 @@ def search_block(
     else:
         use_device, reason = True, "hot_block"
     TEL.record_routing("search_block", "device" if use_device else "host", reason)
-    import time as _time
+    # per-block stage with kernel attrs: a slow query's flame view shows
+    # which block ran where and whether it recompiled
+    with TEL.stage("block:search", block=blk.meta.block_id[:8],
+                   engine="device" if use_device else "host",
+                   reason=reason) as st:
+        compiles0 = TEL.totals()[0]  # delta covers every chunk of a streamed eval
 
-    t0_wall = _time.time()
-    compiles0 = TEL.totals()[0]  # delta covers every chunk of a streamed eval
+        if use_device:
+            if n_rows * 4 * n_span_cols > _STREAM_MIN_STAGE_BYTES:
+                # large scan: stream row-group chunks, prefetching the next
+                # chunk's IO while the device filters the current one
+                if planned.has_struct:  # streaming slices the span axis too
+                    planned = _plan_for_block(blk, req, allow_struct=False)
+                    if planned.prune:
+                        return resp
+                    operands = Operands.build(planned.rows, planned.tables or None)
+                    needed = required_columns(planned.conds)
+                from ..ops.stream import eval_block_streamed
 
-    if use_device:
-        if n_rows * 4 * n_span_cols > _STREAM_MIN_STAGE_BYTES:
-            # large scan: stream row-group chunks, prefetching the next
-            # chunk's IO while the device filters the current one
-            if planned.has_struct:  # streaming slices the span axis too
-                planned = _plan_for_block(blk, req, allow_struct=False)
-                if planned.prune:
-                    return resp
-                operands = Operands.build(planned.rows, planned.tables or None)
-                needed = required_columns(planned.conds)
-            from ..ops.stream import eval_block_streamed
+                tm, counts, n_spans_seen = eval_block_streamed(
+                    blk, needed, (planned.tree, planned.conds), operands,
+                    groups=groups_range, return_device=True,
+                )
+                key = _start_key_dev(blk, tm.shape[0])
+            else:
+                staged = stage_block(blk, needed + ["trace.start_ms"], groups=groups_range)
+                tm, counts = eval_block(
+                    (planned.tree, planned.conds),
+                    staged.cols,
+                    operands,
+                    staged.n_spans,
+                    staged.n_traces,
+                    staged.n_spans_b,
+                    staged.n_res_b,
+                    staged.n_traces_b,
+                    span_out=False,
+                )
+                key = staged.cols["trace.start_ms"]
+                n_spans_seen = staged.n_spans
 
-            tm, counts, n_spans_seen = eval_block_streamed(
-                blk, needed, (planned.tree, planned.conds), operands,
-                groups=groups_range, return_device=True,
-            )
-            key = _start_key_dev(blk, tm.shape[0])
+            def selector(k):
+                return select_topk_device(tm, key, counts, k)
         else:
-            staged = stage_block(blk, needed + ["trace.start_ms"], groups=groups_range)
-            tm, counts = eval_block(
-                (planned.tree, planned.conds),
-                staged.cols,
-                operands,
-                staged.n_spans,
-                staged.n_traces,
-                staged.n_spans_b,
-                staged.n_res_b,
-                staged.n_traces_b,
-                span_out=False,
-            )
-            key = staged.cols["trace.start_ms"]
-            n_spans_seen = staged.n_spans
+            # span_off carries the span->trace grouping: the full-length
+            # trace_sid column never needs to leave disk on the host path
+            plan = None
+            if groups_range is None:
+                from ..ops.stream import staged_warm
 
-        def selector(k):
-            return select_topk_device(tm, key, counts, k)
-    else:
-        # span_off carries the span->trace grouping: the full-length
-        # trace_sid column never needs to leave disk on the host path
-        plan = None
-        if groups_range is None:
-            from ..ops.stream import staged_warm
+                plan = _host_plan(blk, planned, None)
+                # single-unit form of the cold pipeline: coalesced ranged
+                # fetch + one threaded decode, with per-stage kerneltel
+                staged_warm(
+                    blk, plan[0] + list(blk.SEARCH_TRACE_COLS) + ["trace.start_ms"])
+            tm, counts, _ = _host_eval(blk, planned, operands, groups_range, plan=plan)
+            n_spans_seen = n_rows
+            key = _start_key_host(blk)
 
-            plan = _host_plan(blk, planned, None)
-            # single-unit form of the cold pipeline: coalesced ranged
-            # fetch + one threaded decode, with per-stage kerneltel
-            staged_warm(
-                blk, plan[0] + list(blk.SEARCH_TRACE_COLS) + ["trace.start_ms"])
-        tm, counts, _ = _host_eval(blk, planned, operands, groups_range, plan=plan)
-        n_spans_seen = n_rows
-        key = _start_key_host(blk)
+            def selector(k):
+                return select_topk_host(tm, key, counts, k)
 
-        def selector(k):
-            return select_topk_host(tm, key, counts, k)
-
-    # per-block self-trace span with kernel attrs: a slow query's flame
-    # view shows which block ran where and whether it recompiled
-    info = TEL.last_launch() if use_device else None
-    TEL.child_span(
-        f"block:{blk.meta.block_id[:8]}", t0_wall, _time.time(),
-        {"engine": "device" if use_device else "host",
-         "bucket": (int(info[1]) if info and info[0] == "filter" and use_device
-                    else n_rows),
-         "compile": use_device and TEL.totals()[0] > compiles0,
-         "reason": reason},
-    )
+        info = TEL.last_launch() if use_device else None
+        st.attrs.update(
+            bucket=(int(info[1]) if info and info[0] == "filter" else n_rows),
+            compile=use_device and TEL.totals()[0] > compiles0)
     results = _collect_topk(blk, req, planned.needs_verify, selector, limit)
     results.sort(key=lambda r: -r.start_time_unix_nano)
     resp.traces = results[:limit]
@@ -710,7 +714,6 @@ def search_blocks_fused(
 
     from ..util.kerneltel import TEL
 
-    self_trace = TEL.active_trace()  # pool threads lose the contextvar
     dev_items: list[tuple[BackendBlock, object]] = []
     host_items: list[tuple[BackendBlock, object]] = []
     decisions: list[tuple[str, str]] = []  # recorded only if we RUN here
@@ -771,33 +774,28 @@ def search_blocks_fused(
         prefetch = HostPrefetch(cold_wants)
 
     def stage_and_eval(item):
-        import time as _time
-
         blk, p = item
-        t0w = _time.time()
-        operands = Operands.build(p.rows, p.tables or None)
-        needed = required_columns(p.conds) + list(p.extra_cols) + ["trace@gkey_s"]
-        staged = stage_block(blk, needed)
-        tm, counts = eval_block(
-            (p.tree, p.conds), staged.cols, operands,
-            staged.n_spans, staged.n_traces,
-            staged.n_spans_b, staged.n_res_b, staged.n_traces_b,
-            span_out=False,
-        )
-        if self_trace is not None:
+        with TEL.stage("block:search", block=blk.meta.block_id[:8],
+                       engine="device") as st:
+            operands = Operands.build(p.rows, p.tables or None)
+            needed = required_columns(p.conds) + list(p.extra_cols) + ["trace@gkey_s"]
+            staged = stage_block(blk, needed)
+            tm, counts = eval_block(
+                (p.tree, p.conds), staged.cols, operands,
+                staged.n_spans, staged.n_traces,
+                staged.n_spans_b, staged.n_res_b, staged.n_traces_b,
+                span_out=False,
+            )
             info = TEL.last_launch()
-            self_trace.child(
-                f"block:{blk.meta.block_id[:8]}", t0w, _time.time(),
-                {"engine": "device", "bucket": staged.n_spans_b,
-                 "compile": bool(info and info[0] == "filter" and info[2])})
+            st.attrs.update(
+                bucket=staged.n_spans_b,
+                compile=bool(info and info[0] == "filter" and info[2]))
         return tm, counts, staged.cols["trace@gkey_s"], staged.n_spans
 
     def host_eval_collect(item):
         import time as _time
 
         blk, p = item
-        t0w = _time.time()
-        operands = Operands.build(p.rows, p.tables or None)
         # cold-scan detection from the PRE-prefetch snapshot (a pipeline
         # hit still runs the host engine as a cold scan), but the rate
         # EMA only learns from scans that paid their own IO: a block the
@@ -807,30 +805,29 @@ def search_blocks_fused(
         plan = host_plans[id(blk)]
         host_needed = plan[0]
         cold = id(blk) in cold_ids
-        paid_io = False
-        t0 = _time.perf_counter()
-        if cold:
-            # one coalesced ranged read + one threaded decompress batch
-            # for EVERYTHING this query touches (eval columns + the
-            # candidate/result trace columns): a cold scan's cost is
-            # per-column fixed overheads, not bytes. The pipeline ran
-            # (or is running) those stages ahead; wait for them, and do
-            # the read here only if the pipeline was skipped/cancelled.
-            if prefetch is None or not prefetch.wait(blk):
-                paid_io = True
-                blk.pack.warm_columns(
-                    host_needed + list(blk.SEARCH_TRACE_COLS) + ["trace.start_ms"])
-        tm, counts, cols = _host_eval(blk, p, operands, None, plan=plan)
-        if paid_io:
-            _note_host_rate(sum(a.nbytes for a in cols.values()),
-                            _time.perf_counter() - t0)
-        key = _start_key_host(blk)
         n_spans = blk.pack.axes[S.AX_SPAN].n_rows
-        if self_trace is not None:
-            self_trace.child(
-                f"block:{blk.meta.block_id[:8]}", t0w, _time.time(),
-                {"engine": "host", "bucket": int(n_spans), "compile": False,
-                 "cold": cold})
+        with TEL.stage("block:search", block=blk.meta.block_id[:8],
+                       engine="host", bucket=int(n_spans), compile=False,
+                       cold=cold):
+            operands = Operands.build(p.rows, p.tables or None)
+            paid_io = False
+            t0 = _time.perf_counter()
+            if cold:
+                # one coalesced ranged read + one threaded decompress batch
+                # for EVERYTHING this query touches (eval columns + the
+                # candidate/result trace columns): a cold scan's cost is
+                # per-column fixed overheads, not bytes. The pipeline ran
+                # (or is running) those stages ahead; wait for them, and do
+                # the read here only if the pipeline was skipped/cancelled.
+                if prefetch is None or not prefetch.wait(blk):
+                    paid_io = True
+                    blk.pack.warm_columns(
+                        host_needed + list(blk.SEARCH_TRACE_COLS) + ["trace.start_ms"])
+            tm, counts, cols = _host_eval(blk, p, operands, None, plan=plan)
+            if paid_io:
+                _note_host_rate(sum(a.nbytes for a in cols.values()),
+                                _time.perf_counter() - t0)
+            key = _start_key_host(blk)
 
         if not p.needs_verify:
             # exact plans skip the per-block escalating collect: ONE
@@ -868,9 +865,15 @@ def search_blocks_fused(
             raise
 
     try:
-        outs = list(pool.map(run_item, tagged)) if pool is not None else [
-            run_item(t) for t in tagged
-        ]
+        if pool is not None:
+            # pool threads lose the contextvars: each item runs in a copy
+            # of this context so its stages land on the query's self-trace
+            import contextvars
+
+            ctxs = [contextvars.copy_context() for _ in tagged]
+            outs = list(pool.map(lambda c, t: c.run(run_item, t), ctxs, tagged))
+        else:
+            outs = [run_item(t) for t in tagged]
     finally:
         if prefetch is not None:
             prefetch.close()  # an errored item mustn't leak pipeline work
@@ -944,32 +947,35 @@ def _collect_topk_multi(blocks, plans, offsets, req: SearchRequest, selector,
     total = int(offsets[-1])
     if total == 0:
         return []
-    k = min(k_bucket(max(2 * limit, 32)), total)
-    out: list = []
-    seen: set[int] = set()
-    while True:
-        gids, gcnts, n_match = selector(k)
-        per_block: dict[int, list[tuple[int, int]]] = {}
-        fresh = 0
-        for g, c in zip(gids, gcnts):
-            g = int(g)
-            if g in seen:
-                continue
-            seen.add(g)
-            fresh += 1
-            bi = int(np.searchsorted(offsets, g, side="right")) - 1
-            per_block.setdefault(bi, []).append((g - int(offsets[bi]), int(c)))
-        for bi, pairs in per_block.items():
-            blk, p = blocks[bi], plans[bi]
-            sids = np.asarray([s for s, _ in pairs], dtype=np.int64)
-            ok = _verify_candidates(blk, req, sids, p.needs_verify)
-            okset = {int(s) for s in ok}
-            out.extend(
-                _candidates(blk, req, [s for s, c in pairs if s in okset], dict(pairs))
-            )
-        if len(out) >= limit or len(seen) >= n_match or k >= total or fresh == 0:
-            return [_materialize(c) for c in out] if materialize else out
-        k = min(k_bucket(k * 4), total)
+    from ..util.kerneltel import TEL
+
+    with TEL.stage("topk:collect", blocks=len(blocks), limit=limit):
+        k = min(k_bucket(max(2 * limit, 32)), total)
+        out: list = []
+        seen: set[int] = set()
+        while True:
+            gids, gcnts, n_match = selector(k)
+            per_block: dict[int, list[tuple[int, int]]] = {}
+            fresh = 0
+            for g, c in zip(gids, gcnts):
+                g = int(g)
+                if g in seen:
+                    continue
+                seen.add(g)
+                fresh += 1
+                bi = int(np.searchsorted(offsets, g, side="right")) - 1
+                per_block.setdefault(bi, []).append((g - int(offsets[bi]), int(c)))
+            for bi, pairs in per_block.items():
+                blk, p = blocks[bi], plans[bi]
+                sids = np.asarray([s for s, _ in pairs], dtype=np.int64)
+                ok = _verify_candidates(blk, req, sids, p.needs_verify)
+                okset = {int(s) for s in ok}
+                out.extend(
+                    _candidates(blk, req, [s for s, c in pairs if s in okset], dict(pairs))
+                )
+            if len(out) >= limit or len(seen) >= n_match or k >= total or fresh == 0:
+                return [_materialize(c) for c in out] if materialize else out
+            k = min(k_bucket(k * 4), total)
 
 
 # ---- stacked multi-block device search (parallel/search.py)

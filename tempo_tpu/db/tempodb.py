@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -236,25 +235,29 @@ class TempoDB:
         from ..block import schema as S
         from ..ops.find import lookup_ids_blocks_cached
         from ..parallel.find import sharded_find_rows
+        from ..util.kerneltel import TEL
 
-        blocks = [self.open_block(m) for m in candidates]
-        gates = list(self.pool.map(lambda b: b.bloom_test(trace_id), blocks))
-        blocks = [b for b, ok in zip(blocks, gates) if ok]
+        with TEL.stage("find:bloom", blocks=len(candidates)):
+            blocks = [self.open_block(m) for m in candidates]
+            gates = list(self.pool.map(lambda b: b.bloom_test(trace_id), blocks))
+            blocks = [b for b, ok in zip(blocks, gates) if ok]
         if not blocks:
             return []
         query = np.asarray(
             [S.trace_id_to_codes(trace_id.rjust(16, b"\x00"))], dtype=np.int32
         )
-        if self.mesh.devices.size > 1:
-            codes = list(self.pool.map(lambda b: b.trace_index["trace.id_codes"], blocks))
-            sids = sharded_find_rows(self.mesh, codes, query)
-        else:
-            # single chip: lookup_ids_blocks_cached auto-routes to the
-            # host searchsorted engine (zero device round trips)
-            list(self.pool.map(lambda b: b.trace_index, blocks))  # parallel IO
-            sids = lookup_ids_blocks_cached(blocks, query)
+        with TEL.stage("find:lookup", blocks=len(blocks)):
+            if self.mesh.devices.size > 1:
+                codes = list(self.pool.map(lambda b: b.trace_index["trace.id_codes"], blocks))
+                sids = sharded_find_rows(self.mesh, codes, query)
+            else:
+                # single chip: lookup_ids_blocks_cached auto-routes to the
+                # host searchsorted engine (zero device round trips)
+                list(self.pool.map(lambda b: b.trace_index, blocks))  # parallel IO
+                sids = lookup_ids_blocks_cached(blocks, query)
         hits = [(blk, int(sid)) for blk, sid in zip(blocks, sids[:, 0]) if sid >= 0]
-        return list(self.pool.map(lambda h: h[0].materialize_traces([h[1]])[0], hits))
+        with TEL.stage("find:fetch", hits=len(hits)):
+            return list(self.pool.map(lambda h: h[0].materialize_traces([h[1]])[0], hits))
 
     # ------------------------------------------------------------ search
     def search(self, tenant: str, req: SearchRequest) -> SearchResponse:

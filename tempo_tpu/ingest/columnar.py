@@ -18,7 +18,6 @@ lock here is a leaf and never calls out while held.
 from __future__ import annotations
 
 import threading
-import time
 from typing import NamedTuple
 
 import numpy as np
@@ -236,15 +235,10 @@ class ColumnarIngest:
             ent = self._feats.get(key)
             if ent is not None:
                 return ent[1]
-        t0 = time.perf_counter()
-        feat = compute_features(seg, self.dict)
-        dt = time.perf_counter() - t0
-        try:
-            from ..util.kerneltel import TEL
+        from ..util.kerneltel import TEL
 
-            TEL.record_ingest_stage("decode", dt)
-        except Exception:
-            pass
+        with TEL.stage("ingest:decode", bytes=len(seg)):
+            feat = compute_features(seg, self.dict)
         with self._lock:
             self.decodes += 1
             self._install_locked(key, seg, feat)

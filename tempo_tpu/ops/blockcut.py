@@ -27,7 +27,7 @@ import numpy as np
 
 from ..block.bloom import _K, WORD_BITS, shard_for_trace_id
 from ..util.hashing import bloom_hashes
-from .device import bucket, pad_rows
+from .device import bucket, pad_rows, scoped
 
 _I32_MIN = -(2**31)
 _I32_MAX = 2**31 - 1
@@ -55,14 +55,13 @@ def _compiled_remap(n_b: int, r_b: int):
     def kern(col, remap):
         return jnp.where(col >= 0, remap[jnp.maximum(col, 0)], col)
 
-    return jax.jit(kern)
+    return jax.jit(scoped("cut_remap")(kern))
 
 
 def remap_codes_device(col: np.ndarray, remap: np.ndarray) -> np.ndarray:
     """Dictionary-finalize remap of one code column: negatives (absent /
     sentinel codes) pass through, everything else gathers through the
     sort permutation. Twin: remap_codes_host."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
@@ -71,10 +70,8 @@ def remap_codes_device(col: np.ndarray, remap: np.ndarray) -> np.ndarray:
     col_p = pad_rows(np.asarray(col, dtype=np.int32), n_b, -1)
     rm_p = pad_rows(np.asarray(remap, dtype=np.int32), r_b, 0)
     fn = _compiled_remap(n_b, r_b)
-    TEL.record_launch("cut_remap", ("remap", n_b, r_b), n_b)
-    t0 = _time.perf_counter()
-    out = np.asarray(fn(jnp.asarray(col_p), jnp.asarray(rm_p)))[:n]
-    TEL.observe_device("cut_remap", n_b, t0)
+    with TEL.launch("cut_remap", ("remap", n_b, r_b), n_b):
+        out = np.asarray(fn(jnp.asarray(col_p), jnp.asarray(rm_p)))[:n]
     return out.astype(np.int32)
 
 
@@ -109,14 +106,13 @@ def _compiled_bloom(n_b: int, n_words: int):
         # is exactly the scatter-OR; pads add 0 to word 0 (a no-op)
         return flat | jnp.zeros(n_words, jnp.uint32).at[word_idx].add(bits)
 
-    return jax.jit(kern)
+    return jax.jit(scoped("cut_bloom")(kern))
 
 
 def bloom_bits_device(words: np.ndarray, trace_ids: list[bytes],
                       shard_bits: int) -> np.ndarray:
     """Set every trace id's K bloom bits in a (n_shards, W) word array,
     returning the updated array. Twin: bloom_bits_host."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
@@ -126,11 +122,9 @@ def bloom_bits_device(words: np.ndarray, trace_ids: list[bytes],
     word_idx = pad_rows(word_idx, n_b, 0)
     bits = pad_rows(bits, n_b, 0)
     fn = _compiled_bloom(n_b, n_words)
-    TEL.record_launch("cut_bloom", ("bloom", n_b, n_words), n_b)
-    t0 = _time.perf_counter()
-    out = np.asarray(fn(jnp.asarray(words.reshape(-1)), jnp.asarray(word_idx),
-                        jnp.asarray(bits)))
-    TEL.observe_device("cut_bloom", n_b, t0)
+    with TEL.launch("cut_bloom", ("bloom", n_b, n_words), n_b):
+        out = np.asarray(fn(jnp.asarray(words.reshape(-1)), jnp.asarray(word_idx),
+                            jnp.asarray(bits)))
     return out.reshape(words.shape)
 
 
@@ -155,7 +149,7 @@ def _compiled_rowgroup(n_b: int, n_seg: int):
         du = jax.ops.segment_max(dur_us, gid, num_segments=n_seg)
         return lo, hi, du
 
-    return jax.jit(kern)
+    return jax.jit(scoped("cut_rowgroups")(kern))
 
 
 def rowgroup_minmax_device(start_ms: np.ndarray, dur_us: np.ndarray,
@@ -164,7 +158,6 @@ def rowgroup_minmax_device(start_ms: np.ndarray, dur_us: np.ndarray,
     stats as one segmented reduce. bounds are the group boundaries
     (len n_groups+1, covering every row, all groups non-empty).
     Twin: rowgroup_minmax_host."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
@@ -176,12 +169,10 @@ def rowgroup_minmax_device(start_ms: np.ndarray, dur_us: np.ndarray,
     sm = pad_rows(np.asarray(start_ms, dtype=np.int32), n_b, 0)
     du = pad_rows(np.asarray(dur_us, dtype=np.int32), n_b, 0)
     fn = _compiled_rowgroup(n_b, n_groups + 1)
-    TEL.record_launch("cut_rowgroups", ("rowgroups", n_b, n_groups + 1), n_b)
-    t0 = _time.perf_counter()
-    lo, hi, dmax = fn(jnp.asarray(gid), jnp.asarray(sm), jnp.asarray(du))
-    out = (np.asarray(lo)[:n_groups], np.asarray(hi)[:n_groups],
-           np.asarray(dmax)[:n_groups])
-    TEL.observe_device("cut_rowgroups", n_b, t0)
+    with TEL.launch("cut_rowgroups", ("rowgroups", n_b, n_groups + 1), n_b):
+        lo, hi, dmax = fn(jnp.asarray(gid), jnp.asarray(sm), jnp.asarray(du))
+        out = (np.asarray(lo)[:n_groups], np.asarray(hi)[:n_groups],
+               np.asarray(dmax)[:n_groups])
     return out
 
 

@@ -15,9 +15,11 @@ import numpy as np
 
 from ..block.bloom import ShardedBloom, shard_for_trace_id
 from ..util.hashing import bloom_hashes
+from .device import scoped
 
 
 @jax.jit
+@scoped("bloom_union")
 def _union_kernel(stacked: jnp.ndarray) -> jnp.ndarray:
     """(K, n_shards, words) uint32 -> (n_shards, words) bitwise-OR union."""
     return jax.lax.reduce(
@@ -28,7 +30,6 @@ def _union_kernel(stacked: jnp.ndarray) -> jnp.ndarray:
 def union_blooms(blooms: list[ShardedBloom]) -> ShardedBloom:
     """Device union of same-geometry blooms; falls back to ValueError on
     geometry mismatch (caller rebuilds instead)."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
@@ -37,15 +38,14 @@ def union_blooms(blooms: list[ShardedBloom]) -> ShardedBloom:
         if b.n_shards != first.n_shards or b.shard_bits != first.shard_bits:
             raise ValueError("bloom geometry mismatch")
     stacked = jnp.asarray(np.stack([b.words for b in blooms]))
-    TEL.record_launch("bloom_union", ("union", stacked.shape), stacked.shape[0])
-    t0 = _time.perf_counter()
-    out = ShardedBloom(first.n_shards, first.shard_bits)
-    out.words = np.asarray(_union_kernel(stacked))
-    TEL.observe_device("bloom_union", stacked.shape[0], t0)
+    with TEL.launch("bloom_union", ("union", stacked.shape), stacked.shape[0]):
+        out = ShardedBloom(first.n_shards, first.shard_bits)
+        out.words = np.asarray(_union_kernel(stacked))
     return out
 
 
 @jax.jit
+@scoped("bloom_test")
 def _test_kernel(words: jnp.ndarray, word_idx: jnp.ndarray, bit_idx: jnp.ndarray) -> jnp.ndarray:
     """words: (S, W) u32; word_idx/bit_idx: (Q, K) per-query bloom positions
     (word_idx pre-offset by query shard * W is NOT needed -- words indexed
@@ -70,15 +70,12 @@ def batch_test(bloom_words: np.ndarray, shard_bits: int, n_shards: int, trace_id
         for j, pos in enumerate(bloom_hashes(tid, 7, shard_bits)):
             word_idx[i, j] = (shard, pos // 32)
             bit_idx[i, j] = pos % 32
-    import time as _time
 
     from ..util.kerneltel import TEL
 
-    TEL.record_launch("bloom_test", ("test", bloom_words.shape, q, k),
-                      bloom_words.shape[1])
-    t0 = _time.perf_counter()
-    out = np.asarray(
-        _test_kernel(jnp.asarray(bloom_words), jnp.asarray(word_idx), jnp.asarray(bit_idx))
-    )
-    TEL.observe_device("bloom_test", bloom_words.shape[1], t0)
+    with TEL.launch("bloom_test", ("test", bloom_words.shape, q, k),
+                      bloom_words.shape[1]):
+        out = np.asarray(
+            _test_kernel(jnp.asarray(bloom_words), jnp.asarray(word_idx), jnp.asarray(bit_idx))
+        )
     return out

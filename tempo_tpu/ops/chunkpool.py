@@ -30,7 +30,6 @@ demotion is worth host RAM), TEMPO_CHUNK_CACHE_CODEC
 
 from __future__ import annotations
 
-import time as _time
 from collections import OrderedDict
 from dataclasses import dataclass
 
@@ -207,15 +206,16 @@ def restage(block_id: str, key: tuple):
 
     from .stage import StagedBlock
 
-    t0 = _time.time()
     _, dec_fn = _codec_pair(ent.codec)
     host = []
-    for cname, dtype, shape, blob, raw_len in ent.cols:
-        arr = np.frombuffer(dec_fn(blob, raw_len), dtype=dtype).reshape(shape)
-        host.append((cname, arr))
-    # ONE batched transfer, same as upload_stage: per-array device_puts
-    # each pay a full link round trip
-    devs = jax.device_put([a for _, a in host])
+    with tel.stage("cache:chunk-hit", block=block_id[:8], bytes=ent.raw_bytes,
+                   codec=ent.codec):
+        for cname, dtype, shape, blob, raw_len in ent.cols:
+            arr = np.frombuffer(dec_fn(blob, raw_len), dtype=dtype).reshape(shape)
+            host.append((cname, arr))
+        # ONE batched transfer, same as upload_stage: per-array device_puts
+        # each pay a full link round trip
+        devs = jax.device_put([a for _, a in host])
     (n_spans, n_traces, n_res, n_spans_b, n_traces_b, n_res_b,
      span_base) = ent.shape_meta
     staged = StagedBlock(
@@ -225,9 +225,6 @@ def restage(block_id: str, key: tuple):
         cols={cname: dev for (cname, _), dev in zip(host, devs)},
     )
     tel.chunk_cache_hits.inc()
-    tel.child_span("cache:chunk-hit", t0, _time.time(),
-                   {"block": block_id[:8], "bytes": ent.raw_bytes,
-                    "codec": ent.codec})
     return staged
 
 

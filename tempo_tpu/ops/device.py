@@ -35,6 +35,26 @@ def launch_tap(op: str) -> None:
     chaos_plane.tap("device.launch", key=str(op))
 
 
+def scoped(op: str):
+    """Decorator for a jitted body: its whole trace runs inside
+    jax.named_scope("tempo.<op>") (op = the kernel's record_launch
+    name), so a device trace's XLA ops say which kernel they belong to.
+    The function keeps its own name: the module stays `jit_run` /
+    `jit_sel` / ... for whatever groups by module name, and the scope
+    is HLO metadata only -- persistent-cache keys do not move."""
+    import functools
+
+    import jax
+
+    def deco(f):
+        @functools.wraps(f)
+        def inner(*args, **kwargs):
+            with jax.named_scope("tempo." + op):
+                return f(*args, **kwargs)
+        return inner
+    return deco
+
+
 def bucket(n: int) -> int:
     """Next power-of-two >= max(n, MIN_BUCKET)."""
     b = MIN_BUCKET

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .device import scoped
+
 # condition targets
 T_SPAN = "span"  # direct span-axis column
 T_TRACE = "trace"  # trace-axis column
@@ -284,6 +286,7 @@ def _compiled(tree: CondTree | None, conds: tuple[Cond, ...], table_idxs: tuple[
     consumes trace-level outputs."""
 
     @jax.jit
+    @scoped("filter")
     def run(cols, ops_i, ops_f, table_list, n_spans, n_traces):
         tables = dict(zip(table_idxs, table_list))
         valid_span = jnp.arange(n_spans_b, dtype=jnp.int32) < n_spans
@@ -463,22 +466,11 @@ def eval_block(
     from ..util.kerneltel import TEL
 
     ns, nt = np.int32(n_spans), np.int32(n_traces)
-    TEL.record_launch(
+    with TEL.launch(
         "filter",
         ("filter", tree, conds, table_idxs, n_spans_b, n_res_b, n_traces_b, span_out),
         n_spans_b,
         cost=lambda: costmodel.spec(fn, cols, operands.ints, operands.floats,
                                     table_list, ns, nt),
-    )
-    import time as _time
-
-    t0 = _time.perf_counter()
-    out = fn(
-        cols,
-        operands.ints,
-        operands.floats,
-        table_list,
-        ns,
-        nt,
-    )
-    return TEL.observe_device("filter", n_spans_b, t0, out)
+    ) as ln:
+        return ln.sync(fn(cols, operands.ints, operands.floats, table_list, ns, nt))

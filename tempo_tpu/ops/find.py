@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..util.kerneltel import TEL
-from .device import PAD_I32, bucket, pad_rows
+from .device import PAD_I32, bucket, pad_rows, scoped
 
 
 def _lex_less(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -61,11 +61,13 @@ def bisect_ids(ids: jnp.ndarray, queries: jnp.ndarray, n_valid, n_steps: int) ->
 
 
 @partial(jax.jit, static_argnames=("n_steps",))
+@scoped("find")
 def _lookup_kernel(ids: jnp.ndarray, queries: jnp.ndarray, n_valid: jnp.ndarray, n_steps: int):
     return bisect_ids(ids, queries, n_valid, n_steps)
 
 
 @partial(jax.jit, static_argnames=("n_steps",))
+@scoped("find")
 def _lookup_blocks_kernel(ids: jnp.ndarray, queries: jnp.ndarray, n_valid: jnp.ndarray,
                           n_steps: int):
     """ids: (B, T, 4) stacked per-block indexes -> (B, Q) sids. One fused
@@ -304,14 +306,12 @@ def lookup_ids_blocks(id_code_arrays: list[np.ndarray], query_codes: np.ndarray)
     qb = bucket(q)
     queries = pad_rows(np.asarray(query_codes, dtype=np.int32), qb, PAD_I32)
     n_steps = int(T).bit_length()
-    TEL.record_launch(
+    with TEL.launch(
         "find", ("findB", B, T, qb), T,
         cost=lambda: _costmodel().spec(
-            _lookup_blocks_kernel, ids, queries, n_valid, n_steps))
-    t0 = _time.perf_counter()
-    out = _lookup_blocks_kernel(ids, queries, n_valid, n_steps)
-    res = np.asarray(out)[:, :q]
-    TEL.observe_device("find", T, t0)
+            _lookup_blocks_kernel, ids, queries, n_valid, n_steps)):
+        out = _lookup_blocks_kernel(ids, queries, n_valid, n_steps)
+        res = np.asarray(out)[:, :q]
     return res
 
 
@@ -328,11 +328,9 @@ def lookup_ids(id_codes: np.ndarray, query_codes: np.ndarray) -> np.ndarray:
     queries = pad_rows(np.asarray(query_codes, dtype=np.int32), qb, PAD_I32)
     n_steps = int(tb).bit_length()  # ceil(log2(tb)) + 1 covers the range
     nv = np.int32(n)
-    TEL.record_launch(
+    with TEL.launch(
         "find", ("find1", tb, qb), tb,
-        cost=lambda: _costmodel().spec(_lookup_kernel, ids, queries, nv, n_steps))
-    t0 = _time.perf_counter()
-    out = _lookup_kernel(ids, queries, nv, n_steps)
-    res = np.asarray(out)[:q]
-    TEL.observe_device("find", tb, t0)
+        cost=lambda: _costmodel().spec(_lookup_kernel, ids, queries, nv, n_steps)):
+        out = _lookup_kernel(ids, queries, nv, n_steps)
+        res = np.asarray(out)[:q]
     return res

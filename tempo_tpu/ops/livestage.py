@@ -55,7 +55,7 @@ import numpy as np
 # exported here for existing importers
 from ..ingest.columnar import LiveDict, compute_features, kv_pair_key  # noqa: F401
 from ..util.profiler import timed_rlock
-from .device import PAD_I32, bucket, pad_rows
+from .device import PAD_I32, bucket, pad_rows, scoped
 from .stage import GKEY_ORIGIN_S
 
 _I32_MIN = -(2**31)
@@ -152,6 +152,7 @@ def _compiled_live_filter(n_tags: int, n_names: int, f_start: bool, f_end: bool,
     ops/filter launch-key contract)."""
 
     @jax.jit
+    @scoped("live_filter")
     def run(start_s, end_s, dur_ms, alive, kv_owner, kv_code,
             name_owner, name_code, tag_codes, name_qcodes,
             t0, t1, dmin, n_slots):
@@ -200,13 +201,9 @@ def eval_live_device(snap: LiveSnapshot, tag_codes: list[int],
     )
     from ..util import costmodel
 
-    TEL.record_launch("live_filter", ("live_filter",) + key, snap.slot_b,
-                      cost=lambda: costmodel.spec(fn, *args))
-    import time as _time
-
-    t_start = _time.perf_counter()
-    out = fn(*args)
-    return TEL.observe_device("live_filter", snap.slot_b, t_start, out)
+    with TEL.launch("live_filter", ("live_filter",) + key, snap.slot_b,
+                    cost=lambda: costmodel.spec(fn, *args)) as ln:
+        return ln.sync(fn(*args))
 
 
 def eval_live_host(snap: LiveSnapshot, tag_codes: list[int],
@@ -242,6 +239,7 @@ def eval_live_host(snap: LiveSnapshot, tag_codes: list[int],
 @lru_cache(maxsize=32)
 def _compiled_find(slot_b: int):
     @jax.jit
+    @scoped("live_find")
     def run(id_codes, alive, q, n_slots):
         valid = jnp.arange(slot_b, dtype=jnp.int32) < n_slots
         m = jnp.all(id_codes == q[None, :], axis=1) & (alive > 0) & valid
@@ -262,15 +260,10 @@ def find_slot_device(snap: LiveSnapshot, trace_id: bytes) -> int:
     ns = np.int32(snap.n_slots)
     from ..util import costmodel
 
-    TEL.record_launch(
-        "live_find", ("live_find", snap.slot_b), snap.slot_b,
-        cost=lambda: costmodel.spec(fn, d["id_codes"], d["alive"], q, ns))
-    import time as _time
-
-    t0 = _time.perf_counter()
-    out = fn(d["id_codes"], d["alive"], q, ns)
-    out = TEL.observe_device("live_find", snap.slot_b, t0, out)
-    return int(np.asarray(out))
+    with TEL.launch(
+            "live_find", ("live_find", snap.slot_b), snap.slot_b,
+            cost=lambda: costmodel.spec(fn, d["id_codes"], d["alive"], q, ns)):
+        return int(np.asarray(fn(d["id_codes"], d["alive"], q, ns)))
 
 
 def find_slot_host(snap: LiveSnapshot, trace_id: bytes) -> int:
@@ -287,6 +280,7 @@ def find_slot_host(snap: LiveSnapshot, trace_id: bytes) -> int:
 
 
 @jax.jit
+@scoped("live_append")
 def _append_rows_device(dst, src, start):
     """Delta append: next generation's column = resident array with the
     new rows written at `start`. The copy is device-side; only `src`
@@ -295,6 +289,7 @@ def _append_rows_device(dst, src, start):
 
 
 @jax.jit
+@scoped("live_patch")
 def _patch_slots_device(dst, idx, vals):
     """Dirty-slot patch: scatter the changed slot values into the
     resident column. idx is padded by REPEATING real indices (the
@@ -532,23 +527,20 @@ class LiveStager:
         snapshot, segments merged flushing+cut+live per tid) and return
         the new generation's snapshot. stage_device=False keeps the
         refresh host-only (the tiny-head path pays no upload)."""
-        import time as _time
 
         from ..util.kerneltel import TEL
 
         with self.lock:
-            t_delta = _time.perf_counter()
-            dirty = False
-            for tid in [t for t in self.tails if t not in items]:
-                self._retire_locked(tid, self.tails[tid])
-                dirty = True
-            for tid, (segs, state, start_s, end_s) in items.items():
-                dirty |= self._stage_trace_locked(tid, segs, start_s, end_s, state)
-            if dirty:
-                # ingest-stage ledger: the host delta encode (includes any
-                # segment decodes the columnar cache had not absorbed)
-                TEL.record_ingest_stage("stage_delta",
-                                        _time.perf_counter() - t_delta)
+            # ingest-stage ledger: the host delta encode (includes any
+            # segment decodes the columnar cache had not absorbed)
+            with TEL.stage("ingest:stage_delta", traces=len(items)) as delta:
+                dirty = False
+                for tid in [t for t in self.tails if t not in items]:
+                    self._retire_locked(tid, self.tails[tid])
+                    dirty = True
+                for tid, (segs, state, start_s, end_s) in items.items():
+                    dirty |= self._stage_trace_locked(tid, segs, start_s, end_s, state)
+                delta.counted = dirty  # a clean refresh encoded nothing
             total_rows = self.n_kv + self.n_name
             dead_rows = self.dead_kv + self.dead_name
             if self.n_slots and (
@@ -561,7 +553,12 @@ class LiveStager:
             if (not dirty and snap is not None
                     and (not stage_device or snap.dev is not None)):
                 return snap  # same generation still describes the tails
-            dev = self._upload_locked() if stage_device else None
+            dev = None
+            if stage_device:
+                # the live head's stage + upload: delta appends and
+                # dirty-slot patches are jitted programs of their own
+                with TEL.stage("live:upload", slots=self.n_slots):
+                    dev = self._upload_locked()
             self.generation += 1
             n = self.n_slots
             states: dict[str, int] = {"dead": self.dead_slots}

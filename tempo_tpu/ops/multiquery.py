@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device import PAD_I32
+from .device import PAD_I32, scoped
 from .filter import Cond, T_RES, T_SPAN, T_TRACE, normalize_tree
 
 # op codes (order matters: _cmp_code dispatches on these)
@@ -353,6 +353,7 @@ def _compiled_multiquery(shape: ProgramShape, q_b: int, n_spans_b: int,
     n_tc = max(1, len(shape.trace_cols))
 
     @jax.jit
+    @scoped("multiquery")
     def run(span_cols, trace_cols, span_off, progs, n_spans, n_traces):
         valid_span = jnp.arange(n_spans_b, dtype=jnp.int32) < n_spans
         valid_trace = jnp.arange(n_traces_b, dtype=jnp.int32) < n_traces
@@ -431,25 +432,22 @@ def mq_bytes_estimate(shape: ProgramShape, q_b: int, n_spans_b: int) -> int:
 def eval_multiquery(lowered: list[LoweredQuery], staged, progs: dict):
     """Run Q packed programs against one staged block: ONE fused launch.
     Returns device (q_b, n_traces_b) trace_mask, counts."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
     shape = lowered[0].shape
     q_b = progs["cond_op"].shape[0]
     fn = _compiled_multiquery(shape, q_b, staged.n_spans_b, staged.n_traces_b)
-    TEL.record_launch(
+    span_cols = tuple(staged.cols[n] for n in shape.span_cols)
+    trace_cols = tuple(staged.cols[n] for n in shape.trace_cols)
+    with TEL.launch(
         "multiquery",
         ("mq", shape, q_b, staged.n_spans_b, staged.n_traces_b),
         staged.n_spans_b,
-    )
-    span_cols = tuple(staged.cols[n] for n in shape.span_cols)
-    trace_cols = tuple(staged.cols[n] for n in shape.trace_cols)
-    t0 = _time.perf_counter()
-    tm, counts = fn(span_cols, trace_cols, staged.cols["trace.span_off"],
-                    progs, np.int32(staged.n_spans), np.int32(staged.n_traces))
-    TEL.observe_device("multiquery", staged.n_spans_b, t0, (tm, counts))
-    return tm, counts
+    ) as ln:
+        return ln.sync(fn(
+            span_cols, trace_cols, staged.cols["trace.span_off"],
+            progs, np.int32(staged.n_spans), np.int32(staged.n_traces)))
 
 
 _NEG = -(2**31)
@@ -458,6 +456,7 @@ _NEG = -(2**31)
 @lru_cache(maxsize=64)
 def _compiled_mq_select(k: int, q_b: int):
     @jax.jit
+    @scoped("mq_select")
     def sel(tm, key, counts):
         keyed = jnp.where(tm, key.astype(jnp.int32)[None, :], jnp.int32(_NEG))
         _, topi = jax.lax.top_k(keyed, k)  # (Q, k), rowwise == 1-D top_k
@@ -477,16 +476,13 @@ def select_multiquery(tm, key, counts, k: int):
     slice to their own smaller k' THEN apply valid, which reproduces the
     single-query select at k' exactly (top_k's order is deterministic,
     so the first k' slots of a k-select equal a k'-select)."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
     q_b, nt = int(tm.shape[0]), int(tm.shape[1])
     k = int(min(k, nt))
-    TEL.record_launch("mq_select", ("mqsel", k, q_b, nt), k)
-    t0 = _time.perf_counter()
-    out = np.asarray(_compiled_mq_select(k, q_b)(tm, key, counts))
-    TEL.observe_device("mq_select", k, t0)
+    with TEL.launch("mq_select", ("mqsel", k, q_b, nt), k):
+        out = np.asarray(_compiled_mq_select(k, q_b)(tm, key, counts))
     res = []
     for q in range(q_b):
         row = out[q]

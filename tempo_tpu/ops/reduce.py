@@ -18,9 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from .device import bucket as pow2
+from .device import scoped
 
 
 @partial(jax.jit, static_argnames=("n_series_b", "n_buckets"))
+@scoped("reduce")
 def _reduce_kernel(sid, dur, n_valid, edges, n_series_b: int, n_buckets: int):
     """sid: (N,) int32 (pad: n_series_b), dur: (N,) f32, edges: (n_buckets-1,)
     -> calls (S,), lat_sum (S,), hist (S, n_buckets)."""
@@ -61,6 +63,7 @@ def _reduce_host(sid: np.ndarray, dur_s: np.ndarray, n_series: int,
 
 
 @partial(jax.jit, static_argnames=("n_edges_b", "n_buckets"))
+@scoped("edge_reduce")
 def _edge_reduce_kernel(eid, cdur, sdur, failed, n_valid, edges,
                         n_edges_b: int, n_buckets: int):
     """One fused program for a window's completed service-graph edges:
@@ -135,22 +138,23 @@ def edge_metrics_reduce(eid: np.ndarray, cdur: np.ndarray, sdur: np.ndarray,
     sdur_p[:n] = sdur
     failed_p = np.zeros(Np, dtype=np.int32)
     failed_p[:n] = failed.astype(np.int32)
-    import time as _time
 
-    TEL.record_launch("edge_reduce", ("edge_reduce", Np, Eb, nb), Np)
-    t0 = _time.perf_counter()
-    counts, fcounts, csum, ssum, chist, shist = _edge_reduce_kernel(
-        jnp.asarray(eid_p), jnp.asarray(cdur_p), jnp.asarray(sdur_p),
-        jnp.asarray(failed_p), jnp.int32(n),
-        jnp.asarray(np.asarray(bucket_edges, np.float32)), Eb, nb
-    )
-    out = (np.asarray(counts[:n_edges]).astype(np.int64),
-           np.asarray(fcounts[:n_edges]).astype(np.int64),
-           np.asarray(csum[:n_edges]).astype(np.float64),
-           np.asarray(ssum[:n_edges]).astype(np.float64),
-           np.asarray(chist[:n_edges]).astype(np.int64),
-           np.asarray(shist[:n_edges]).astype(np.int64))
-    TEL.observe_device("edge_reduce", Np, t0)
+    with TEL.launch("edge_reduce", ("edge_reduce", Np, Eb, nb), Np):
+        # host scalars in, host slices out: an eager jnp.int32() or a
+        # slice of a device array is a jitted program of its own, and the
+        # slice compiles once per distinct edge count (a traced write
+        # window read 0.9 s of PjitFunction(dynamic_slice), PR 23)
+        counts, fcounts, csum, ssum, chist, shist = _edge_reduce_kernel(
+            jnp.asarray(eid_p), jnp.asarray(cdur_p), jnp.asarray(sdur_p),
+            jnp.asarray(failed_p), np.int32(n),
+            jnp.asarray(np.asarray(bucket_edges, np.float32)), Eb, nb
+        )
+        out = (np.asarray(counts)[:n_edges].astype(np.int64),
+               np.asarray(fcounts)[:n_edges].astype(np.int64),
+               np.asarray(csum)[:n_edges].astype(np.float64),
+               np.asarray(ssum)[:n_edges].astype(np.float64),
+               np.asarray(chist)[:n_edges].astype(np.int64),
+               np.asarray(shist)[:n_edges].astype(np.int64))
     return out
 
 
@@ -182,16 +186,13 @@ def span_metrics_reduce(sid: np.ndarray, dur_s: np.ndarray, n_series: int,
     sid_p[:n] = sid
     dur_p = np.zeros(Np, dtype=np.float32)
     dur_p[:n] = dur_s
-    import time as _time
 
-    TEL.record_launch("reduce", ("reduce", Np, Sb, nb), Np)
-    t0 = _time.perf_counter()
-    calls, lsum, hist = _reduce_kernel(
-        jnp.asarray(sid_p), jnp.asarray(dur_p), jnp.int32(n),
-        jnp.asarray(np.asarray(bucket_edges, np.float32)), Sb, nb
-    )
-    out = (np.asarray(calls[:n_series]).astype(np.int64),
-           np.asarray(lsum[:n_series]).astype(np.float64),
-           np.asarray(hist[:n_series]).astype(np.int64))
-    TEL.observe_device("reduce", Np, t0)
+    with TEL.launch("reduce", ("reduce", Np, Sb, nb), Np):
+        calls, lsum, hist = _reduce_kernel(  # host scalar/slices: see above
+            jnp.asarray(sid_p), jnp.asarray(dur_p), np.int32(n),
+            jnp.asarray(np.asarray(bucket_edges, np.float32)), Sb, nb
+        )
+        out = (np.asarray(calls)[:n_series].astype(np.int64),
+               np.asarray(lsum)[:n_series].astype(np.float64),
+               np.asarray(hist)[:n_series].astype(np.int64))
     return out

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .device import scoped
+
 _NEG = -(2**31)
 
 
@@ -40,6 +42,7 @@ def k_bucket(k: int) -> int:
 @lru_cache(maxsize=64)
 def _compiled_select(k: int):
     @jax.jit
+    @scoped("select")
     def sel(mask, key, counts):
         keyed = jnp.where(mask, key.astype(jnp.int32), jnp.int32(_NEG))
         _, topi = jax.lax.top_k(keyed, k)
@@ -58,7 +61,6 @@ def select_topk_device(mask, key, counts, k: int):
     """mask/key/counts: same-length device (or host) arrays; k <= len.
     Returns (sids desc-by-key, counts at sids, n_match) as numpy --
     one device sync total."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
@@ -66,11 +68,9 @@ def select_topk_device(mask, key, counts, k: int):
     from ..util import costmodel
 
     sel = _compiled_select(k)
-    TEL.record_launch("select", ("sel1", k, int(mask.shape[0])), k,
-                      cost=lambda: costmodel.spec(sel, mask, key, counts))
-    t0 = _time.perf_counter()
-    out = np.asarray(sel(mask, key, counts))
-    TEL.observe_device("select", k, t0)
+    with TEL.launch("select", ("sel1", k, int(mask.shape[0])), k,
+                      cost=lambda: costmodel.spec(sel, mask, key, counts)):
+        out = np.asarray(sel(mask, key, counts))
     sids, cnts, valid = out[:k], out[k : 2 * k], out[2 * k : 3 * k] > 0
     return sids[valid], cnts[valid], int(out[3 * k])
 
@@ -82,6 +82,7 @@ def _compiled_select_multi(k: int, n_parts: int):
     discriminator; jax.jit itself re-specializes on the part shapes."""
 
     @jax.jit
+    @scoped("select")
     def sel(masks, keys, counts):
         m = jnp.concatenate(masks)
         key = jnp.concatenate(keys).astype(jnp.int32)
@@ -105,7 +106,6 @@ def select_topk_device_multi(masks, keys, counts, k: int):
     Returns (global_idx desc-by-key, counts at winners, total n_match);
     global_idx indexes the concatenation of the (padded) parts -- the
     caller maps it back to (block, sid) with the part offsets."""
-    import time as _time
 
     from ..util.kerneltel import TEL
 
@@ -114,15 +114,13 @@ def select_topk_device_multi(masks, keys, counts, k: int):
     from ..util import costmodel
 
     sel = _compiled_select_multi(k, len(masks))
-    TEL.record_launch(
+    with TEL.launch(
         "select", ("selN", k, tuple(int(m.shape[0]) for m in masks)), k,
         cost=lambda: costmodel.spec(
-            sel, tuple(masks), tuple(keys), tuple(counts)))
-    t0 = _time.perf_counter()
-    out = np.asarray(
-        sel(tuple(masks), tuple(keys), tuple(counts))
-    )
-    TEL.observe_device("select", k, t0)
+            sel, tuple(masks), tuple(keys), tuple(counts))):
+        out = np.asarray(
+            sel(tuple(masks), tuple(keys), tuple(counts))
+        )
     gids, cnts, valid = out[:k], out[k : 2 * k], out[2 * k : 3 * k] > 0
     return gids[valid], cnts[valid], int(out[3 * k])
 

@@ -24,7 +24,7 @@ import numpy as np
 from ..block import schema as S
 from ..block.reader import BackendBlock
 from ..util.profiler import timed_lock
-from .device import PAD_I32, bucket, pad_rows
+from .device import PAD_I32, bucket, pad_rows, scoped
 
 _CACHE_MAX_ENTRIES = 32  # per block
 _CACHE_MAX_ENTRY_BYTES = 256 << 20
@@ -142,6 +142,24 @@ def _evict_over_budget_locked() -> None:
                         _pending_demote.append((block_id, key, staged))
 
 
+def _miss_reason(store: dict | None, key: tuple) -> str:
+    """Why a staged-cache lookup missed, from what the block holds now:
+    everything asked for is staged under another key; some of it is;
+    none of it is (never was, or was evicted). Other threads insert and
+    evict while this reads, so it works on a copy of the keys and gives
+    "" rather than raise into the query path."""
+    try:
+        cols, groups = set(key[0]), key[1]
+        have = [set(k[0]) for k in list(store or ()) if k[1] == groups]
+    except Exception:
+        return ""
+    if any(cols <= h for h in have):
+        return "key_mismatch"
+    if any(cols & h for h in have):
+        return "partial_columns"
+    return "not_staged"
+
+
 def _drain_demotions() -> None:
     """Compress HBM-evicted entries into the host chunk pool. Called by
     every path that may have run an eviction pass, AFTER _lru_lock is
@@ -176,6 +194,7 @@ def gkey_from_start_ms(meta, start_ms):
     return np.asarray(start_ms).astype(np.int64) // 1000 + base_s
 
 @jax.jit
+@scoped("res_to_span")
 def _res_to_span(res_vals, res_idx):
     """Broadcast a res-axis column to span rows; PAD where no resource."""
     out = res_vals[jnp.clip(res_idx, 0, res_vals.shape[0] - 1)]
@@ -243,18 +262,21 @@ def read_stage_columns(blk: BackendBlock, plan: StagePlan,
                        groups: list[int]) -> tuple[dict, int]:
     """The host-read phase: raw columns (sliced to `groups` on their
     axis) + the res-axis row count."""
+    from ..util.kerneltel import TEL
+
     pack = blk.pack
     span_ax = pack.axes[S.AX_SPAN]
     host: dict[str, np.ndarray] = {}
     n_res = 0
-    for name in plan.read_names:
-        pref = name.split(".", 1)[0]
-        ax = _AXIS_OF.get(pref)
-        if ax is None:
-            arr = pack.read(name)
-        else:
-            arr = pack.read_groups(name, groups) if span_ax.n_groups else pack.read(name)
-        host[name] = arr
+    with TEL.stage("stage:read_columns", columns=len(plan.read_names)):
+        for name in plan.read_names:
+            pref = name.split(".", 1)[0]
+            ax = _AXIS_OF.get(pref)
+            if ax is None:
+                arr = pack.read(name)
+            else:
+                arr = pack.read_groups(name, groups) if span_ax.n_groups else pack.read(name)
+            host[name] = arr
     for name, arr in host.items():
         if name.startswith("res."):
             n_res = max(n_res, arr.shape[0])
@@ -274,8 +296,9 @@ def stage_block(
 
     key = (tuple(needed), tuple(groups) if groups is not None else None)
     store: dict | None = getattr(blk, "_staged_cache", None) if cache else None
-    if store is not None:
-        hit = store.get(key)
+    hit = store.get(key) if store is not None else None
+    reason = "" if hit is not None or not cache else _miss_reason(store, key)
+    with TEL.stage("stage:lookup", hit=hit is not None, reason=reason):
         if hit is not None:
             TEL.staged_cache_hits.inc()
             # attribute the hit to the dequeue placement of the job
@@ -340,6 +363,13 @@ def assemble_stage(blk: BackendBlock, plan: StagePlan, groups: list[int],
                    host: dict, n_res: int) -> tuple[StagedBlock, dict, dict]:
     """The pad/assemble phase: owner-offset transforms, derived columns,
     bucket padding. Pure host numpy -- no IO, no device."""
+    from ..util.kerneltel import TEL
+
+    with TEL.stage("stage:assemble", block=blk.meta.block_id[:8]):
+        return _assemble(blk, plan, groups, host, n_res)
+
+
+def _assemble(blk, plan, groups, host, n_res):
     host = dict(host)  # owner-offset transforms mutate; callers may retry
     pack = blk.pack
     span_ax = pack.axes[S.AX_SPAN]
@@ -431,26 +461,23 @@ def upload_stage(blk: BackendBlock, plan: StagePlan, staged: StagedBlock,
                  padded: dict, real_rows: dict) -> StagedBlock:
     """The host->device phase: one batched transfer + the query-
     independent res->span materialization."""
-    import time as _time
-
     from ..util.kerneltel import TEL
 
-    t0_wall = _time.time()
-    # ONE batched transfer for the whole block: per-array device_puts
-    # each pay their own dispatch + link round trip
-    staged.cols = dict(zip(padded, jax.device_put(list(padded.values()))))
+    nbytes = sum(int(a.nbytes) for a in padded.values())
+    # THE host->device transfer, whether a warm staging miss or a
+    # stream-pipeline unit (whose `stream:upload` stage is around this
+    # whole call: ops/stream)
+    with TEL.stage("stage:upload", bytes=nbytes, block=blk.meta.block_id[:8]):
+        # ONE batched transfer for the whole block: per-array device_puts
+        # each pay their own dispatch + link round trip
+        staged.cols = dict(zip(padded, jax.device_put(list(padded.values()))))
     # telemetry: upload volume + padding waste (padded vs real rows
     # summed per column -- columns live on different axes)
-    nbytes = sum(int(a.nbytes) for a in padded.values())
     TEL.record_transfer(
         nbytes,
         sum(real_rows.values()),
         sum(int(a.shape[0]) for a in padded.values()),
     )
-    # timeline span for the active self-trace: this is THE host->device
-    # upload, whether a warm staging miss or a stream-pipeline unit
-    TEL.child_span("stream:upload", t0_wall, _time.time(),
-                   {"bytes": nbytes, "block": blk.meta.block_id[:8]})
 
     # materialize requested res columns at SPAN level: the res->span
     # broadcast gather is query-independent, so paying it once here

@@ -268,41 +268,36 @@ def _run_stages(u: StreamUnit, plan, cf, state: _PipeState | None):
 
         if state is not None and not state.wait_upload_turn(u.index):
             return None  # cancelled before the restage upload
-        t0 = _time.perf_counter()
-        staged = chunkpool.restage(u.blk.meta.block_id, _unit_pool_key(u))
+        with TEL.stage("stream:upload") as up:
+            staged = chunkpool.restage(u.blk.meta.block_id, _unit_pool_key(u))
+            up.counted = staged is not None
         if staged is not None:
-            TEL.record_stream_stage("upload", _time.perf_counter() - t0)
             return staged
         # evicted between plan and run: late-plan the cold fetch and
         # fall through to the normal stages (est_bytes stays 0 -- the
         # gate's one-always-admits rule bounds the raced unit)
         u.pool_hit = False
         cf = pack.plan_fetch(stage_fetch_wants(u.blk, plan, u.groups))
-    t0 = _time.perf_counter()
-    if cf is not None:
-        pack.fetch_ranges(cf)
-    TEL.record_stream_stage("fetch", _time.perf_counter() - t0)
+    with TEL.stage("stream:fetch"):
+        if cf is not None:
+            pack.fetch_ranges(cf)
     if state is not None and state.cancelled.is_set():
         return None
-    t0 = _time.perf_counter()
-    if cf is not None:
-        pack.decode_fetched(cf)
-    if not u.upload:
-        TEL.record_stream_stage("decompress", _time.perf_counter() - t0)
-        return True  # columns are cache-resident; host engines read them
-    groups = _unit_groups(u)
-    host, n_res = read_stage_columns(u.blk, plan, groups)
-    TEL.record_stream_stage("decompress", _time.perf_counter() - t0)
+    with TEL.stage("stream:decompress"):
+        if cf is not None:
+            pack.decode_fetched(cf)
+        if not u.upload:
+            return True  # columns are cache-resident; host engines read them
+        groups = _unit_groups(u)
+        host, n_res = read_stage_columns(u.blk, plan, groups)
     if state is not None and state.cancelled.is_set():
         return None
-    t0 = _time.perf_counter()
-    staged, padded, real_rows = assemble_stage(u.blk, plan, groups, host, n_res)
-    TEL.record_stream_stage("assemble", _time.perf_counter() - t0)
+    with TEL.stage("stream:assemble"):
+        staged, padded, real_rows = assemble_stage(u.blk, plan, groups, host, n_res)
     if state is not None and not state.wait_upload_turn(u.index):
         return None  # cancelled: no device work for abandoned units
-    t0 = _time.perf_counter()
-    upload_stage(u.blk, plan, staged, padded, real_rows)
-    TEL.record_stream_stage("upload", _time.perf_counter() - t0)
+    with TEL.stage("stream:upload"):
+        upload_stage(u.blk, plan, staged, padded, real_rows)
     return staged
 
 

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device import bucket, pad_rows
+from .device import bucket, pad_rows, scoped
 from .filter import Cond, Operands, T_TRACE, _cmp, _cond_mask
 from .hostfilter import eval_span_mask_host
 
@@ -50,6 +50,7 @@ def _compiled_ts(tree, conds: tuple[Cond, ...], table_idxs: tuple[int, ...],
     Trace-target conds gather through span.trace_sid."""
 
     @jax.jit
+    @scoped("timeseries")
     def run(cols, ops_i, ops_f, table_list, gid, val, vpres,
             t0_ms, step_ms, n_spans, n_buckets):
         tables = dict(zip(table_idxs, table_list))
@@ -141,7 +142,6 @@ def eval_timeseries_device(query, staged, operands: Operands,
     else:
         val_p = pres_p = np.zeros(0, np.float32)
     t0 = int(np.clip(t0_rel_ms, -(2**31) + 1, 2**31 - 1))
-    import time as _time
 
     from ..util import costmodel
     from ..util.kerneltel import TEL
@@ -149,7 +149,7 @@ def eval_timeseries_device(query, staged, operands: Operands,
     t0_i = np.int32(t0)
     step_i = np.int32(max(1, step_ms))
     ns_i, nb_i = np.int32(staged.n_spans), np.int32(n_buckets)
-    TEL.record_launch(
+    with TEL.launch(
         "timeseries",
         ("ts", tree, conds, table_idxs, has_val, staged.n_spans_b,
          staged.n_res_b, staged.n_traces_b, G_b, B_b),
@@ -157,13 +157,11 @@ def eval_timeseries_device(query, staged, operands: Operands,
         cost=lambda: costmodel.spec(fn, staged.cols, operands.ints,
                                     operands.floats, tabs, gid_p, val_p,
                                     pres_p, t0_i, step_i, ns_i, nb_i),
-    )
-    tw = _time.perf_counter()
-    outs = fn(staged.cols, operands.ints, operands.floats, tabs,
-              gid_p, val_p, pres_p,
-              t0_i, step_i, ns_i, nb_i)
-    res = tuple(np.asarray(o)[:n_groups, :n_buckets] for o in outs)
-    TEL.observe_device("timeseries", staged.n_spans_b, tw)
+    ):
+        outs = fn(staged.cols, operands.ints, operands.floats, tabs,
+                  gid_p, val_p, pres_p,
+                  t0_i, step_i, ns_i, nb_i)
+        res = tuple(np.asarray(o)[:n_groups, :n_buckets] for o in outs)
     return res
 
 

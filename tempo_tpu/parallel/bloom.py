@@ -17,6 +17,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..block.bloom import ShardedBloom
+from ..ops.device import scoped
 from .mesh import smap
 
 
@@ -32,7 +33,8 @@ def make_sharded_union(mesh, K: int, NS: int, W: int):
         gathered = jax.lax.all_gather(acc, "dp")
         return jax.lax.reduce(gathered, jnp.uint32(0), jax.lax.bitwise_or, dimensions=(0,))
 
-    fn = smap(local, mesh, in_specs=(P(("dp", "sp")),), out_specs=P())
+    fn = smap(scoped("mesh_bloom")(local), mesh, in_specs=(P(("dp", "sp")),),
+              out_specs=P())
     return jax.jit(fn)
 
 
@@ -48,19 +50,16 @@ def sharded_bloom_union(mesh, blooms: list[ShardedBloom]) -> ShardedBloom:
     for i, b in enumerate(blooms):
         stacked[i] = b.words
     fn = make_sharded_union(mesh, K, first.words.shape[0], first.words.shape[1])
-    import time as _time
 
     from ..util import costmodel
     from ..util.kerneltel import TEL
 
     stacked_j = jnp.asarray(stacked)
-    TEL.record_launch("mesh_bloom", ("union", K, first.words.shape), K,
-                      cost=lambda: costmodel.spec(fn, stacked_j, mesh=mesh))
-    t0 = _time.perf_counter()
-    out = ShardedBloom(first.n_shards, first.shard_bits)
-    from .mesh import DISPATCH_LOCK
+    with TEL.launch("mesh_bloom", ("union", K, first.words.shape), K,
+                      cost=lambda: costmodel.spec(fn, stacked_j, mesh=mesh)):
+        out = ShardedBloom(first.n_shards, first.shard_bits)
+        from .mesh import DISPATCH_LOCK
 
-    with DISPATCH_LOCK:  # collective programs must not interleave enqueues
-        out.words = np.asarray(fn(stacked_j))
-    TEL.observe_device("mesh_bloom", K, t0)
+        with DISPATCH_LOCK:  # collective programs must not interleave enqueues
+            out.words = np.asarray(fn(stacked_j))
     return out

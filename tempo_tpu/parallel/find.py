@@ -27,7 +27,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import smap
-from ..ops.device import bucket, pad_rows
+from ..ops.device import bucket, pad_rows, scoped
 from ..ops.find import bisect_ids
 
 
@@ -63,7 +63,7 @@ def make_sharded_find(mesh, B: int, T: int, Q: int):
         row = jax.lax.pmax(jax.lax.pmax(row, "sp"), "dp")
         return jnp.stack([best_blk, row], axis=-1)  # (Q, 2)
 
-    fn = smap(local, mesh,
+    fn = smap(scoped("mesh_find")(local), mesh,
         in_specs=(P(("dp", "sp")), P(("dp", "sp")), P()),
         out_specs=P(),
     )
@@ -83,7 +83,7 @@ def make_sharded_find_rows(mesh, B: int, T: int, Q: int):
     def local(ids_l, n_valid_l, queries):
         return jax.vmap(lambda a, nv: bisect_ids(a, queries, nv, n_steps))(ids_l, n_valid_l)
 
-    fn = smap(local, mesh,
+    fn = smap(scoped("mesh_find")(local), mesh,
         in_specs=(P(("dp", "sp")), P(("dp", "sp")), P()),
         out_specs=P(("dp", "sp")),
     )
@@ -101,21 +101,18 @@ def sharded_find_rows(mesh, id_code_arrays: list[np.ndarray], query_codes: np.nd
     Qb = bucket(q)
     queries = pad_rows(np.asarray(query_codes, np.int32), Qb, np.int32(-(2**31)))
     fn = make_sharded_find_rows(mesh, ids.shape[0], T, Qb)
-    import time as _time
 
     from ..util import costmodel
     from ..util.kerneltel import TEL
 
     ids_j, nv_j, q_j = jnp.asarray(ids), jnp.asarray(n_valid), jnp.asarray(queries)
-    TEL.record_launch(
+    with TEL.launch(
         "mesh_find", ("rows", ids.shape[0], T, Qb), T,
-        cost=lambda: costmodel.spec(fn, ids_j, nv_j, q_j, mesh=mesh))
-    t0 = _time.perf_counter()
-    from .mesh import DISPATCH_LOCK
+        cost=lambda: costmodel.spec(fn, ids_j, nv_j, q_j, mesh=mesh)):
+        from .mesh import DISPATCH_LOCK
 
-    with DISPATCH_LOCK:  # collective programs must not interleave enqueues
-        out = np.asarray(fn(ids_j, nv_j, q_j))
-    TEL.observe_device("mesh_find", T, t0)
+        with DISPATCH_LOCK:  # collective programs must not interleave enqueues
+            out = np.asarray(fn(ids_j, nv_j, q_j))
     return out[: len(id_code_arrays), :q]
 
 
@@ -145,21 +142,18 @@ def sharded_find(mesh, id_code_arrays: list[np.ndarray], query_codes: np.ndarray
     Qb = bucket(q)
     queries = pad_rows(np.asarray(query_codes, np.int32), Qb, np.int32(-(2**31)))
     fn = make_sharded_find(mesh, ids.shape[0], T, Qb)
-    import time as _time
 
     from ..util import costmodel
     from ..util.kerneltel import TEL
 
     ids_j, nv_j, q_j = jnp.asarray(ids), jnp.asarray(n_valid), jnp.asarray(queries)
-    TEL.record_launch(
+    with TEL.launch(
         "mesh_find", ("elect", ids.shape[0], T, Qb), T,
-        cost=lambda: costmodel.spec(fn, ids_j, nv_j, q_j, mesh=mesh))
-    t0 = _time.perf_counter()
-    from .mesh import DISPATCH_LOCK
+        cost=lambda: costmodel.spec(fn, ids_j, nv_j, q_j, mesh=mesh)):
+        from .mesh import DISPATCH_LOCK
 
-    with DISPATCH_LOCK:  # collective programs must not interleave enqueues
-        out = np.asarray(fn(ids_j, nv_j, q_j))[:q]
-    TEL.observe_device("mesh_find", T, t0)
+        with DISPATCH_LOCK:  # collective programs must not interleave enqueues
+            out = np.asarray(fn(ids_j, nv_j, q_j))[:q]
     out = out.astype(np.int32, copy=True)
     out[out[:, 0] < 0] = -1  # normalize misses to (-1, -1)
     return out

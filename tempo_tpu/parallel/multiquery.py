@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.device import PAD_I32
+from ..ops.device import PAD_I32, scoped
 from ..ops.multiquery import ProgramShape, _cmp_code
 from .mesh import smap
 
@@ -126,7 +126,8 @@ def make_mesh_multiquery(mesh, shape: ProgramShape, q_b: int,
 
     row_spec = P(None, axes)  # row axis over every device, dp-major
     in_specs = (row_spec, P(), P(), P(), P(), P())
-    fn = smap(local, mesh, in_specs=in_specs, out_specs=(P(), P()))
+    fn = smap(scoped("mesh_multiquery")(local), mesh, in_specs=in_specs,
+              out_specs=(P(), P()))
     return jax.jit(fn)
 
 
@@ -146,7 +147,6 @@ def mesh_eval_multiquery(mesh, lowered: list, staged, progs: dict):
     arrays: the demux path slices per-query rows and mixing the mesh
     program's replicated outputs with single-device staged arrays in a
     later jit would force a device-mismatch reshard anyway."""
-    import time as _time
 
     from ..util import costmodel
     from ..util.kerneltel import TEL
@@ -164,23 +164,17 @@ def mesh_eval_multiquery(mesh, lowered: list, staged, progs: dict):
                  else jnp.zeros((1, staged.n_traces_b), jnp.int32))
     args = (span_mat, trace_mat, staged.cols["trace.span_off"], progs,
             np.int32(staged.n_spans), np.int32(staged.n_traces))
-    TEL.record_launch(
+    with TEL.launch(
         "mesh_multiquery",
         ("mmq", shape, q_b, staged.n_spans_b, staged.n_traces_b,
          tuple(mesh.shape.items())),
         staged.n_spans_b,
-        cost=lambda: costmodel.spec(fn, *args, mesh=mesh))
-    t0 = _time.perf_counter()
-    t0_wall = _time.time()
-    with DISPATCH_LOCK:  # collective programs must not interleave enqueues
-        tm, counts = fn(*args)
-        out = np.asarray(tm), np.asarray(counts)
-    TEL.observe_device("mesh_multiquery", staged.n_spans_b, t0)
+        cost=lambda: costmodel.spec(fn, *args, mesh=mesh),
+        occupancy=len(lowered), devices=int(mesh.devices.size)) as ln:
+        with DISPATCH_LOCK:  # collective programs must not interleave enqueues
+            tm, counts = fn(*args)
+            out = np.asarray(tm), np.asarray(counts)
+        comm = costmodel.COST.comm_for("mesh_multiquery", str(staged.n_spans_b))
+        ln.attrs["comm_bytes"] = int(sum(comm.values()))
     TEL.record_mesh_batch(len(lowered))
-    comm = costmodel.COST.comm_for("mesh_multiquery", str(staged.n_spans_b))
-    TEL.child_span(
-        "mesh:batch", t0_wall, _time.time(),
-        {"occupancy": len(lowered), "bucket": staged.n_spans_b,
-         "devices": int(mesh.devices.size),
-         "comm_bytes": int(sum(comm.values()))})
     return out

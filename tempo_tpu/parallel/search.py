@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.device import bucket
+from ..ops.device import bucket, scoped
 from ..ops.filter import (
     _ATTR_VALUE_COL,
     _VT_CODE,
@@ -329,7 +329,8 @@ def make_sharded_search(mesh, tree, conds: tuple[Cond, ...], col_names: tuple[st
             in_specs.append(P("dp", "sp"))  # row axes shard over sp
         else:
             in_specs.append(P("dp"))
-    fn = smap(local, mesh, in_specs=tuple(in_specs), out_specs=(P("dp"), P("dp")))
+    fn = smap(scoped("mesh_search")(local), mesh, in_specs=tuple(in_specs),
+              out_specs=(P("dp"), P("dp")))
     return jax.jit(fn)
 
 
@@ -397,7 +398,6 @@ def sharded_search(mesh, tree, conds, operands, cols: dict[str, np.ndarray],
     fn = make_sharded_search(mesh, tree, conds, names, B, S, R, NT, table_idxs,
                              pack=pack)
     arrays = [jnp.asarray(tabs[i]) for i in table_idxs] + [jnp.asarray(cols[n]) for n in names]
-    import time as _time
 
     from ..util import costmodel
     from ..util.kerneltel import TEL
@@ -408,23 +408,19 @@ def sharded_search(mesh, tree, conds, operands, cols: dict[str, np.ndarray],
     # the legacy (unpacked) program keeps its own costmodel op label so
     # the comm-shrink bench can read both variants' walker prices
     op = "mesh_search" if pack else "mesh_search_nopack"
-    TEL.record_launch(
+    with TEL.launch(
         op, ("search", tree, conds, names, B, S, R, NT, table_idxs, pack), S,
         cost=lambda: costmodel.spec(fn, ints_j, floats_j, nsp_j, *arrays,
-                                    mesh=mesh))
-    t0 = _time.perf_counter()
-    t0_wall = _time.time()
-    from .mesh import DISPATCH_LOCK
+                                    mesh=mesh),
+            blocks=B) as ln:
+        from .mesh import DISPATCH_LOCK
 
-    with DISPATCH_LOCK:  # collective programs must not interleave enqueues
-        tm, sc = fn(ints_j, floats_j, nsp_j, *arrays)
-        out = np.asarray(tm), np.asarray(sc)
-    TEL.observe_device(op, S, t0)
-    # timeline: the mesh leg with its statically-priced collective bytes
-    # (costmodel comm walker; zeros until the background capture lands)
-    comm = costmodel.COST.comm_for(op, str(S))
-    TEL.child_span(
-        "mesh:search", t0_wall, _time.time(),
-        {"blocks": B, "bucket": S, "comm_bytes": int(sum(comm.values())),
-         **{f"comm.{c}": int(b) for c, b in sorted(comm.items())}})
+        with DISPATCH_LOCK:  # collective programs must not interleave enqueues
+            tm, sc = fn(ints_j, floats_j, nsp_j, *arrays)
+            out = np.asarray(tm), np.asarray(sc)
+        # timeline: the mesh leg with its statically-priced collective bytes
+        # (costmodel comm walker; zeros until the background capture lands)
+        comm = costmodel.COST.comm_for(op, str(S))
+        ln.attrs.update({"comm_bytes": int(sum(comm.values())),
+                         **{f"comm.{c}": int(b) for c, b in sorted(comm.items())}})
     return out

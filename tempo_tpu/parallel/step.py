@@ -14,6 +14,7 @@ from functools import lru_cache
 
 import jax
 
+from ..ops.device import scoped
 from ..ops.filter import normalize_tree
 from .bloom import make_sharded_union
 from .find import make_sharded_find
@@ -46,7 +47,7 @@ def distributed_query_step(mesh, tree, conds, col_names: tuple[str, ...],
         bu = union_fn(blooms)
         return hits, tm, sc, bu
 
-    fn = jax.jit(step)
+    fn = jax.jit(scoped("mesh_step")(step))
 
     def launcher(ids, n_valid, queries, ops_i, ops_f, n_spans, col_arrays, blooms):
         """Thin telemetry shim over the jitted step: the driver calls
@@ -58,14 +59,10 @@ def distributed_query_step(mesh, tree, conds, col_names: tuple[str, ...],
         from ..util.kerneltel import TEL
 
         args = (ids, n_valid, queries, ops_i, ops_f, n_spans, col_arrays, blooms)
-        TEL.record_launch(
+        with TEL.launch(
             "mesh_step", ("step", B, T, Q, S, R, NT, K, NS, W), S,
-            cost=lambda: costmodel.spec(fn, *args, mesh=mesh))
-        import time as _time
-
-        t0 = _time.perf_counter()
-        out = fn(*args)
-        TEL.observe_device("mesh_step", S, t0)
+            cost=lambda: costmodel.spec(fn, *args, mesh=mesh)):
+            out = fn(*args)
         return out
 
     return launcher

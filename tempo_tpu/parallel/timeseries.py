@@ -29,6 +29,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from ..ops.filter import Cond, T_RES, T_SPAN, T_TRACE
+from ..ops.device import scoped
 from .mesh import smap
 from .search import _cmp_b, _stack_operands
 
@@ -126,7 +127,7 @@ def make_sharded_timeseries(mesh, tree, conds: tuple[Cond, ...],
     in_specs += [P("dp")] * (len(table_idxs) + len(col_names))
     assert len(in_specs) == n_in
     n_out = 5 if has_val else 1
-    fn = smap(local, mesh, in_specs=tuple(in_specs),
+    fn = smap(scoped("mesh_timeseries")(local), mesh, in_specs=tuple(in_specs),
               out_specs=tuple([P()] * n_out) if n_out > 1 else (P(),))
     return jax.jit(fn)
 
@@ -160,7 +161,6 @@ def sharded_timeseries(mesh, tree, conds, operands, cols: dict[str, np.ndarray],
         pres = np.zeros((B, 1), bool)
     arrays = [jnp.asarray(tabs[i]) for i in table_idxs]
     arrays += [jnp.asarray(cols[n]) for n in names]
-    import time as _time
 
     from ..util import costmodel
     from ..util.kerneltel import TEL
@@ -173,15 +173,13 @@ def sharded_timeseries(mesh, tree, conds, operands, cols: dict[str, np.ndarray],
         jnp.asarray(np.asarray(gid, np.int32)),
         jnp.asarray(np.asarray(val, np.float32)),
         jnp.asarray(np.asarray(pres, bool)), *arrays)
-    TEL.record_launch(
+    with TEL.launch(
         "mesh_timeseries",
         ("ts", tree, conds, names, has_val, G_b, NB_b, NT, B, S, table_idxs), S,
-        cost=lambda: costmodel.spec(fn, *call_args, mesh=m1))
-    tw = _time.perf_counter()
-    from .mesh import DISPATCH_LOCK
+        cost=lambda: costmodel.spec(fn, *call_args, mesh=m1)):
+        from .mesh import DISPATCH_LOCK
 
-    with DISPATCH_LOCK:  # collective programs must not interleave enqueues
-        outs = fn(*call_args)
-        res = tuple(np.asarray(o)[:n_groups, :n_buckets] for o in outs)
-    TEL.observe_device("mesh_timeseries", S, tw)
+        with DISPATCH_LOCK:  # collective programs must not interleave enqueues
+            outs = fn(*call_args)
+            res = tuple(np.asarray(o)[:n_groups, :n_buckets] for o in outs)
     return res

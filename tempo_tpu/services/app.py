@@ -27,6 +27,7 @@ from ..db.search import SearchRequest
 from ..db.tempodb import TempoDB, TempoDBConfig
 from ..db.wal import WAL
 from ..ring.ring import InMemoryKV, Lifecycler, Ring
+from ..util.kerneltel import TEL
 from ..util.traceid import parse_trace_id
 from ..wire import otlp_json
 from ..wire.model import Trace
@@ -406,14 +407,15 @@ class App:
         that identity-keyed cache before the tap item was enqueued, so
         this is a pure cache read with ZERO extra proto decodes
         (ColumnarIngest.decodes proves it) -- and fold the window."""
-        col = self.ingester.instance(tenant).columnar
-        cols = []
-        for seg in segs:
-            feat = col.features_for(seg)
-            if feat.spans is not None:
-                cols.append(feat.spans)
-        if cols:
-            self.generator.push_window(tenant, cols, col.dict, push_ts)
+        with TEL.stage("generator:window", segments=len(segs)):
+            col = self.ingester.instance(tenant).columnar
+            cols = []
+            for seg in segs:
+                feat = col.features_for(seg)
+                if feat.spans is not None:
+                    cols.append(feat.spans)
+            if cols:
+                self.generator.push_window(tenant, cols, col.dict, push_ts)
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> None:
@@ -634,13 +636,14 @@ def _make_handler(app: App):
                   headers: dict | None = None):
             if isinstance(body, str):
                 body = body.encode()
-            self.send_response(code)
-            self.send_header("Content-Type", ctype)
-            self.send_header("Content-Length", str(len(body)))
-            for k, v in (headers or {}).items():
-                self.send_header(k, v)
-            self.end_headers()
-            self.wfile.write(body)
+            with TEL.stage("http:write", bytes=len(body), status=code):
+                self.send_response(code)
+                self.send_header("Content-Type", ctype)
+                self.send_header("Content-Length", str(len(body)))
+                for k, v in (headers or {}).items():
+                    self.send_header(k, v)
+                self.end_headers()
+                self.wfile.write(body)
 
         @staticmethod
         def _cache_headers() -> dict:
@@ -717,6 +720,10 @@ def _make_handler(app: App):
             return bool(tok) and self.headers.get("X-Tempo-Internal-Token", "") == tok
 
         # ----------------------------------------------------------- GET
+        # The served routes are rooted where they branch off, in an
+        # `http:<route>` stage that ends with the reply's last byte: every
+        # request in flight is under one, so a device-trace idle gap no
+        # `tempo/http:*` annotation covers had nothing to serve.
         def do_GET(self):
             u = urlparse(self.path)
             q = {k: v[0] for k, v in parse_qs(u.query).items()}
@@ -772,7 +779,6 @@ def _make_handler(app: App):
                     # shapes ran
                     from ..chaos import plane as chaos_plane
                     from ..util.breaker import breakers_snapshot
-                    from ..util.kerneltel import TEL
 
                     out = chaos_plane.status()
                     out["breakers"] = breakers_snapshot()
@@ -865,7 +871,11 @@ def _make_handler(app: App):
                     # for ?seconds=N while serving continues and publish
                     # the zipped trace directory as an artifact (fetch
                     # via /debug/profile/artifact/<id> or
-                    # `tempo-tpu-cli profile device`)
+                    # `tempo-tpu-cli profile device`). Device planes,
+                    # XLA runtime events and the tempo/<layer>:<stage>
+                    # annotations; ?python=1 adds jax's Python tracer
+                    # (frames, at ~14x on pure-Python code and a stop
+                    # that freezes every thread for seconds)
                     if not self._authorized_internal():
                         return self._err(403, "forbidden")
                     from ..util.profiler import PROF, ProfilerUnavailable
@@ -877,7 +887,8 @@ def _make_handler(app: App):
                     if not app._profile_lock.acquire(blocking=False):
                         return self._err(409, "a profile is already running")
                     try:
-                        aid, summary = PROF.capture_device_profile(secs)
+                        aid, summary = PROF.capture_device_profile(
+                            secs, python=q.get("python", "") in ("1", "true"))
                     except ProfilerUnavailable as e:
                         return self._err(503, f"device profiler: {e}")
                     finally:
@@ -905,7 +916,8 @@ def _make_handler(app: App):
                 tenant = app.tenant_of(self.headers, read=True)
                 m = re.fullmatch(r"/api/traces/([0-9a-fA-F]+)", u.path)
                 if m:
-                    return self._trace_by_id(tenant, m.group(1), q)
+                    with TEL.stage("http:find"):
+                        return self._trace_by_id(tenant, m.group(1), q)
                 m = re.fullmatch(r"/jaeger/api/traces/([0-9a-fA-F]+)", u.path)
                 if m:  # tempo-query shim: Jaeger UI JSON
                     from ..util.traceid import parse_trace_id
@@ -916,9 +928,11 @@ def _make_handler(app: App):
                         return self._err(404, "trace not found")
                     return self._send(200, json.dumps(trace_to_jaeger(tr)))
                 if u.path == "/api/search":
-                    return self._search(tenant, q)
+                    with TEL.stage("http:search"):
+                        return self._search(tenant, q)
                 if u.path == "/api/metrics/query_range":
-                    return self._metrics_query_range(tenant, q)
+                    with TEL.stage("http:metrics"):
+                        return self._metrics_query_range(tenant, q)
                 if u.path == "/api/search/tags":
                     tags = app.querier.search_tags(tenant)
                     return self._send(200, json.dumps({"tagNames": tags}))
@@ -942,7 +956,9 @@ def _make_handler(app: App):
             hdrs = self._cache_headers()
             if tr is None:
                 return self._err(404, "trace not found")
-            return self._send(200, otlp_json.dumps(tr), headers=hdrs)
+            with TEL.stage("http:encode", spans=tr.span_count()):
+                body = otlp_json.dumps(tr)
+            return self._send(200, body, headers=hdrs)
 
         def _metrics_query_range(self, tenant: str, q: dict):
             """GET /api/metrics/query_range?q=...&start=...&end=...&step=...
@@ -991,8 +1007,9 @@ def _make_handler(app: App):
                 # execution-time request errors (e.g. by() cardinality
                 # over the accumulator budget) are the caller's to fix
                 return self._err(400, f"query_range failed: {e}")
-            return self._send(200, json.dumps(to_prometheus(resp)),
-                              headers=self._cache_headers())
+            with TEL.stage("http:encode"):
+                body = json.dumps(to_prometheus(resp))
+            return self._send(200, body, headers=self._cache_headers())
 
         def _search(self, tenant: str, q: dict):
             tags = {}
@@ -1065,9 +1082,8 @@ def _make_handler(app: App):
                 return self._stream_json(
                     app.frontend.search_stream(tenant, req), sse)
             resp = app.frontend.search(tenant, req)
-            return self._send(
-                200,
-                json.dumps(
+            with TEL.stage("http:encode", traces=len(resp.traces)):
+                body = json.dumps(
                     {
                         "traces": [t.to_dict() for t in resp.traces],
                         "metrics": {
@@ -1075,9 +1091,8 @@ def _make_handler(app: App):
                             "inspectedSpans": str(resp.inspected_spans),
                         },
                     }
-                ),
-                headers=self._cache_headers(),
-            )
+                )
+            return self._send(200, body, headers=self._cache_headers())
 
         # ---------------------------------------------------------- POST
         def do_POST(self):
@@ -1106,14 +1121,15 @@ def _make_handler(app: App):
                         return self._err(404, f"target {app.cfg.target} does not ingest")
                     tenant = app.tenant_of(self.headers)
                     ctype = self.headers.get("Content-Type", "")
-                    if "json" in ctype:
-                        tr = otlp_json.loads(body)
-                        app.distributor.push(tenant, tr.resource_spans)
-                    else:
-                        # proto bodies take the raw fast path (native
-                        # scan + splice; 400 if undecodable)
-                        app.distributor.push_raw(tenant, body)
-                    return self._send(200, "{}")
+                    with TEL.stage("http:push", bytes=len(body)):
+                        if "json" in ctype:
+                            tr = otlp_json.loads(body)
+                            app.distributor.push(tenant, tr.resource_spans)
+                        else:
+                            # proto bodies take the raw fast path (native
+                            # scan + splice; 400 if undecodable)
+                            app.distributor.push_raw(tenant, body)
+                        return self._send(200, "{}")
                 if u.path == "/api/traces":  # Jaeger collector thrift ingest
                     if app.distributor is None:
                         return self._err(404, f"target {app.cfg.target} does not ingest")
@@ -1137,9 +1153,10 @@ def _make_handler(app: App):
                 if u.path == "/flush":
                     if not self._authorized_internal():
                         return self._err(401, "missing or wrong internal token")
-                    if app.ingester:
-                        app.ingester.flush_all()
-                    return self._send(204, "")
+                    with TEL.stage("http:flush"):
+                        if app.ingester:
+                            app.ingester.flush_all()
+                        return self._send(204, "")
                 if u.path == "/shutdown":
                     if not self._authorized_internal():
                         return self._err(401, "missing or wrong internal token")

@@ -608,9 +608,8 @@ class MetricsGenerator:
         edges = 0
         spans = 0
         for pname, p in procs.items():
-            t0 = time.perf_counter()
-            r = p.push_columns(cols, ldict, now)
-            TEL.record_generator_stage(pname, time.perf_counter() - t0)
+            with TEL.stage("generator:" + pname):
+                r = p.push_columns(cols, ldict, now)
             if p is sg:
                 edges = r
             else:

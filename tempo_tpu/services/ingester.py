@@ -180,9 +180,17 @@ class Instance:
     # ---------------------------------------------------------------- push
     def push_segments(self, batch: list[tuple[bytes, int, int, bytes]]) -> None:
         """batch: [(trace_id, start_s, end_s, segment)]"""
+        from ..util.kerneltel import TEL
+
         lim = self.overrides.for_tenant(self.tenant)
         now = time.time()
-        with self.lock:
+        # what an acknowledgement waits on when a cut or a decode holds
+        # the instance: its own stage, not a longer wal_append
+        wait = TEL.stage("ingest:lock_wait")
+        wait.__enter__()
+        self.lock.acquire()
+        try:
+            wait.__exit__(None, None, None)
             # phase 1: validate the WHOLE batch before touching any state,
             # so a limit error never leaves a half-applied batch behind
             # (a retried batch would duplicate spans otherwise)
@@ -208,24 +216,18 @@ class Instance:
                 lt.start_s = min(lt.start_s or s, s)
                 lt.end_s = max(lt.end_s, e)
             self.live_gen += 1
-            t_wal = time.perf_counter()
-            if hasattr(self.head, "append_window"):
-                # columnar WAL: the whole push window is ONE framed
-                # record -- one CRC, one file write on the ack path
-                self.head.append_window(batch)
-            else:
-                for tid, s, e, seg in batch:
-                    self.head.append(tid, s, e, seg)
-            self.head.flush()
-            t_wal = time.perf_counter() - t_wal
-        try:
-            from ..util.kerneltel import TEL
-
-            TEL.record_ingest_stage("wal_append", t_wal)
-            TEL.record_ingest_window(len(batch),
-                                     sum(len(seg) for *_, seg in batch))
-        except Exception:
-            pass
+            with TEL.stage("ingest:wal_append", traces=len(batch)):
+                if hasattr(self.head, "append_window"):
+                    # columnar WAL: the whole push window is ONE framed
+                    # record -- one CRC, one file write on the ack path
+                    self.head.append_window(batch)
+                else:
+                    for tid, s, e, seg in batch:
+                        self.head.append(tid, s, e, seg)
+                self.head.flush()
+        finally:
+            self.lock.release()
+        TEL.record_ingest_window(len(batch), sum(len(seg) for *_, seg in batch))
         if self.live_engine is not None:
             # staging-lag clock only -- the delta decode itself happens
             # at the next refresh, OFF this push path
@@ -274,6 +276,8 @@ class Instance:
     def cut_block_if_ready(self, force: bool = False, now: float | None = None):
         """Cut set -> columnar block in the backend; WAL head rotates
         (instance.go:266-289 + CompleteBlock)."""
+        from ..util.kerneltel import TEL
+
         now = now or time.time()
         with self.lock:
             if not self.cut:
@@ -291,53 +295,39 @@ class Instance:
             size = self.head.size_bytes()
             if not (force or age >= self.cfg.max_block_age_s or size >= self.cfg.max_block_bytes):
                 return None
-            t_cut = time.perf_counter()
-            traces = []
-            cut_snapshot = dict(self.cut)
-            for tid, lt in self.cut.items():
-                parts = [segment_to_trace(s) for s in lt.segments]
-                traces.append((tid, sort_trace(combine_traces(parts)) if len(parts) > 1 else parts[0]))
-            self.flushing.update(cut_snapshot)  # stay visible during the write
-            self.cut.clear()
-            # live traces staying behind move to the NEW head's WAL file so
-            # the old file can be deleted after the block lands
-            old_head = self.head
-            self.head = self.wal.new_block(self.tenant, self.cfg.wal_version)
-            self.head_created = now
-            carry = [(lt.trace_id, lt.start_s, lt.end_s, seg)
-                     for lt in self.live.values() for seg in lt.segments]
-            if hasattr(self.head, "append_window"):
-                if carry:
-                    self.head.append_window(carry)
-                    # carried segments were already decoded for staging:
-                    # checkpoint those features into the fresh file so a
-                    # crash-now replay skips their proto decode too
-                    self.head.flush_features(self.columnar.cached,
-                                             self.columnar.dict)
-            else:
-                for tid, s, e, seg in carry:
-                    self.head.append(tid, s, e, seg)
-            # the new head is about to become the ONLY wal copy of the
-            # carried-over live traces (the old file is deleted once the
-            # block lands): force the fsync
-            self.head.flush(sync=True)
-            t_cut = time.perf_counter() - t_cut
+            with TEL.stage("ingest:cut", traces=len(self.cut)):
+                traces = []
+                cut_snapshot = dict(self.cut)
+                for tid, lt in self.cut.items():
+                    parts = [segment_to_trace(s) for s in lt.segments]
+                    traces.append((tid, sort_trace(combine_traces(parts)) if len(parts) > 1 else parts[0]))
+                self.flushing.update(cut_snapshot)  # stay visible during the write
+                self.cut.clear()
+                # live traces staying behind move to the NEW head's WAL file so
+                # the old file can be deleted after the block lands
+                old_head = self.head
+                self.head = self.wal.new_block(self.tenant, self.cfg.wal_version)
+                self.head_created = now
+                carry = [(lt.trace_id, lt.start_s, lt.end_s, seg)
+                         for lt in self.live.values() for seg in lt.segments]
+                if hasattr(self.head, "append_window"):
+                    if carry:
+                        self.head.append_window(carry)
+                        # carried segments were already decoded for staging:
+                        # checkpoint those features into the fresh file so a
+                        # crash-now replay skips their proto decode too
+                        self.head.flush_features(self.columnar.cached,
+                                                 self.columnar.dict)
+                else:
+                    for tid, s, e, seg in carry:
+                        self.head.append(tid, s, e, seg)
+                # the new head is about to become the ONLY wal copy of the
+                # carried-over live traces (the old file is deleted once the
+                # block lands): force the fsync
+                self.head.flush(sync=True)
         try:
-            from ..util.kerneltel import TEL
-
-            TEL.record_ingest_stage("cut", t_cut)
-        except Exception:
-            pass
-        try:
-            t_flush = time.perf_counter()
-            with timed(FLUSH_DURATION):
+            with TEL.stage("ingest:flush", traces=len(traces)), timed(FLUSH_DURATION):
                 meta = self.db.write_block(self.tenant, traces)
-            try:
-                from ..util.kerneltel import TEL
-
-                TEL.record_ingest_stage("flush", time.perf_counter() - t_flush)
-            except Exception:
-                pass
         except Exception:
             FLUSH_FAILURES.inc()
             # block write failed: restore the cut set for the next retry;

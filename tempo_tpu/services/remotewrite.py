@@ -134,19 +134,12 @@ class RemoteWriter:
         self._thread: threading.Thread | None = None
 
     def push_once(self) -> bool:
-        t0 = time.perf_counter()
-        try:
-            return self._push_once()
-        finally:
-            # shipping rides the generator stage histogram so /status/
-            # kernels shows the full pipeline: fold stages + export
-            try:
-                from ..util.kerneltel import TEL
+        from ..util.kerneltel import TEL
 
-                TEL.record_generator_stage("remote_write",
-                                           time.perf_counter() - t0)
-            except Exception:
-                pass
+        # shipping rides the generator stage histogram so /status/
+        # kernels shows the full pipeline: fold stages + export
+        with TEL.stage("generator:remote_write"):
+            return self._push_once()
 
     def _push_once(self) -> bool:
         series = parse_exposition(self.generator.metrics_text())

@@ -26,11 +26,15 @@ ops/ and parallel/:
     entry carrying its self-trace id so a slow query links straight to
     its flame view.
 
-Self-trace plumbing: the frontend parks the active SelfTracer trace in
-a contextvar (set_active_trace) around local job execution; engine code
-deep in db/ attaches per-block child spans with kernel attrs
-(engine=device|host, bucket=..., compile=true) through child_span()
-without any signature threading. Everything here is advisory -- no
+One span primitive, three sinks: `with TEL.stage("<layer>:<stage>",
+**attrs):` times its body into the cumulative `stages` table of
+/status/kernels (always on), records a child span on the self-trace the
+frontend parked in a contextvar (set_active_trace) and is the ambient
+parent of its body, and wraps it in a jax.profiler.TraceAnnotation
+("tempo/<name>", inert without a profiler session) so a device trace's
+idle gaps are owned by layers. A stage is per block or per request,
+never per row. child_span() stays for retroactive leaves (`verify`,
+queue-wait, the result-cache hits). Everything here is advisory -- no
 method may raise into the query path.
 """
 
@@ -38,6 +42,7 @@ from __future__ import annotations
 
 import contextvars
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -84,9 +89,83 @@ def _esc_label(v: str) -> str:
     return escape_label(v)
 
 
+class _Stage:
+    """One timed stage (TEL.stage): counter + self-trace span +
+    profiler annotation. `attrs` may be filled in the body; the span
+    records them as they are on exit, the annotation as on entry.
+    `seconds` holds the duration after exit; a body that finds it did
+    none of the stage's work sets `counted = False` and the table and
+    its histogram get no sample."""
+
+    __slots__ = ("tel", "name", "attrs", "t0", "seconds", "counted", "_span", "_ann")
+
+    def __init__(self, tel, name: str, attrs: dict):
+        self.tel, self.name, self.attrs = tel, name, attrs
+        self.seconds, self.counted = 0.0, True
+
+    def __enter__(self):
+        self._span = self._ann = None
+        try:
+            t = _active_trace.get()
+            if t is not None:
+                self._span = t.span(self.name, self.attrs)
+                self._span.__enter__()
+            # only once THIS process runs jax (a session needs it anyway):
+            # control-plane processes keep stages at two clock reads
+            prof = sys.modules.get("jax.profiler")
+            if prof is not None:
+                self._ann = prof.TraceAnnotation("tempo/" + self.name, **self.attrs)
+                self._ann.__enter__()
+        except Exception:
+            pass  # observability must never fail the body
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = time.perf_counter() - self.t0
+        try:
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
+            if self._span is not None:
+                self._span.__exit__(exc_type, exc, tb)
+            if self.counted:
+                self.tel._add_stage(self.name, self.seconds)
+        except Exception:
+            pass
+        return False
+
+
+class _Launch(_Stage):
+    """One kernel dispatch (TEL.launch): a `kernel:launch` stage whose
+    exit also closes the op's device-time window (observe_device)."""
+
+    __slots__ = ()
+
+    def sync(self, out):
+        """Wait for `out` inside the window when sync timing is on, so
+        it covers device execution, not just the dispatch."""
+        try:
+            if self.tel.sync_timing():
+                import jax
+
+                jax.block_until_ready(out)
+        except Exception:
+            pass
+        return out
+
+    def __exit__(self, exc_type, exc, tb):
+        _Stage.__exit__(self, exc_type, exc, tb)
+        if exc_type is None:
+            self.tel.observe_device(self.attrs["op"], self.attrs["bucket"], self.t0)
+        return False
+
+
 class KernelTelemetry:
     def __init__(self):
         self._lock = threading.Lock()
+        # TEL.stage: name -> [count, seconds, histogram | None, labels]
+        self._stages: dict[str, list] = {}
+        self._stages_at_session: dict | None = None  # mark_session
         self._tls = threading.local()
         self._sync: bool | None = None
         self.compiles = Counter(
@@ -200,7 +279,7 @@ class KernelTelemetry:
             "tempo_stream_bytes_inflight",
             help="estimated host bytes of admitted stream pipeline units")
         self._stream: dict = {
-            "runs": 0, "wall_seconds": 0.0, "stage_seconds": {},
+            "runs": 0, "wall_seconds": 0.0,
             "units": 0, "errors": 0, "cancelled": 0,
         }
         # cache-affinity scheduling (services/frontend): dequeue
@@ -249,7 +328,7 @@ class KernelTelemetry:
             help="write-path stage wall seconds by stage "
                  "(decode/wal_append/stage_delta/cut/flush)")
         self._ingest: dict = {
-            "stages": {}, "windows": 0, "window_traces": 0,
+            "windows": 0, "window_traces": 0,
             "window_bytes": 0, "feature_entries": 0,
             "replays": {"records": 0, "features": 0, "torn": 0},
         }
@@ -270,7 +349,7 @@ class KernelTelemetry:
             "tempo_generator_series_shed_total",
             help="spans shed by the per-tenant max-active-series limit")
         self._generator: dict = {
-            "stages": {}, "windows": 0, "window_spans": 0,
+            "windows": 0, "window_spans": 0,
             "edges_completed": 0, "unpaired": 0, "expired": 0,
             "shed": {}, "freshness_count": 0, "freshness_sum": 0.0,
             "freshness_max": 0.0,
@@ -402,6 +481,58 @@ class KernelTelemetry:
 
                 self._sync = link_rtt_ms() <= SYNC_RTT_MS
         return self._sync
+
+    # ------------------------------------------------------------ stages
+    def stage(self, name: str, **attrs) -> _Stage:
+        """`with TEL.stage("<layer>:<stage>", **attrs):` -- see the
+        module docstring. Names are a fixed vocabulary (they key a
+        table); what varies (block id, bucket, bytes) is an attr."""
+        return _Stage(self, name, attrs)
+
+    def launch(self, op: str, key, bucket, cost=None, **attrs) -> _Launch:
+        """`with TEL.launch(op, key, bucket, cost=...) as ln: out = fn(...)`
+        -- record_launch, a `kernel:launch` stage (op, bucket, compile)
+        around the dispatch, and the op's device-time window on exit.
+        Dispatch-only programs call `ln.sync(out)` before leaving."""
+        new = self.record_launch(op, key, bucket, cost)
+        return _Launch(self, "kernel:launch",
+                       dict(attrs, op=op, bucket=str(bucket), compile=new))
+
+    def _add_stage(self, name: str, seconds: float) -> None:
+        with self._lock:
+            row = self._stages.get(name)
+            if row is None:
+                # the stage families with a histogram of their own
+                # (dashboards and alerts in ops/ read them by `stage`)
+                layer, _, stage = name.partition(":")
+                hist = {"ingest": self.ingest_stage_time,
+                        "stream": self.stream_stage_time,
+                        "generator": self.generator_stage_time}.get(layer)
+                row = self._stages[name] = [0, 0.0, hist, f'stage="{stage}"']
+            row[0] += 1
+            row[1] += seconds
+        if row[2] is not None:
+            row[2].observe(seconds, row[3], exemplar=self._exemplar_tid())
+
+    def mark_session(self) -> None:
+        """A device-trace session starts: keep the table as it stands
+        (`stages_at_session`), so that a reader can take what ran before
+        the session apart from what ran beside its stop (seconds of CPU
+        next to serving: PERF.md)."""
+        self._stages_at_session = self.stage_stats()
+
+    def stage_stats(self, layer: str | None = None) -> dict:
+        """The cumulative stages table: {name: {count, seconds}}; with
+        `layer`, that layer's rows keyed by the bare stage name (the
+        `ingest.stages` / `generator.stages` / `stream.stage_seconds`
+        shapes of /status/kernels)."""
+        with self._lock:
+            rows = {n: (r[0], r[1]) for n, r in self._stages.items()}
+        if layer is not None:
+            rows = {n.partition(":")[2]: r for n, r in rows.items()
+                    if n.startswith(layer + ":")}
+        return {n: {"count": c, "seconds": round(s, 6)}
+                for n, (c, s) in sorted(rows.items())}
 
     # ----------------------------------------------------------- kernels
     def record_launch(self, op: str, key, bucket, cost=None) -> bool:
@@ -691,29 +822,6 @@ class KernelTelemetry:
         return c
 
     # ------------------------------------------------- cold-read streaming
-    # stages that emit timeline spans from this chokepoint; "upload"
-    # spans come from ops/stage.upload_stage (which knows the bytes and
-    # also covers warm staging uploads outside the stream pipeline)
-    _STREAM_SPAN_STAGES = ("fetch", "decompress", "assemble")
-
-    def record_stream_stage(self, stage: str, seconds: float) -> None:
-        """One stream-pipeline stage (fetch/decompress/assemble/upload)
-        finished for one unit: observe its wall time, and attach a
-        timeline span to the active self-trace -- this is the single
-        chokepoint every cold ranged read passes (colio._run_plan and
-        ops/stream._run_stages both land here)."""
-        try:
-            self.stream_stage_time.observe(float(seconds), f'stage="{stage}"',
-                                           exemplar=self._exemplar_tid())
-            with self._lock:
-                ss = self._stream["stage_seconds"]
-                ss[stage] = ss.get(stage, 0.0) + float(seconds)
-            if stage in self._STREAM_SPAN_STAGES:
-                t1 = time.time()
-                self.child_span(f"stream:{stage}", t1 - float(seconds), t1)
-        except Exception:
-            pass
-
     def record_stream_unit(self, outcome: str = "ok") -> None:
         """One pipeline unit reached a terminal state (ok / error /
         cancelled)."""
@@ -750,9 +858,9 @@ class KernelTelemetry:
         seconds: <=1.0 means effectively sequential, >1 means stages of
         different units genuinely overlapped in time."""
         with self._lock:
-            c = {k: v for k, v in self._stream.items() if k != "stage_seconds"}
-            c["stage_seconds"] = {
-                k: round(v, 6) for k, v in self._stream["stage_seconds"].items()}
+            c = dict(self._stream)
+        c["stage_seconds"] = {k: v["seconds"]
+                              for k, v in self.stage_stats("stream").items()}
         wall = c["wall_seconds"]
         stage_total = sum(c["stage_seconds"].values())
         c["overlap_ratio"] = round(stage_total / wall, 3) if wall > 0 else 0.0
@@ -798,9 +906,6 @@ class KernelTelemetry:
             _affinity_placement.reset(token)
         except Exception:
             pass
-
-    def affinity_placement(self) -> str:
-        return _affinity_placement.get()
 
     def record_staged_lookup(self, hit: bool) -> None:
         """One staged-cache probe, attributed to the ambient dequeue
@@ -896,20 +1001,6 @@ class KernelTelemetry:
         return out
 
     # ----------------------------------------------------------- ingest
-    def record_ingest_stage(self, stage: str, seconds: float) -> None:
-        """One write-path stage interval: decode / wal_append /
-        stage_delta / cut / flush (tempo_tpu/ingest)."""
-        try:
-            self.ingest_stage_time.observe(float(seconds),
-                                           labels=f'stage="{stage}"')
-            with self._lock:
-                st = self._ingest["stages"].setdefault(
-                    stage, {"count": 0, "seconds": 0.0})
-                st["count"] += 1
-                st["seconds"] += float(seconds)
-        except Exception:
-            pass
-
     def record_ingest_window(self, traces: int, nbytes: int) -> None:
         """One push window appended to the columnar WAL."""
         try:
@@ -945,27 +1036,11 @@ class KernelTelemetry:
         """Write-path aggregates for /status/kernels."""
         with self._lock:
             out = dict(self._ingest)
-            out["stages"] = {k: dict(v) for k, v in self._ingest["stages"].items()}
             out["replays"] = dict(self._ingest["replays"])
-        for st in out["stages"].values():
-            st["seconds"] = round(st["seconds"], 6)
+        out["stages"] = self.stage_stats("ingest")
         return out
 
     # -------------------------------------------------------- generator
-    def record_generator_stage(self, stage: str, seconds: float) -> None:
-        """One streaming-generator fold interval (span-metrics /
-        service-graphs) on the tap worker."""
-        try:
-            self.generator_stage_time.observe(float(seconds),
-                                              labels=f'stage="{stage}"')
-            with self._lock:
-                st = self._generator["stages"].setdefault(
-                    stage, {"count": 0, "seconds": 0.0})
-                st["count"] += 1
-                st["seconds"] += float(seconds)
-        except Exception:
-            pass
-
     def record_generator_window(self, spans: int, edges: int,
                                 unpaired: int = 0, expired: int = 0) -> None:
         """One push window folded: spans aggregated, service-graph
@@ -1009,11 +1084,8 @@ class KernelTelemetry:
         """Streaming-generator aggregates for /status/kernels."""
         with self._lock:
             out = dict(self._generator)
-            out["stages"] = {k: dict(v)
-                             for k, v in self._generator["stages"].items()}
             out["shed"] = dict(self._generator["shed"])
-        for st in out["stages"].values():
-            st["seconds"] = round(st["seconds"], 6)
+        out["stages"] = self.stage_stats("generator")
         out["freshness_avg_s"] = round(
             out["freshness_sum"] / out["freshness_count"],
             6) if out["freshness_count"] else 0.0
@@ -1264,6 +1336,8 @@ class KernelTelemetry:
             "livestage": self.livestage_stats(),
             "ingest": self.ingest_stats(),
             "generator": self.generator_stats(),
+            "stages": self.stage_stats(),
+            "stages_at_session": self._stages_at_session,
             "slow_queries": self.slow_queries(slow_k),
         }
 

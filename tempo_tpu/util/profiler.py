@@ -555,11 +555,21 @@ class Profiler:
             lines.extend(f"    {fr}\n" for fr in stack.split(";")[-12:])
         return "".join(lines)
 
-    def capture_device_profile(self, seconds: float) -> tuple[str, dict]:
+    def capture_device_profile(self, seconds: float,
+                               python: bool = False) -> tuple[str, dict]:
         """Record a jax.profiler trace for `seconds` while serving
         continues, zip the trace directory, publish it as an artifact.
         Returns (artifact_id, summary). Raises ProfilerUnavailable when
-        the device profiler or the store can't run here."""
+        the device profiler or the store can't run here.
+
+        The session records the device planes, the XLA runtime's host
+        events and the program's own `tempo/<layer>:<stage>` annotations
+        (kerneltel TEL.stage). jax's Python tracer is OFF unless
+        `python`: it slows pure-Python code ~14x while it records and
+        freezes every thread for seconds when the session stops. The
+        first event is `tempo/profile:session` with `unix_ns` and
+        `seconds`: event times in the file are relative to the session's
+        start, and this lays wall-clock spans on them."""
         import io
         import shutil
         import tempfile
@@ -575,16 +585,29 @@ class Profiler:
             import jax
         except Exception as e:  # pragma: no cover - jax is baked in
             raise ProfilerUnavailable(f"jax unavailable: {e}")
+        from .kerneltel import TEL
+
         tmpd = tempfile.mkdtemp(prefix="tempo-devprof-")
         try:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 1 if python else 0
+            opts.host_tracer_level = 2
+            stop_s = 0.0
             try:
-                jax.profiler.start_trace(tmpd)
+                TEL.mark_session()
+                jax.profiler.start_trace(tmpd, profiler_options=opts)
+                with jax.profiler.TraceAnnotation(
+                        "tempo/profile:session", unix_ns=time.time_ns(),
+                        seconds=seconds, python=int(python)):
+                    pass
                 time.sleep(seconds)
             finally:
+                t_stop = time.perf_counter()
                 try:
                     jax.profiler.stop_trace()
                 except Exception:
                     pass
+                stop_s = time.perf_counter() - t_stop
             buf = io.BytesIO()
             n_files = 0
             with zipfile.ZipFile(buf, "w", zipfile.ZIP_DEFLATED) as z:
@@ -605,8 +628,16 @@ class Profiler:
         finally:
             shutil.rmtree(tmpd, ignore_errors=True)
         aid = store.put("device", data, suffix=".zip")
+        # one server-log line per session: how long stop_trace() took
+        # (the Python tracer's conversion freezes every thread for it)
+        from .log import get_logger
+
+        get_logger("profiler").info(
+            "device trace session stopped", seconds=seconds,
+            stop_s=round(stop_s, 4), python_tracer=python)
         return aid, {"bytes": len(data), "files": n_files,
-                     "seconds": seconds}
+                     "seconds": seconds, "stop_s": round(stop_s, 4),
+                     "python": python}
 
 
 PROF = Profiler()

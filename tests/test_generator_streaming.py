@@ -231,7 +231,8 @@ def test_kerneltel_generator_plane():
     from tempo_tpu.util.kerneltel import TEL
 
     g0 = TEL.generator_stats()
-    TEL.record_generator_stage("span-metrics", 0.002)
+    with TEL.stage("generator:span-metrics"):
+        pass
     TEL.record_generator_window(40, 7, unpaired=3, expired=1)
     TEL.record_generator_shed(TENANT, 2)
     TEL.record_generator_freshness(0.25)
