@@ -1466,7 +1466,11 @@ def bench_caching(tmp: str) -> None:
     from tempo_tpu.db import TempoDB, TempoDBConfig
     from tempo_tpu.ops import chunkpool
     from tempo_tpu.ops.filter import Cond, required_columns
-    from tempo_tpu.ops.stage import set_staged_cache_budget, stage_block
+    from tempo_tpu.ops.stage import (
+        pool_holds,
+        set_staged_cache_budget,
+        stage_block,
+    )
 
     rng = np.random.default_rng(29)
     backend = LocalBackend(tmp + "/store-chunk")
@@ -1500,8 +1504,7 @@ def bench_caching(tmp: str) -> None:
         stage_block(blk_b, needed)
         set_staged_cache_budget(1)
         set_staged_cache_budget(4 << 30)
-        assert chunkpool.probe(meta_a.block_id,
-                               (tuple(needed), None)), "demotion missed"
+        assert pool_holds(blk_a, needed, None), "demotion missed"
         t0 = time.perf_counter()
         stage_block(blk_a, needed)
         restage_lats.append(time.perf_counter() - t0)

@@ -600,6 +600,12 @@ class ColumnPack:
         meta = self._cols.get(name)
         return int(meta["shape"][0]) if meta else 0
 
+    def dtype_of(self, name: str) -> np.dtype | None:
+        """A column's dtype from footer metadata alone (None if the
+        pack has no such column)."""
+        meta = self._cols.get(name)
+        return np.dtype(meta["dtype"]) if meta else None
+
     def _cache_get(self, off: int) -> bytes | None:
         with self._cache_lock:
             hit = self._cache.get(off)

@@ -75,7 +75,7 @@ KNOBS: dict[str, tuple[str, str, str]] = {
         "pays where a native codec wheel is installed)"),
     "TEMPO_CHUNK_CACHE_MAX_ENTRY": (
         "int", "268435456",
-        "largest single staged-column set the chunk tier admits (raw "
+        "largest single staged column the chunk tier admits (raw "
         "bytes)"),
     "TEMPO_CHUNK_CACHE_MIN_REUSE": (
         "int", "1",

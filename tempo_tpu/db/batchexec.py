@@ -599,10 +599,10 @@ def _probe_search_entry(batcher, blk, req, groups_range, promote_touches,
     if n_rows * 4 * n_span_cols > _STREAM_MIN_STAGE_BYTES:
         TEL.record_routing("search_batch", "fallback", "stream_scan")
         return None
-    stage_key = (tuple(needed + ["trace.start_ms"]),
-                 tuple(groups_range) if groups_range is not None else None)
-    store = getattr(blk, "_staged_cache", None)
-    staged_hit = store is not None and stage_key in store
+    from ..ops.stage import is_staged
+
+    stage_cols = needed + ["trace.start_ms"]
+    staged_hit = is_staged(blk, stage_cols, groups_range)
     touches = getattr(blk, "search_touches", 0)
     hot = (staged_hit
            or (groups_range is not None and getattr(blk, "device_pinned", False))
@@ -617,5 +617,6 @@ def _probe_search_entry(batcher, blk, req, groups_range, promote_touches,
         limit=req.limit or default_limit or DEFAULT_LIMIT,
     )
     key = ("search", blk.meta.tenant_id, blk.meta.block_id,
-           stage_key[1], stage_key[0], lowered.shape)
+           tuple(groups_range) if groups_range is not None else None,
+           tuple(stage_cols), lowered.shape)
     return key, item

@@ -613,14 +613,14 @@ def _metrics_block(blk, q, req, resp, mode, planned, b_off, nb, t0_rel,
     # origin past int32 ms (~24.8 days) runs on the int64 host engine
     # instead -- identical results, no overflow
     i32_ok = req.step_ms < 2**31 and -(2**31) < t0_rel < 2**31
+    from ..ops.stage import has_staged, stage_block
+
     use_device = i32_ok and (mode == "device" or (
         mode == "auto"
-        and (getattr(blk, "device_pinned", False)
-             or getattr(blk, "_staged_cache", None) is not None)
+        and (getattr(blk, "device_pinned", False) or has_staged(blk))
     ))
     n_spans = blk.pack.axes["span"].n_rows if "span" in blk.pack.axes else 0
     if use_device:
-        from ..ops.stage import stage_block
         from ..ops.timeseries import eval_timeseries_device
 
         TEL.record_routing("metrics", "device",

@@ -47,7 +47,7 @@ from ..ops.select import (
     select_topk_host,
     select_topk_host_multi,
 )
-from ..ops.stage import stage_block
+from ..ops.stage import has_staged, is_staged, stage_block
 from ..traceql.plan import plan_search_request
 from ..util.distinct import DistinctStringCollector
 
@@ -553,8 +553,7 @@ def search_block(
 
     from ..util.kerneltel import TEL
 
-    hot = (getattr(blk, "device_pinned", False)
-           or getattr(blk, "_staged_cache", None) is not None)
+    hot = getattr(blk, "device_pinned", False) or has_staged(blk)
     if mode != "auto":
         use_device, reason = mode == "device", "forced"
     elif not hot:
@@ -642,11 +641,6 @@ def search_block(
 # its origin constant lives in ops/stage.GKEY_ORIGIN_S)
 
 
-def _staged_hit(blk: BackendBlock, needed: tuple) -> bool:
-    store = getattr(blk, "_staged_cache", None)
-    return store is not None and (needed, None) in store
-
-
 def search_blocks_fused(
     blocks: list[BackendBlock],
     req: SearchRequest,
@@ -722,7 +716,7 @@ def search_blocks_fused(
         blk.search_touches = getattr(blk, "search_touches", 0) + 1
         needed = (tuple(required_columns(p.conds)) + tuple(p.extra_cols)
                   + ("trace@gkey_s",))
-        staged_hit = _staged_hit(blk, needed)
+        staged_hit = is_staged(blk, needed)
         hot = not prefer_host and (staged_hit or blk.search_touches >= promote_touches)
         if hot:
             n_span_cols = max(1, sum(

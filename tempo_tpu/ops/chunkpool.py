@@ -1,16 +1,17 @@
 """Tier B of the cache plane: a host-RAM compressed column-chunk pool
 under the HBM staged cache (ops/stage).
 
-When the staged-column LRU evicts an entry to stay under the HBM
-budget, the padded device arrays are pulled back to host and parked
+When the staged-column LRU evicts a column to stay under the HBM
+budget, the padded device array is pulled back to host and parked
 here instead of discarded -- the bytes already paid object-store IO,
 decompression AND pad/assemble once. Entries are stored raw by
 default and optionally recompressed through the block codec layer
 (block/blockcodecs): a restage must beat the backend read + decode +
 assemble it replaces, and without a native codec wheel the
-compression round trip costs more than the RAM it saves. A later stage of the same
-(block, column set, group range) decompresses and re-uploads straight
-from the pool: no backend ranged read, no column decode, no
+compression round trip costs more than the RAM it saves. An entry is
+ONE column, keyed (block, ((device column name,), group range)): a
+later stage that misses that column in HBM decompresses and re-uploads
+it straight from the pool: no backend ranged read, no column decode, no
 owner-offset assembly. The pool is per-process, which under PR-7
 affinity placement means per cache domain -- the queries that staged an
 entry are the ones routed back to the process holding its demotion.
@@ -83,12 +84,14 @@ def codec_name() -> str:
 
 @dataclass
 class _Entry:
-    """One demoted staged-cache entry: the padded columns' compressed
-    bytes plus everything restage() needs to rebuild the StagedBlock
-    bit-identically."""
+    """One demoted staged column: the padded array's compressed bytes
+    plus what restage() needs to rebuild it bit-identically. The
+    request's shape fields are not here: ops/stage derives them from
+    the block's footer and the group range."""
 
-    cols: list  # [(name, dtype_str, shape, comp_bytes, raw_len), ...]
-    shape_meta: tuple  # (n_spans, n_traces, n_res, *_b, span_base)
+    dtype: str
+    shape: tuple
+    blob: bytes
     codec: str
     raw_bytes: int
     comp_bytes: int
@@ -134,12 +137,13 @@ def _evict_over_budget_locked() -> None:
     _tel().chunk_cache_bytes.set(_pool_bytes)
 
 
-def demote(block_id: str, key: tuple, staged) -> bool:
-    """Compress an evicted StagedBlock's padded columns into the pool.
-    Called by ops/stage AFTER releasing the stage LRU lock. Returns
-    whether the entry was admitted."""
+def demote(block_id: str, key: tuple, arr) -> bool:
+    """Compress one evicted staged column (a padded device array) into
+    the pool under `key` = ((device column name,), groups). Called by
+    ops/stage AFTER releasing the stage LRU lock. Returns whether the
+    entry was admitted."""
     global _pool_bytes
-    if not enabled() or not block_id or not staged.cols:
+    if not enabled() or not block_id or arr is None:
         return False
     pk = (block_id, key)
     with _pool_lock:
@@ -147,32 +151,21 @@ def demote(block_id: str, key: tuple, staged) -> bool:
             _pool.move_to_end(pk)
             return True
         reuse = _stage_counts.get(pk, 1)
-    raw = sum(int(a.nbytes) for a in staged.cols.values())
-    if raw > _max_entry() or reuse < _min_reuse():
+    if int(arr.nbytes) > _max_entry() or reuse < _min_reuse():
         return False
     name = codec_name()
     comp_fn, _ = _codec_pair(name)
-    cols = []
-    comp_total = 0
-    for cname, arr in staged.cols.items():
-        # device -> host pull; contiguous bytes for the codec
-        host = np.ascontiguousarray(np.asarray(arr))
-        blob = comp_fn(host.tobytes())
-        cols.append((cname, str(host.dtype), host.shape, blob, host.nbytes))
-        comp_total += len(blob)
-    ent = _Entry(
-        cols=cols,
-        shape_meta=(staged.n_spans, staged.n_traces, staged.n_res,
-                    staged.n_spans_b, staged.n_traces_b, staged.n_res_b,
-                    staged.span_base),
-        codec=name, raw_bytes=raw, comp_bytes=comp_total,
-    )
+    # device -> host pull; contiguous bytes for the codec
+    host = np.ascontiguousarray(np.asarray(arr))
+    blob = comp_fn(host.tobytes())
+    ent = _Entry(dtype=str(host.dtype), shape=host.shape, blob=blob,
+                 codec=name, raw_bytes=host.nbytes, comp_bytes=len(blob))
     with _pool_lock:
         if pk in _pool:
             _pool.move_to_end(pk)
             return True
         _pool[pk] = ent
-        _pool_bytes += comp_total
+        _pool_bytes += ent.comp_bytes
         _tel().chunk_cache_demotions.inc()
         _evict_over_budget_locked()
     return True
@@ -187,45 +180,42 @@ def probe(block_id: str, key: tuple) -> bool:
         return (block_id, key) in _pool
 
 
-def restage(block_id: str, key: tuple):
-    """Rebuild the StagedBlock for (block, key) from the pool:
-    decompress on host, one batched device upload. Returns None on a
-    pool miss. Counts hits/misses and attaches a cache:chunk-hit span
-    to the active self-trace."""
-    if not enabled():
-        return None
+def restage(block_id: str, keys: list[tuple]) -> dict:
+    """Rebuild the device columns of `keys` that the pool holds:
+    decompress on host, ONE batched device upload. Returns key -> device
+    array for the hits. Counts hits/misses per column and attaches a
+    cache:chunk-hit span to the active self-trace."""
+    if not enabled() or not keys:
+        return {}
     tel = _tel()
+    found: dict[tuple, _Entry] = {}
     with _pool_lock:
-        ent = _pool.get((block_id, key))
-        if ent is not None:
-            _pool.move_to_end((block_id, key))
-    if ent is None:
-        tel.chunk_cache_misses.inc()
-        return None
+        for key in keys:
+            ent = _pool.get((block_id, key))
+            if ent is not None:
+                _pool.move_to_end((block_id, key))
+                found[key] = ent
+    tel.chunk_cache_misses.inc(len(keys) - len(found))
+    if not found:
+        return {}
     import jax
 
-    from .stage import StagedBlock
-
-    _, dec_fn = _codec_pair(ent.codec)
-    host = []
-    with tel.stage("cache:chunk-hit", block=block_id[:8], bytes=ent.raw_bytes,
-                   codec=ent.codec):
-        for cname, dtype, shape, blob, raw_len in ent.cols:
-            arr = np.frombuffer(dec_fn(blob, raw_len), dtype=dtype).reshape(shape)
-            host.append((cname, arr))
+    raw = sum(e.raw_bytes for e in found.values())
+    with tel.stage("cache:chunk-hit", block=block_id[:8], bytes=raw,
+                   columns=len(found)):
+        host = [
+            np.frombuffer(_codec_pair(e.codec)[1](e.blob, e.raw_bytes),
+                          dtype=e.dtype).reshape(e.shape)
+            for e in found.values()
+        ]
         # ONE batched transfer, same as upload_stage: per-array device_puts
         # each pay a full link round trip
-        devs = jax.device_put([a for _, a in host])
-    (n_spans, n_traces, n_res, n_spans_b, n_traces_b, n_res_b,
-     span_base) = ent.shape_meta
-    staged = StagedBlock(
-        n_spans=n_spans, n_traces=n_traces, n_res=n_res,
-        n_spans_b=n_spans_b, n_traces_b=n_traces_b, n_res_b=n_res_b,
-        span_base=span_base,
-        cols={cname: dev for (cname, _), dev in zip(host, devs)},
-    )
-    tel.chunk_cache_hits.inc()
-    return staged
+        devs = jax.device_put(host)
+    # a host->device upload like any other staging miss's (the rows
+    # were counted, real and padded, when the column was first staged)
+    tel.record_transfer(raw, 0, 0)
+    tel.chunk_cache_hits.inc(len(found))
+    return dict(zip(found, devs))
 
 
 def stats() -> dict:

@@ -4,18 +4,32 @@ Reads only the columns a condition set needs (ops.filter.required_columns),
 optionally only a row-group range (the unit of search-job sharding,
 mirroring the reference's StartPage/TotalPages jobs,
 modules/frontend/searchsharding.go), pads every axis to its power-of-two
-bucket, and uploads. Staged device arrays are cached on the (immutable)
-block object keyed by (column set, group range), so repeated queries
-against a hot block skip IO, decompression, AND the host->device
-transfer -- the device-memory analog of the reference's page cache +
-memcached layers, and the biggest win when the host<->device link has
-high latency."""
+bucket, and uploads.
+
+The staged cache holds device COLUMNS, not column sets. Each (immutable)
+block object carries a store keyed by (device column name, group range
+or None) -> one padded device array. Span- and sattr-axis columns and
+what is derived from them per slice (`sattr.off`, the rebased
+`trace.span_off`, `span@<res column>`) key on the group range; trace-,
+res- and rattr-axis columns and `trace@gkey_s` do not depend on the
+range and key on None, so row-group shards and whole-block requests
+share them. `column_keys` is the one place a request name becomes a
+device name. A request resolves every column it names against the
+store, stages only the missing ones (host chunk pool first, then the
+backend) and gets a fresh StagedBlock view over the shared arrays: a
+column is read, assembled and uploaded once however the routes spell
+their column lists, and repeated queries against a hot block skip IO,
+decompression AND the host->device transfer -- the device-memory analog
+of the reference's page cache + memcached layers, and the biggest win
+when the host<->device link has high latency. One policy bounds it: a
+byte-budget LRU over every (block, column)."""
 
 from __future__ import annotations
 
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
@@ -26,12 +40,9 @@ from ..block.reader import BackendBlock
 from ..util.profiler import timed_lock
 from .device import PAD_I32, bucket, pad_rows, scoped
 
-_CACHE_MAX_ENTRIES = 32  # per block
-_CACHE_MAX_ENTRY_BYTES = 256 << 20
-
-# aggregate device-memory budget across EVERY block's staged cache: an
-# LRU over (block, entry) pairs, so a wide working set evicts the
-# coldest block's columns instead of growing until HBM OOMs
+# aggregate device-memory budget across EVERY block's staged columns: an
+# LRU over (block, column key) pairs, so a wide working set evicts the
+# coldest columns instead of growing until HBM OOMs
 _GLOBAL_CACHE_BUDGET = 4 << 30
 # a cataloged hot lock: TEMPO_LOCK_PROFILE arms contention timing
 # (tempo_lock_wait_seconds{lock="stage_lru"}); off = a raw Lock
@@ -39,7 +50,7 @@ _lru_lock = timed_lock("stage_lru")
 _lru: OrderedDict[tuple[int, tuple], tuple] = OrderedDict()  # -> (blk weakref, nbytes)
 _lru_bytes = 0
 
-# HBM-evicted entries awaiting demotion into the host chunk pool
+# HBM-evicted columns awaiting demotion into the host chunk pool
 # (ops/chunkpool): collected under _lru_lock, compressed OUTSIDE it --
 # the D2H pull + codec work is milliseconds, the lock guards
 # microsecond bookkeeping
@@ -49,23 +60,21 @@ _pending_demote: list[tuple[str, tuple, object]] = []
 def staged_cache_stats(max_entries: int = 32) -> dict:
     """Point-in-time view of the device staged-column cache for
     /status/kernels: aggregate occupancy plus the hottest (most recently
-    touched) entries' shape."""
+    touched) columns."""
     with _lru_lock:
         items = list(_lru.items())
         total = _lru_bytes
         budget = _GLOBAL_CACHE_BUDGET
-    entries = []
-    for (_bid, key), (wr, nbytes) in reversed(items[-max_entries:]):
-        blk = wr()
-        cols, groups = key
-        entries.append({
-            "block_id": getattr(getattr(blk, "meta", None), "block_id", "")[:8],
-            "columns": len(cols),
+    hottest = []
+    for (_bid, (name, groups)), (wr, nbytes) in reversed(items[-max_entries:]):
+        hottest.append({
+            "block_id": getattr(getattr(wr(), "meta", None), "block_id", "")[:8],
+            "column": name,
             "groups": list(groups) if groups is not None else None,
             "nbytes": int(nbytes),
         })
     return {"entries": len(items), "bytes": int(total),
-            "budget_bytes": int(budget), "hottest": entries}
+            "budget_bytes": int(budget), "hottest": hottest}
 
 
 def set_staged_cache_budget(n_bytes: int) -> None:
@@ -90,35 +99,42 @@ def _sweep_dead_locked() -> None:
         _lru_bytes -= _lru.pop(k)[1]
 
 
-def _lru_touch(blk, key: tuple, nbytes: int) -> None:
-    global _lru_bytes
-    k = (id(blk), key)
+def _lru_touch(blk, keys) -> int:
+    """Re-rank the resident columns a lookup just used -> their bytes."""
+    bid, nbytes = id(blk), 0
     with _lru_lock:
-        existing = _lru.get(k)
-        if existing is not None:
-            if existing[0]() is blk:
-                _lru.move_to_end(k)
-                return
-            # id() reuse after the old block was GC'd: replace the stale
-            # entry and its accounting
-            _lru_bytes -= existing[1]
-            del _lru[k]
-        _lru[k] = (weakref.ref(blk), nbytes)
-        _lru_bytes += nbytes
+        for key in keys:
+            entry = _lru.get((bid, key))
+            if entry is not None and entry[0]() is blk:
+                _lru.move_to_end((bid, key))
+                nbytes += entry[1]
+    return nbytes
+
+
+def _admit(blk, fresh: dict) -> None:
+    """Put freshly staged (or pool-restaged) columns into the block's
+    store and the global LRU. Store and accounting change together under
+    the lock, so an eviction pass never sees one without the other."""
+    global _lru_bytes
+    bid = id(blk)
+    with _lru_lock:
+        store = getattr(blk, "_staged_cache", None)
+        if store is None:
+            store = blk._staged_cache = {}
+        for key, arr in fresh.items():
+            # already accounted: a concurrent miss staged the same
+            # column, or id() was reused after the old block was GC'd --
+            # either way the new array replaces it
+            old = _lru.pop((bid, key), None)
+            if old is not None:
+                _lru_bytes -= old[1]
+            store[key] = arr
+            _lru[(bid, key)] = (weakref.ref(blk), int(arr.nbytes))
+            _lru_bytes += int(arr.nbytes)
         # the eviction pass sweeps dead weakrefs first, so every insert
         # restores the accounting invariant in one O(n) scan
         _evict_over_budget_locked()
     _drain_demotions()
-
-
-def _lru_drop(blk, key: tuple) -> None:
-    """Per-block cap evictions must release their global accounting."""
-    global _lru_bytes
-    k = (id(blk), key)
-    with _lru_lock:
-        entry = _lru.pop(k, None)
-        if entry is not None:
-            _lru_bytes -= entry[1]
 
 
 def _evict_over_budget_locked() -> None:
@@ -128,40 +144,25 @@ def _evict_over_budget_locked() -> None:
         (_bid, key), (wr, nbytes) = _lru.popitem(last=False)
         _lru_bytes -= nbytes
         blk = wr()
-        if blk is not None:
-            store = getattr(blk, "_staged_cache", None)
-            if store is not None:
-                staged = store.pop(key, None)
-                if staged is not None:
-                    # Tier B demotion candidate: the padded device
-                    # arrays still exist here -- park them for the
-                    # post-lock compress instead of discarding
-                    block_id = getattr(
-                        getattr(blk, "meta", None), "block_id", "") or ""
-                    if block_id:
-                        _pending_demote.append((block_id, key, staged))
+        if blk is None:
+            continue
+        arr = (getattr(blk, "_staged_cache", None) or {}).pop(key, None)
+        block_id = getattr(getattr(blk, "meta", None), "block_id", "") or ""
+        if arr is not None and block_id:
+            # Tier B demotion candidate: the padded device array still
+            # exists here -- park it for the post-lock compress instead
+            # of discarding
+            _pending_demote.append((block_id, key, arr))
 
 
-def _miss_reason(store: dict | None, key: tuple) -> str:
-    """Why a staged-cache lookup missed, from what the block holds now:
-    everything asked for is staged under another key; some of it is;
-    none of it is (never was, or was evicted). Other threads insert and
-    evict while this reads, so it works on a copy of the keys and gives
-    "" rather than raise into the query path."""
-    try:
-        cols, groups = set(key[0]), key[1]
-        have = [set(k[0]) for k in list(store or ()) if k[1] == groups]
-    except Exception:
-        return ""
-    if any(cols <= h for h in have):
-        return "key_mismatch"
-    if any(cols & h for h in have):
-        return "partial_columns"
-    return "not_staged"
+def _pool_key(key: tuple) -> tuple:
+    """A store key as the host chunk pool spells it: a one-column entry
+    of the (column tuple, groups) shape the pool has always had."""
+    return ((key[0],), key[1])
 
 
 def _drain_demotions() -> None:
-    """Compress HBM-evicted entries into the host chunk pool. Called by
+    """Compress HBM-evicted columns into the host chunk pool. Called by
     every path that may have run an eviction pass, AFTER _lru_lock is
     released. With TEMPO_CHUNK_CACHE=0 the pool refuses every entry and
     eviction degrades to exactly the old discard."""
@@ -174,8 +175,8 @@ def _drain_demotions() -> None:
         return
     from . import chunkpool
 
-    for block_id, key, staged in victims:
-        chunkpool.demote(block_id, key, staged)
+    for block_id, key, arr in victims:
+        chunkpool.demote(block_id, _pool_key(key), arr)
 
 # absolute-seconds origin (2020-01-01 UTC) for the derived trace@gkey_s
 # column: a global trace start time in int32 seconds (valid until 2088)
@@ -267,7 +268,6 @@ def read_stage_columns(blk: BackendBlock, plan: StagePlan,
     pack = blk.pack
     span_ax = pack.axes[S.AX_SPAN]
     host: dict[str, np.ndarray] = {}
-    n_res = 0
     with TEL.stage("stage:read_columns", columns=len(plan.read_names)):
         for name in plan.read_names:
             pref = name.split(".", 1)[0]
@@ -277,10 +277,79 @@ def read_stage_columns(blk: BackendBlock, plan: StagePlan,
             else:
                 arr = pack.read_groups(name, groups) if span_ax.n_groups else pack.read(name)
             host[name] = arr
-    for name, arr in host.items():
-        if name.startswith("res."):
-            n_res = max(n_res, arr.shape[0])
-    return host, n_res
+    return host, _n_res(blk, plan.read_names)
+
+
+# request names whose device column has another name: owner-row columns
+# reach the device as cumulative offsets (_assemble)
+_DEVICE_NAME = {"sattr.span": "sattr.off", "rattr.res": "rattr.off"}
+
+
+def column_keys(blk: BackendBlock, needed, groups) -> dict[str, tuple]:
+    """Request name -> store key (device column name, group range or
+    None) for every column a request of `needed` gets staged: THE place
+    a request name becomes a device name. Columns read per row-group
+    range (span / sattr axis) and what is rebased or gathered per slice
+    (`trace.span_off`, `span@X`) key on the range; everything else is
+    the same array for every range and keys on None. Left out, as
+    _assemble and upload_stage leave them out: a `span@X` whose sources
+    (`X`, `span.res_idx`) the request does not name, and trace columns
+    of a host-only dtype. (`rattr.off` is cut to the res axis, which
+    every request that names `rattr.res` sizes alike: required_columns
+    sends `res.service_id` along.)"""
+    gkey = tuple(groups) if groups is not None else None
+    keys: dict[str, tuple] = {}
+    for n in needed:
+        device_name, ranged, source = _device_column(n)
+        if source is not None:  # span@X
+            if source not in needed or "span.res_idx" not in needed:
+                continue
+        elif (n.startswith("trace.") and not ranged
+              and blk.pack.dtype_of(n) not in (None, np.int32, np.float32)):
+            continue
+        keys[n] = (device_name, gkey if ranged else None)
+    return keys
+
+
+@lru_cache(maxsize=1024)
+def _device_column(name: str) -> tuple[str, bool, str | None]:
+    """What column_keys knows from a request name alone -> (device
+    column name, whether it depends on the row-group range, the res
+    column a `span@X` is gathered from)."""
+    if name.startswith("span@"):
+        return name, True, name.split("@", 1)[1]
+    ranged = (name == "trace.span_off"
+              or _AXIS_OF.get(name.split(".", 1)[0]) is not None)
+    return _DEVICE_NAME.get(name, name), ranged, None
+
+
+def has_staged(blk: BackendBlock) -> bool:
+    """The routes' temperature test: has this block object ever had a
+    column admitted to the staged cache (it may since have been
+    evicted; a block that was hot once is restaged, not sent cold)."""
+    return getattr(blk, "_staged_cache", None) is not None
+
+
+def is_staged(blk: BackendBlock, needed, groups=None) -> bool:
+    """Would stage_block(blk, needed, groups) be a full hit now: every
+    column the request names is resident."""
+    store = getattr(blk, "_staged_cache", None)
+    return store is not None and all(
+        k in store for k in column_keys(blk, needed, groups).values())
+
+
+def _group_list(blk: BackendBlock, groups) -> list[int]:
+    if groups is not None:
+        return list(groups)
+    return list(range(blk.pack.axes[S.AX_SPAN].n_groups))
+
+
+def _n_res(blk: BackendBlock, needed) -> int:
+    """Rows of the res axis as the request sizes it: from the `res.*`
+    columns it names (footer metadata; what read_stage_columns counts
+    from the arrays)."""
+    return max((blk.pack.n_rows_of(n) for n in needed
+                if n.startswith("res.")), default=0)
 
 
 def stage_block(
@@ -291,72 +360,103 @@ def stage_block(
 ) -> StagedBlock:
     """Load `needed` columns (padded, on device). If `groups` is given,
     span/sattr-axis columns cover only those contiguous row groups.
-    Results cache on the block object (blocks are immutable)."""
+    Columns cache on the block object (blocks are immutable), one entry
+    each: whatever is resident is shared, only the rest is staged, and
+    the result is a fresh view holding exactly the columns asked for."""
     from ..util.kerneltel import TEL
 
-    key = (tuple(needed), tuple(groups) if groups is not None else None)
-    store: dict | None = getattr(blk, "_staged_cache", None) if cache else None
-    hit = store.get(key) if store is not None else None
-    reason = "" if hit is not None or not cache else _miss_reason(store, key)
-    with TEL.stage("stage:lookup", hit=hit is not None, reason=reason):
-        if hit is not None:
-            TEL.staged_cache_hits.inc()
-            # attribute the hit to the dequeue placement of the job
+    keys = column_keys(blk, needed, groups)
+    store = (getattr(blk, "_staged_cache", None) or {}) if cache else {}
+    cols: dict[str, jnp.ndarray] = {}  # device name -> array: the view's
+    resident: list[tuple] = []
+    missing: list[str] = []  # request names still to stage
+    for n, key in keys.items():
+        arr = store.get(key)
+        if arr is None:
+            missing.append(n)
+        else:
+            cols[key[0]] = arr
+            resident.append(key)
+    if cache:
+        reason = "" if not missing else (
+            "partial_columns" if cols else "not_staged")
+        with TEL.stage("stage:lookup", hit=not missing, reason=reason,
+                       missing=len(missing)):
+            (TEL.staged_cache_misses if missing else TEL.staged_cache_hits).inc()
+            # attribute the lookup to the dequeue placement of the job
             # asking (own/steal/unowned): the affinity scheduler's
             # whole point is moving this ratio
-            TEL.record_staged_lookup(True)
-            _lru_touch(blk, key, sum(a.nbytes for a in hit.cols.values()))
-            return hit
-    if cache:
-        TEL.staged_cache_misses.inc()
-        TEL.record_staged_lookup(False)
-        # Tier B probe: a previous HBM eviction may have demoted exactly
-        # this (block, columns, groups) entry into the host chunk pool
-        # -- restaging from there skips the backend ranged read, the
-        # column decode AND the pad/assemble phase
-        block_id = getattr(blk.meta, "block_id", "") or ""
-        if block_id:
-            from . import chunkpool
+            TEL.record_staged_lookup(not missing)
+            TEL.record_staged_columns(
+                len(resident), len(missing), _lru_touch(blk, resident))
+    fresh: dict[tuple, jnp.ndarray] = {}
+    block_id = getattr(blk.meta, "block_id", "") or ""
+    if cache and missing and block_id:
+        # Tier B probe, per column: a previous HBM eviction may have
+        # demoted it into the host chunk pool -- restaging from there
+        # skips the backend ranged read, the column decode AND the
+        # pad/assemble phase
+        from . import chunkpool
 
-            chunkpool.note_stage(block_id, key)
-            warm = chunkpool.restage(block_id, key)
-            if warm is not None:
-                _cache_insert(blk, key, warm)
-                return warm
-    plan = plan_stage(needed)
-    span_ax = blk.pack.axes[S.AX_SPAN]
-    if groups is None:
-        groups = list(range(span_ax.n_groups))
-    host, n_res = read_stage_columns(blk, plan, groups)
-    staged, padded, real_rows = assemble_stage(blk, plan, groups, host, n_res)
-    upload_stage(blk, plan, staged, padded, real_rows)
-    if cache:
-        _cache_insert(blk, key, staged)
-    return staged
+        pool_keys = {_pool_key(keys[n]): n for n in missing}
+        for pk in pool_keys:
+            chunkpool.note_stage(block_id, pk)
+        for pk, arr in chunkpool.restage(block_id, list(pool_keys)).items():
+            n = pool_keys[pk]
+            fresh[keys[n]] = cols[keys[n][0]] = arr
+            missing.remove(n)
+    glist = _group_list(blk, groups)
+    n_res = _n_res(blk, needed)
+    if missing:
+        plan = plan_stage(missing)
+        host, _ = read_stage_columns(blk, plan, glist)
+        view, padded, real_rows = assemble_stage(blk, plan, glist, host, n_res)
+        view.cols = cols  # what is resident: upload_stage adds the rest
+        upload_stage(blk, plan, view, padded, real_rows)
+        for n in missing:
+            if keys[n][0] in cols:
+                fresh[keys[n]] = cols[keys[n][0]]
+    else:
+        # nothing to read or upload: assembling the view is all the
+        # staging this request costs, and it is timed as that (a traced
+        # search's staging time then reads ~0 rather than not at all)
+        with TEL.stage("stage:assemble", block=blk.meta.block_id[:8]):
+            view = _dims(blk, glist, n_res)
+            view.cols = cols
+    if fresh and cache:
+        _admit(blk, fresh)
+    return view
 
 
-def _cache_insert(blk: BackendBlock, key: tuple, staged: StagedBlock) -> None:
-    """Admit a freshly staged (or pool-restaged) entry into the
-    per-block store + global LRU; a per-block cap victim demotes into
-    the host chunk pool the same way budget evictions do."""
-    nbytes = sum(a.nbytes for a in staged.cols.values())
-    if nbytes > _CACHE_MAX_ENTRY_BYTES:
-        return
-    store = getattr(blk, "_staged_cache", None)
-    if store is None:
-        store = {}
-        blk._staged_cache = store
-    if len(store) >= _CACHE_MAX_ENTRIES:
-        victim = next(iter(store))
-        vstaged = store.pop(victim)
-        _lru_drop(blk, victim)
-        block_id = getattr(blk.meta, "block_id", "") or ""
-        if block_id and vstaged is not None:
-            from . import chunkpool
+def restage_from_pool(blk: BackendBlock, needed: list[str],
+                      groups: list[int] | None) -> StagedBlock | None:
+    """The whole request rebuilt from the host chunk pool (one batched
+    upload), or None unless every column is there: the streamed cold
+    path's unit restage (ops/stream), whose units never enter the
+    staged cache."""
+    from . import chunkpool
 
-            chunkpool.demote(block_id, victim, vstaged)
-    store[key] = staged
-    _lru_touch(blk, key, nbytes)
+    block_id = getattr(blk.meta, "block_id", "") or ""
+    keys = column_keys(blk, needed, groups)
+    if not block_id or not keys:
+        return None
+    warm = chunkpool.restage(block_id, [_pool_key(k) for k in keys.values()])
+    if len(warm) < len(keys):  # pool_holds said yes, an eviction since
+        return None
+    view = _dims(blk, _group_list(blk, groups), _n_res(blk, needed))
+    view.cols = {pk[0][0]: arr for pk, arr in warm.items()}
+    return view
+
+
+def pool_holds(blk: BackendBlock, needed: list[str],
+               groups: list[int] | None) -> bool:
+    """Plan-time form of restage_from_pool: would it hit."""
+    from . import chunkpool
+
+    block_id = getattr(blk.meta, "block_id", "") or ""
+    keys = column_keys(blk, needed, groups)
+    return bool(block_id and keys) and all(
+        chunkpool.probe(block_id, _pool_key(k)) for k in keys.values())
 
 
 def assemble_stage(blk: BackendBlock, plan: StagePlan, groups: list[int],
@@ -369,31 +469,36 @@ def assemble_stage(blk: BackendBlock, plan: StagePlan, groups: list[int],
         return _assemble(blk, plan, groups, host, n_res)
 
 
-def _assemble(blk, plan, groups, host, n_res):
-    host = dict(host)  # owner-offset transforms mutate; callers may retry
-    pack = blk.pack
-    span_ax = pack.axes[S.AX_SPAN]
+def _dims(blk: BackendBlock, groups: list[int], n_res: int) -> StagedBlock:
+    """The shape fields of a staging of `groups` (no columns yet): what
+    the kernels take as static arguments."""
+    span_ax = blk.pack.axes[S.AX_SPAN]
     span_base = span_ax.offsets[groups[0]] if groups else 0
     span_hi = span_ax.offsets[groups[-1] + 1] if groups else 0
     n_spans = span_hi - span_base
     n_traces = blk.meta.total_traces
+    return StagedBlock(
+        n_spans=n_spans,
+        n_traces=n_traces,
+        n_res=n_res,
+        n_spans_b=bucket(max(n_spans, 1)),
+        n_traces_b=bucket(max(n_traces, 1)),
+        n_res_b=bucket(max(n_res, 1)),
+        span_base=span_base,
+    )
 
-    n_spans_b = bucket(max(n_spans, 1))
-    n_traces_b = bucket(max(n_traces, 1))
-    n_res_b = bucket(max(n_res, 1))
+
+def _assemble(blk, plan, groups, host, n_res):
+    host = dict(host)  # owner-offset transforms mutate; callers may retry
+    staged = _dims(blk, groups, n_res)
+    span_base, n_spans = staged.span_base, staged.n_spans
+    span_hi = span_base + n_spans
+    n_spans_b, n_traces_b, n_res_b = (
+        staged.n_spans_b, staged.n_traces_b, staged.n_res_b)
 
     want_gkey = plan.want_gkey
     start_ms_for_gkey_only = plan.start_ms_for_gkey_only
 
-    staged = StagedBlock(
-        n_spans=n_spans,
-        n_traces=n_traces,
-        n_res=n_res,
-        n_spans_b=n_spans_b,
-        n_traces_b=n_traces_b,
-        n_res_b=n_res_b,
-        span_base=span_base,
-    )
     # owner-offset columns: rows of every child table are grouped by
     # owner, so the kernel aggregates with cumsum + offset gathers
     # (ops/filter._offset_counts) -- the owner row columns themselves
@@ -460,7 +565,9 @@ def _assemble(blk, plan, groups, host, n_res):
 def upload_stage(blk: BackendBlock, plan: StagePlan, staged: StagedBlock,
                  padded: dict, real_rows: dict) -> StagedBlock:
     """The host->device phase: one batched transfer + the query-
-    independent res->span materialization."""
+    independent res->span materialization. Adds to what `staged.cols`
+    already holds (stage_block seeds it with the resident columns, so a
+    missing `span@X` gathers from a resident `X` or `span.res_idx`)."""
     from ..util.kerneltel import TEL
 
     nbytes = sum(int(a.nbytes) for a in padded.values())
@@ -470,7 +577,7 @@ def upload_stage(blk: BackendBlock, plan: StagePlan, staged: StagedBlock,
     with TEL.stage("stage:upload", bytes=nbytes, block=blk.meta.block_id[:8]):
         # ONE batched transfer for the whole block: per-array device_puts
         # each pay their own dispatch + link round trip
-        staged.cols = dict(zip(padded, jax.device_put(list(padded.values()))))
+        staged.cols.update(zip(padded, jax.device_put(list(padded.values()))))
     # telemetry: upload volume + padding waste (padded vs real rows
     # summed per column -- columns live on different axes)
     TEL.record_transfer(

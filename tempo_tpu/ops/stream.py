@@ -61,7 +61,9 @@ from .filter import Operands, eval_block, normalize_tree
 from .stage import (
     assemble_stage,
     plan_stage,
+    pool_holds,
     read_stage_columns,
+    restage_from_pool,
     stage_fetch_wants,
     upload_stage,
 )
@@ -226,14 +228,6 @@ def _unit_groups(u: StreamUnit) -> list[int]:
     return list(range(span_ax.n_groups)) if span_ax else []
 
 
-def _unit_pool_key(u: StreamUnit) -> tuple:
-    """The (columns, groups) identity a stage_block caching of this
-    unit would use -- ONE key shape shared with ops/stage so demotions
-    from either path restage on the other."""
-    return (tuple(u.needed),
-            tuple(u.groups) if u.groups is not None else None)
-
-
 def _plan_unit(u: StreamUnit):
     """(stage plan, column-fetch plan) for a unit -- footer metadata
     only, no IO; fills u.est_bytes for the admission gate. Upload units
@@ -241,14 +235,12 @@ def _plan_unit(u: StreamUnit):
     no backend ranged read to plan and no admission bytes to hold."""
     if u.upload:
         plan = plan_stage(u.needed)
-        block_id = getattr(u.blk.meta, "block_id", "") or ""
-        if block_id:
-            from . import chunkpool
-
-            if chunkpool.probe(block_id, _unit_pool_key(u)):
-                u.pool_hit = True
-                u.est_bytes = 0
-                return plan, None
+        # ONE key shape shared with ops/stage (a pool entry is a device
+        # column), so columns its evictions demoted restage here
+        if pool_holds(u.blk, u.needed, u.groups):
+            u.pool_hit = True
+            u.est_bytes = 0
+            return plan, None
         wants = stage_fetch_wants(u.blk, plan, u.groups)
     else:
         plan = None
@@ -264,12 +256,10 @@ def _run_stages(u: StreamUnit, plan, cf, state: _PipeState | None):
     checks (the serial path)."""
     pack = u.blk.pack
     if u.upload and u.pool_hit:
-        from . import chunkpool
-
         if state is not None and not state.wait_upload_turn(u.index):
             return None  # cancelled before the restage upload
         with TEL.stage("stream:upload") as up:
-            staged = chunkpool.restage(u.blk.meta.block_id, _unit_pool_key(u))
+            staged = restage_from_pool(u.blk, u.needed, u.groups)
             up.counted = staged is not None
         if staged is not None:
             return staged
@@ -555,9 +545,9 @@ def eval_block_streamed(
 
     single_tracify = sum(1 for lf in leaves if lf[0] == "tracify") == 1
     # the streamed path exists because staging the whole block exceeds
-    # the device budget, so chunks never enter the staged cache (per-
-    # block FIFO would evict before reuse); the pipeline's own double
-    # buffer bounds device memory instead
+    # the device budget, so chunks never enter the staged cache (the
+    # byte-budget LRU would evict them before reuse); the pipeline's own
+    # double buffer bounds device memory instead
     units = [StreamUnit(blk, needed, cg, upload=True) for cg in chunk_groups]
     it = stream_staged(units)
     try:

@@ -193,6 +193,18 @@ class KernelTelemetry:
         self.staged_cache_misses = Counter(
             "tempo_stage_cache_misses_total",
             help="staged-column device cache misses (uploads)")
+        self.staged_column_hits = Counter(
+            "tempo_stage_column_hits_total",
+            help="columns a staged-cache lookup found resident on the "
+                 "device")
+        self.staged_column_misses = Counter(
+            "tempo_stage_column_misses_total",
+            help="columns a staged-cache lookup had to stage (host pool "
+                 "or backend read, assemble, upload)")
+        self.staged_bytes_reused = Counter(
+            "tempo_stage_bytes_reused_total",
+            help="device bytes of resident columns that staged-cache "
+                 "lookups did not have to stage again")
         self.routing = Counter(
             "tempo_engine_routing_total",
             help="engine routing decisions by layer, engine and reason")
@@ -437,7 +449,9 @@ class KernelTelemetry:
             self.compiles, self.cache_hits, self.device_time,
             self.transfer_bytes, self.staged_rows_real,
             self.staged_rows_padded, self.staged_cache_hits,
-            self.staged_cache_misses, self.routing,
+            self.staged_cache_misses, self.staged_column_hits,
+            self.staged_column_misses, self.staged_bytes_reused,
+            self.routing,
             self.batch_groups, self.batch_queries,
             self.batch_occupancy, self.batch_window_wait,
             self.batch_demux, self.mesh_batch_launches,
@@ -907,6 +921,14 @@ class KernelTelemetry:
         except Exception:
             pass
 
+    def record_staged_columns(self, hits: int, misses: int,
+                              bytes_reused: int) -> None:
+        """One staged-cache lookup by column: how many it found
+        resident, how many it has to stage, and the bytes it reuses."""
+        self.staged_column_hits.inc(hits)
+        self.staged_column_misses.inc(misses)
+        self.staged_bytes_reused.inc(bytes_reused)
+
     def record_staged_lookup(self, hit: bool) -> None:
         """One staged-cache probe, attributed to the ambient dequeue
         placement -- the owner-vs-stolen hit-rate split that says
@@ -1322,6 +1344,9 @@ class KernelTelemetry:
                     rows_padded / rows_real, 4) if rows_real else 0.0,
                 "cache_hits": int(self.staged_cache_hits.get()),
                 "cache_misses": int(self.staged_cache_misses.get()),
+                "column_hits": int(self.staged_column_hits.get()),
+                "column_misses": int(self.staged_column_misses.get()),
+                "bytes_reused": int(self.staged_bytes_reused.get()),
             },
             "routing": routing,
             "hedging": self.hedge_stats(),

@@ -326,36 +326,53 @@ def _block(n_traces=120, seed=5):
 _NEEDED = required_columns((Cond(target="res", col="res.service_id", op="eq"),))
 
 
+def _pool_keys(blk, needed=_NEEDED, groups=None):
+    """The one-column pool keys of a request, as ops/stage spells them."""
+    from tempo_tpu.ops.stage import _pool_key, column_keys
+
+    return [_pool_key(k) for k in column_keys(blk, needed, groups).values()]
+
+
+def _demote_all(meta, blk, staged):
+    """Demote every device column of a staged request, one entry each."""
+    keys = _pool_keys(blk)
+    assert sorted(k[0][0] for k in keys) == sorted(staged.cols)
+    return keys, [chunkpool.demote(meta.block_id, k, staged.cols[k[0][0]])
+                  for k in keys]
+
+
 @pytest.mark.parametrize("codec", ["none", "lz4", "snappy", "zstd"])
 def test_chunkpool_roundtrip_bit_identity(codec, monkeypatch):
-    """demote -> restage rebuilds the StagedBlock bit-identically
-    under every codec: same columns, same dtypes/shapes/bytes, same
-    padded-shape metadata."""
+    """demote -> restage rebuilds every column of a StagedBlock
+    bit-identically under every codec (same dtypes/shapes/bytes), and a
+    fresh reader's view over the restaged columns carries the same
+    padded-shape fields."""
     monkeypatch.setenv("TEMPO_CHUNK_CACHE", "1")
     monkeypatch.setenv("TEMPO_CHUNK_CACHE_CODEC", codec)
     monkeypatch.setenv("TEMPO_CHUNK_CACHE_MIN_REUSE", "1")
-    _, meta, blk = _block()
+    backend, meta, blk = _block()
     staged = stage_block(blk, _NEEDED)
     ref = {k: np.asarray(v).copy() for k, v in staged.cols.items()}
     shape_ref = (staged.n_spans, staged.n_traces, staged.n_res,
                  staged.n_spans_b, staged.n_traces_b, staged.n_res_b,
                  staged.span_base)
-    key = (tuple(_NEEDED), None)
-    assert chunkpool.demote(meta.block_id, key, staged)
-    got = chunkpool.restage(meta.block_id, key)
-    assert got is not None
-    assert set(got.cols) == set(ref)
-    for name in ref:
-        arr = np.asarray(got.cols[name])
-        assert arr.dtype == ref[name].dtype
-        np.testing.assert_array_equal(arr, ref[name])
-    assert (got.n_spans, got.n_traces, got.n_res, got.n_spans_b,
-            got.n_traces_b, got.n_res_b, got.span_base) == shape_ref
+    keys, admitted = _demote_all(meta, blk, staged)
+    assert all(admitted)
+    got = chunkpool.restage(meta.block_id, keys)
+    assert set(got) == set(keys)
+    for key, dev in got.items():
+        arr = np.asarray(dev)
+        assert arr.dtype == ref[key[0][0]].dtype
+        np.testing.assert_array_equal(arr, ref[key[0][0]])
+    view = stage_block(open_block(backend, TENANT, meta.block_id), _NEEDED)
+    assert set(view.cols) == set(ref)
+    assert (view.n_spans, view.n_traces, view.n_res, view.n_spans_b,
+            view.n_traces_b, view.n_res_b, view.span_base) == shape_ref
     assert chunkpool.stats()["codec"] == codec
 
 
 def test_chunkpool_restage_skips_backend_read(monkeypatch):
-    """A fresh reader staging a pooled entry must be served from the
+    """A fresh reader staging pooled columns must be served from the
     pool: the backend read/decode/assemble path is provably never
     entered."""
     monkeypatch.setenv("TEMPO_CHUNK_CACHE", "1")
@@ -364,8 +381,8 @@ def test_chunkpool_restage_skips_backend_read(monkeypatch):
     backend, meta, blk = _block()
     staged = stage_block(blk, _NEEDED)
     ref = {k: np.asarray(v).copy() for k, v in staged.cols.items()}
-    key = (tuple(_NEEDED), None)
-    assert chunkpool.demote(meta.block_id, key, staged)
+    keys, admitted = _demote_all(meta, blk, staged)
+    assert all(admitted)
 
     def boom(*a, **k):
         raise AssertionError("restage fell through to the backend read path")
@@ -374,7 +391,8 @@ def test_chunkpool_restage_skips_backend_read(monkeypatch):
     h0 = chunkpool.stats()["hits"]
     fresh_blk = open_block(backend, TENANT, meta.block_id)
     warm = stage_block(fresh_blk, _NEEDED)
-    assert chunkpool.stats()["hits"] == h0 + 1
+    assert chunkpool.stats()["hits"] == h0 + len(keys)
+    assert set(warm.cols) == set(ref)
     for name in ref:
         np.testing.assert_array_equal(np.asarray(warm.cols[name]), ref[name])
 
@@ -386,11 +404,12 @@ def test_chunkpool_budget_and_admission(monkeypatch):
     monkeypatch.setenv("TEMPO_CHUNK_CACHE", "1")
     monkeypatch.setenv("TEMPO_CHUNK_CACHE_CODEC", "none")
     monkeypatch.setenv("TEMPO_CHUNK_CACHE_MIN_REUSE", "1")
-    key = (tuple(_NEEDED), None)
+    key = (("span.trace_sid",), None)
     blocks = []
     for i in range(4):
         _, meta, blk = _block(n_traces=60, seed=20 + i)
-        blocks.append((meta, stage_block(blk, _NEEDED, cache=False)))
+        blocks.append(
+            (meta, stage_block(blk, _NEEDED, cache=False).cols["span.trace_sid"]))
 
     # size one entry, then budget for two-and-a-half of them
     s0 = chunkpool.stats()
@@ -398,8 +417,8 @@ def test_chunkpool_budget_and_admission(monkeypatch):
     one = chunkpool.stats()["compressed_bytes"]
     assert one > 0
     monkeypatch.setenv("TEMPO_CHUNK_CACHE_BUDGET", str(one * 5 // 2))
-    for meta, staged in blocks[1:]:
-        assert chunkpool.demote(meta.block_id, key, staged)
+    for meta, col in blocks[1:]:
+        assert chunkpool.demote(meta.block_id, key, col)
     st = chunkpool.stats()
     assert st["compressed_bytes"] <= one * 5 // 2
     assert st["entries"] == 2
@@ -411,9 +430,9 @@ def test_chunkpool_budget_and_admission(monkeypatch):
     assert chunkpool.probe(blocks[2][0].block_id, key)
     assert chunkpool.probe(blocks[3][0].block_id, key)
 
-    # per-entry admission cap: an oversized entry is refused
+    # per-entry admission cap: an oversized column is refused
     chunkpool.clear()
-    raw = sum(int(np.asarray(a).nbytes) for a in blocks[0][1].cols.values())
+    raw = int(np.asarray(blocks[0][1]).nbytes)
     monkeypatch.setenv("TEMPO_CHUNK_CACHE_MAX_ENTRY", str(raw // 2))
     assert not chunkpool.demote(blocks[0][0].block_id, key, blocks[0][1])
     assert chunkpool.stats()["entries"] == 0
@@ -433,11 +452,11 @@ def test_chunk_cache_kill_switch(monkeypatch):
     monkeypatch.setenv("TEMPO_CHUNK_CACHE", "0")
     _, meta, blk = _block(n_traces=40, seed=30)
     staged = stage_block(blk, _NEEDED, cache=False)
-    key = (tuple(_NEEDED), None)
     d0 = chunkpool.stats()["demotions"]
-    assert not chunkpool.demote(meta.block_id, key, staged)
+    keys, admitted = _demote_all(meta, blk, staged)
+    assert not any(admitted)
     st = chunkpool.stats()
     assert not st["enabled"]
     assert st["entries"] == 0 and st["demotions"] == d0
-    assert not chunkpool.probe(meta.block_id, key)
-    assert chunkpool.restage(meta.block_id, key) is None
+    assert not any(chunkpool.probe(meta.block_id, k) for k in keys)
+    assert chunkpool.restage(meta.block_id, keys) == {}

@@ -195,32 +195,47 @@ def _case_uncounted():
 
 
 def _case_miss_reason():
-    """stage:lookup's `reason` reads a store other threads insert into
-    and evict from: right on a still store, never raising on a moving one."""
-    from tempo_tpu.ops.stage import _miss_reason
+    """stage:lookup's `hit` / `reason` / `missing` say what the lookup
+    found resident, and a lookup reads a store other threads insert into
+    and evict from without ever raising."""
+    from tempo_tpu.backend import MemBackend
+    from tempo_tpu.block import build_block_from_traces, open_block
+    from tempo_tpu.ops import stage
+    from tempo_tpu.util.testdata import make_traces
 
-    store = {(("a", "b"), None): 1, (("c",), (0, 1)): 2}
-    assert _miss_reason(store, (("a",), None)) == "key_mismatch"
-    assert _miss_reason(store, (("a", "x"), None)) == "partial_columns"
-    assert _miss_reason(store, (("c",), None)) == "not_staged"
-    assert _miss_reason(None, (("a",), None)) == "not_staged"
+    backend = MemBackend()
+    meta = build_block_from_traces(backend, "t", make_traces(30, seed=5, n_spans=4))
+    blk = open_block(backend, "t", meta.block_id)
+
+    def lookup(cols):
+        a = _run_in_trace(lambda: stage.stage_block(blk, cols))["stage:lookup"].attrs
+        return a["hit"], a["reason"], a["missing"]
+
+    got = lookup(["span.dur_us", "trace.span_off"])
+    assert got == (False, "not_staged", 2)
+    got = lookup(["span.dur_us", "span.name_id", "trace.span_off"])
+    assert got == (False, "partial_columns", 1)
+    got = lookup(["trace.span_off", "span.name_id"])  # any spelling, any order
+    assert got == (True, "", 0)
     stop = threading.Event()
+    budget = stage.staged_cache_stats()["budget_bytes"]
 
     def churn():
-        i = 0
         while not stop.is_set():
-            store[((f"k{i % 64}",), None)] = i
-            store.pop(((f"k{(i + 32) % 64}",), None), None)
-            i += 1
+            stage.set_staged_cache_budget(1)
+            stage.set_staged_cache_budget(budget)
 
     th = threading.Thread(target=churn, daemon=True)
     th.start()
     try:
-        for _ in range(20000):
-            assert _miss_reason(store, (("a", "zz"), None)) in ("partial_columns", "")
+        for _ in range(200):
+            view = stage.stage_block(blk, ["span.dur_us", "trace.span_off"])
+            assert set(view.cols) == {"span.dur_us", "trace.span_off"}
     finally:
         stop.set()
         th.join(10)
+        stage.set_staged_cache_budget(budget)
+    assert not th.is_alive()
 
 
 _STAGE_CASES = {
