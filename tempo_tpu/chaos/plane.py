@@ -136,6 +136,9 @@ SEAM_MODULES = {
     "ops/device.py": ("device.launch",),
     "db/wal.py": ("wal.append", "wal.fsync"),
     "fleet/harness.py": (),  # certification driver: fault source
+    # a tree's supervisor: readiness probes and the status fan-out to its
+    # own children; the jobs' wire is worker.py's seam
+    "services/proctree.py": (),
 }
 
 

@@ -194,7 +194,11 @@ KNOBS: dict[str, tuple[str, str, str]] = {
 FLAGS: dict[str, tuple[str, str]] = {
     "--config.file": ("path", "YAML/JSON config file"),
     "--config.expand-env": ("bool", "substitute ${VAR} in the config file"),
-    "--target": ("str", "module preset (all/distributor/querier/...)"),
+    "--target": ("str", "module preset (all/scalable-single-binary/"
+                        "distributor/querier/...)"),
+    "--scalable.instances": ("int", "scalable-single-binary: processes in "
+                                    "the tree, one chip each (default: the "
+                                    "chips the host shows)"),
     "--http.port": ("int", "HTTP listen port"),
     "--storage.path": ("path", "block storage root"),
     "--overrides.path": ("path", "per-tenant overrides file"),

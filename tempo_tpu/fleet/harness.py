@@ -34,7 +34,6 @@ import argparse
 import json
 import os
 import signal
-import socket
 import subprocess
 import sys
 import threading
@@ -42,8 +41,16 @@ import time
 import urllib.error
 import urllib.request
 
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+# every role is an app process started, waited for and stopped the way
+# the scalable-single-binary target starts its own children
+from ..services.proctree import (
+    REPO_ROOT,
+    fetch,
+    free_port as _free_port,
+    spawn_app,
+    stop_procs,
+    wait_ready as _wait_ready,
+)
 
 # must clear the gossip full-sync cadence (1s) with margin: a live
 # replica whose latest heartbeat is still in flight between peers must
@@ -67,32 +74,8 @@ DISTRIBUTOR_CHAOS = json.dumps({
 })
 
 
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    p = s.getsockname()[1]
-    s.close()
-    return p
-
-
-def _wait_ready(port: int, timeout: float = 90.0) -> None:
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        try:
-            with urllib.request.urlopen(
-                    f"http://127.0.0.1:{port}/ready", timeout=1) as r:
-                if r.status == 200:
-                    return
-        except (urllib.error.URLError, ConnectionError, OSError):
-            pass
-        time.sleep(0.3)
-    raise TimeoutError(f"port {port} never became ready")
-
-
 def _get_json(port: int, path: str, timeout: float = 10.0) -> dict:
-    with urllib.request.urlopen(
-            f"http://127.0.0.1:{port}{path}", timeout=timeout) as r:
-        return json.loads(r.read())
+    return json.loads(fetch(port, path, timeout=timeout))
 
 
 class FleetTopology:
@@ -123,8 +106,7 @@ class FleetTopology:
         port = self.ports.setdefault(name, _free_port())
         gport = self.gports.setdefault(name, _free_port())
         seed = f"127.0.0.1:{self.gports[self._ingesters[0]]}"
-        args = [sys.executable, "-m", "tempo_tpu.services.app",
-                f"--target={target}", "--http.port", str(port),
+        args = [f"--target={target}", "--http.port", str(port),
                 "--storage.path", self.storage,
                 "--memberlist.bind", f"127.0.0.1:{gport}",
                 "--instance.id", name,
@@ -136,8 +118,7 @@ class FleetTopology:
         self.logs[name] = log
         env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT)
         env.pop("TEMPO_CHAOS", None)  # only explicit per-role rules
-        self.procs[name] = subprocess.Popen(
-            args, env=env, stdout=log, stderr=subprocess.STDOUT)
+        self.procs[name] = spawn_app(args, env=env, log=log)
 
     def start(self) -> None:
         for name in self._ingesters:
@@ -167,20 +148,14 @@ class FleetTopology:
         p = self.procs[name]
         p.send_signal(signal.SIGKILL)
         p.wait(timeout=15)
+        stop_procs([p])  # reaped: closes its lifeline
 
     def respawn_ingester(self, name: str) -> None:
         self._spawn(name, "ingester")
         _wait_ready(self.ports[name])
 
     def stop(self) -> None:
-        for p in self.procs.values():
-            if p.poll() is None:
-                p.terminate()
-        for p in self.procs.values():
-            try:
-                p.wait(timeout=10)
-            except subprocess.TimeoutExpired:
-                p.kill()
+        stop_procs(list(self.procs.values()), grace_s=10.0)
         for log in self.logs.values():
             try:
                 log.close()

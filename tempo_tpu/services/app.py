@@ -36,6 +36,7 @@ from .distributor import Distributor, PushError
 from .frontend import Frontend, TooManyRequests
 from .ingester import Ingester, IngesterConfig
 from .overrides import Overrides
+from .proctree import SCALABLE_TARGET
 from .querier import Querier
 
 DEFAULT_TENANT = "single-tenant"
@@ -138,15 +139,30 @@ class AppConfig:
     # standalone-querier worker threads against the frontend job API
     # (reference: querier.max-concurrent-queries)
     worker_concurrency: int = 4
+    # --target scalable-single-binary: processes in the tree, one chip
+    # each (0 = the chips the host shows); the replica count of the
+    # reference's scalable deployment
+    scalable_instances: int = 0
 
 
 class App:
     """All modules of one process, wired per target."""
 
-    VALID_TARGETS = ("all", "distributor", "ingester", "querier", "query-frontend",
-                     "compactor", "metrics-generator")
+    VALID_TARGETS = ("all", SCALABLE_TARGET, "distributor", "ingester", "querier",
+                     "query-frontend", "compactor", "metrics-generator")
 
     def __init__(self, cfg: AppConfig):
+        # instance 0 of a scalable tree is the single binary plus a
+        # supervisor of querier children; they reach its ingester through
+        # a ring of the tree's own, made anew with every start
+        single_binary = cfg.target in ("all", SCALABLE_TARGET)
+        n_tree = (max(1, cfg.scalable_instances)
+                  if cfg.target == SCALABLE_TARGET else 1)
+        if n_tree > 1 and not (cfg.kv_dir or cfg.gossip_bind):
+            import shutil
+
+            cfg.kv_dir = os.path.join(cfg.storage_path, "scalable-ring")
+            shutil.rmtree(cfg.kv_dir, ignore_errors=True)
         shared_ring = bool(cfg.kv_dir or cfg.gossip_bind)
         if cfg.target == "distributor" and not shared_ring:
             raise ValueError(
@@ -170,9 +186,9 @@ class App:
             chaos_plane.configure_spec(cfg.chaos_rules)
 
         def has(role: str) -> bool:
-            return cfg.target in ("all", role)
+            return single_binary or cfg.target == role
 
-        if shared_ring and cfg.target in ("all", "ingester") and not cfg.advertise_addr.startswith(
+        if shared_ring and (single_binary or cfg.target == "ingester") and not cfg.advertise_addr.startswith(
             ("http://", "https://")
         ):
             raise ValueError(
@@ -272,7 +288,7 @@ class App:
 
         self.generator = self.generator_lifecycler = None
         gen_forward = None
-        if cfg.enable_generator and (has("metrics-generator") or cfg.target == "all"):
+        if cfg.enable_generator and has("metrics-generator"):
             from .generator import MetricsGenerator
 
             self.generator = MetricsGenerator(self.overrides)
@@ -326,6 +342,19 @@ class App:
             n_workers = cfg.frontend_workers
             if cfg.target == "query-frontend" and shared_ring:
                 n_workers = 0
+            if n_tree > 1:
+                from ..util.log import get_logger
+                from .proctree import TREE_WORKER_CONCURRENCY
+
+                if n_workers > TREE_WORKER_CONCURRENCY:
+                    # said aloud: the tree overrides a configured value
+                    get_logger("app").info(
+                        "process tree: instance 0 runs fewer in-process "
+                        "workers than frontend_workers asks for",
+                        frontend_workers=n_workers,
+                        running=TREE_WORKER_CONCURRENCY,
+                        querier_worker_concurrency=cfg.worker_concurrency)
+                n_workers = min(n_workers, TREE_WORKER_CONCURRENCY)
             self.frontend = Frontend(self.querier, n_workers=n_workers,
                                      overrides=self.overrides)
             if self.frontend.result_cache is not None and self.ingester is not None:
@@ -344,6 +373,7 @@ class App:
                     token=cfg.internal_token,
                     concurrency=cfg.worker_concurrency,
                     worker_id=cfg.instance_id,
+                    device=self.device,
                 )
 
         # blocklist-poll sharding (fleet/poller_shard): standalone
@@ -360,6 +390,12 @@ class App:
                 Ring(self.kv, QUERIER_RING, heartbeat_timeout=hb_timeout),
                 cfg.instance_id)
             self.poller_shard.install(self.db)
+
+        self.tree = None
+        if n_tree > 1:
+            from .proctree import QuerierTree
+
+            self.tree = QuerierTree(cfg, n_tree, self.frontend, cfg.kv_dir)
 
         self.compactor = self.compactor_lifecycler = None
         if has("compactor"):
@@ -515,9 +551,13 @@ class App:
 
             self.warmup_report = run_warmup()
         self.db.enable_polling()
+        if self.tree is not None:
+            self.tree.start()  # they poll with back-off until the port serves
         self._started = True
 
     def stop(self) -> None:
+        if self.tree is not None:
+            self.tree.stop()  # children first: they post results here
         if self.distributor is not None:
             self.distributor.stop()  # drain the async generator tap
         if self.remote_writer is not None:
@@ -560,6 +600,8 @@ class App:
     def ready(self) -> bool:
         if not self._started:
             return False
+        if self.tree is not None and not self.tree.ready():
+            return False  # a querier has not attached (or died)
         if self.ingester is not None:
             return bool(self.ring.healthy_instances())
         return True
@@ -727,6 +769,9 @@ def _make_handler(app: App):
         def do_GET(self):
             u = urlparse(self.path)
             q = {k: v[0] for k, v in parse_qs(u.query).items()}
+            # a tree's status speaks for the deployment unless asked for
+            # this instance alone
+            whole_tree = app.tree is not None and q.get("scope") != "instance"
             try:
                 # operational endpoints never need a tenant (probes/scrapes
                 # carry no X-Scope-OrgID)
@@ -761,7 +806,14 @@ def _make_handler(app: App):
                 if u.path == "/status/kernels":
                     # kernel telemetry: compile/cache-hit table, staged-
                     # cache contents, routing reasons, slow-query log
-                    return self._send(200, json.dumps(_kernel_status(app), indent=2))
+                    own = _kernel_status(app)
+                    if whole_tree:
+                        # the sum over instances, each one's own beside
+                        from .proctree import tree_kernel_status
+
+                        own = tree_kernel_status(
+                            own, _tree_get(app, "/status/kernels"))
+                    return self._send(200, json.dumps(own, indent=2))
                 if u.path == "/status/cost":
                     # device cost plane (util/costmodel): per-(op,bucket)
                     # FLOPs/bytes/utilization vs roofline, collective
@@ -769,8 +821,18 @@ def _make_handler(app: App):
                     # and compile-cache state
                     from ..util.costmodel import COST
 
-                    return self._send(
-                        200, json.dumps(COST.status_snapshot(), indent=2))
+                    snap = COST.status_snapshot()
+                    if whole_tree:
+                        # every instance's chip in the HBM list, numbered
+                        # by instance (each sees its own as device 0)
+                        stats = snap.setdefault("hbm", {}).setdefault(
+                            "per_device_memory_stats", [])
+                        for info, other in _tree_get(app, "/status/cost"):
+                            for d in ((other.get("hbm") or {}).get(
+                                    "per_device_memory_stats") or []):
+                                stats.append({**d, "id": info["index"],
+                                              "instance": info["id"]})
+                    return self._send(200, json.dumps(snap, indent=2))
                 if u.path == "/status/chaos":
                     # chaos + resilience surface: active fault rules
                     # with call/fire counts, the recent injection log,
@@ -887,8 +949,11 @@ def _make_handler(app: App):
                     if not app._profile_lock.acquire(blocking=False):
                         return self._err(409, "a profile is already running")
                     try:
-                        aid, summary = PROF.capture_device_profile(
-                            secs, python=q.get("python", "") in ("1", "true"))
+                        if whole_tree:
+                            aid, summary = _tree_device_profile(app, secs)
+                        else:
+                            aid, summary = PROF.capture_device_profile(
+                                secs, python=q.get("python", "") in ("1", "true"))
                     except ProfilerUnavailable as e:
                         return self._err(503, f"device profiler: {e}")
                     finally:
@@ -1107,8 +1172,13 @@ def _make_handler(app: App):
                     from ..transport.frames import CONTENT_TYPE as FRAMES_CT
 
                     ctype = self.headers.get("Content-Type", "")
-                    payload = ({} if ctype.startswith(FRAMES_CT)
-                               else json.loads(body or b"{}"))
+                    if u.path == "/internal/jobs/result":
+                        # the frontend's side of the wire: a result decoded
+                        with TEL.stage("job:decode", bytes=len(body)):
+                            payload = json.loads(body or b"{}")
+                    else:
+                        payload = ({} if ctype.startswith(FRAMES_CT)
+                                   else json.loads(body or b"{}"))
                     code, out = handle_internal(
                         app, u.path, payload, raw_body=body, content_type=ctype,
                         accept=self.headers.get("Accept", ""),
@@ -1235,6 +1305,79 @@ def build_default_slo(frontend, generator=None):
             description=f"pushed spans reflected in generated series "
                         f"within {gen_thr:g}s (streaming tap fold lag)"))
     return engine
+
+
+def _tree_get(app: App, path: str) -> list[tuple[dict, dict]]:
+    """[(instance row, its JSON answer to `path` or {})] for every
+    querier child of a tree, asked at once."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from .proctree import fetch
+
+    def one(info: dict):
+        if not info["alive"]:
+            return info, {}
+        try:
+            return info, json.loads(fetch(info["port"], path,
+                                          app.cfg.internal_token))
+        except (OSError, ValueError):
+            return info, {}
+
+    rows = app.tree.instances()
+    with ThreadPoolExecutor(max_workers=max(1, len(rows))) as ex:
+        return list(ex.map(one, rows))
+
+
+def _tree_device_profile(app: App, seconds: float) -> tuple[str, dict]:
+    """One device-trace artifact for a tree: a session in every
+    instance over the same wall-clock span (each process can trace only
+    the chip it owns), then one zip whose first file holds every chip's
+    plane (proctree.merge_xspaces) and, after it, each instance's own
+    files as its profiler wrote them."""
+    import io
+    import zipfile
+    from concurrent.futures import ThreadPoolExecutor
+
+    from ..util.profiler import PROF, ProfilerUnavailable
+    from .proctree import fetch, merge_xspaces
+
+    def child(info: dict) -> bytes | None:
+        try:
+            tok = app.cfg.internal_token
+            out = json.loads(fetch(
+                info["port"], f"/debug/profile/device?seconds={seconds}",
+                tok, timeout=seconds + 120))
+            return fetch(info["port"],
+                         "/debug/profile/artifact/" + out["artifact_id"],
+                         tok, timeout=120)
+        except (OSError, ValueError, KeyError):
+            return None
+
+    rows = [r for r in app.tree.instances() if r["alive"]]
+    with ThreadPoolExecutor(max_workers=max(1, len(rows))) as ex:
+        futs = [ex.submit(child, r) for r in rows]
+        aid0, summary = PROF.capture_device_profile(seconds)
+        zips = [PROF.artifact_bytes(aid0)] + [f.result() for f in futs]
+    spaces, files = [], []
+    for i, data in enumerate(zips):
+        if data is None:
+            continue
+        with zipfile.ZipFile(io.BytesIO(data)) as z:
+            for name in z.namelist():
+                body = z.read(name)
+                files.append((f"instances/{i}/{name}", body))
+                if name.endswith(".xplane.pb"):
+                    spaces.append(body)
+    if not spaces:
+        raise ProfilerUnavailable("no instance produced a trace file")
+    buf = io.BytesIO()
+    with zipfile.ZipFile(buf, "w", zipfile.ZIP_DEFLATED) as z:
+        z.writestr("tree.xplane.pb", merge_xspaces(spaces))
+        for name, body in files:
+            z.writestr(name, body)
+    aid = PROF.put_artifact("device", buf.getvalue(), suffix=".zip")
+    return aid, {**summary, "bytes": buf.tell(), "files": len(files) + 1,
+                 "instances": len(spaces)}
 
 
 def _kernel_status(app: App) -> dict:
@@ -1547,6 +1690,32 @@ def load_config_file(path: str, expand_env: bool = False) -> dict:
     return data
 
 
+def _prepare_tree(cfg: AppConfig, concurrency_given: bool) -> None:
+    """--target scalable-single-binary, before anything touches the
+    jax backend: settle how many instances the tree has and pin THIS
+    process (instance 0) to its chip. On a TPU host an instance needs a
+    chip of its own; under JAX_PLATFORMS=cpu instances are plain
+    processes and nothing is pinned."""
+    from . import proctree
+
+    chips = proctree.visible_chips()
+    n = cfg.scalable_instances or chips or 1
+    if n < 1:
+        raise ValueError("--scalable.instances must be at least 1")
+    if not proctree.on_cpu():
+        if chips and n > chips:
+            raise ValueError(
+                f"--scalable.instances {n} but this host shows {chips} "
+                "chips: an instance owns exactly one")
+        xb = sys.modules.get("jax._src.xla_bridge")
+        if xb is not None and getattr(xb, "_backends", None):
+            raise ValueError("the jax backend is already up: too late to pin")
+        os.environ.update(proctree.pin_env(0, n))
+    cfg.scalable_instances = n
+    if not concurrency_given:
+        cfg.worker_concurrency = proctree.TREE_WORKER_CONCURRENCY
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(prog="tempo-tpu")
     # None defaults = "flag not given"; a flag the user set ALWAYS overrides
@@ -1626,7 +1795,19 @@ def main(argv=None):
                     type=int, default=None,
                     help="standalone-querier worker threads pulling "
                          "frontend jobs")
+    ap.add_argument("--scalable.instances", dest="scalable_instances",
+                    type=int, default=None,
+                    help="--target scalable-single-binary: processes in "
+                         "the tree, one chip each; instance 0 serves the "
+                         "port, the others are queriers pulling its jobs "
+                         "(default: the chips this host shows)")
+    ap.add_argument("--lifeline.fd", dest="lifeline_fd", type=int, default=None,
+                    help=argparse.SUPPRESS)  # services/proctree.spawn_app
     args = ap.parse_args(argv)
+    if args.lifeline_fd is not None:
+        from .proctree import watch_lifeline
+
+        watch_lifeline(args.lifeline_fd)
     base = (load_config_file(args.config_file, args.config_expand_env)
             if args.config_file else {})
     flag_vals = {
@@ -1660,11 +1841,17 @@ def main(argv=None):
         "ring_heartbeat_timeout": args.ring_heartbeat_timeout,
         "rpc_deadline_s": args.rpc_deadline,
         "worker_concurrency": args.worker_concurrency,
+        "scalable_instances": args.scalable_instances,
     }
     base.update({k: v for k, v in flag_vals.items() if v is not None})
     cfg = AppConfig(**base)
     if not cfg.advertise_addr:
         cfg.advertise_addr = f"http://127.0.0.1:{cfg.http_port}"
+    if cfg.target == SCALABLE_TARGET:
+        try:
+            _prepare_tree(cfg, "worker_concurrency" in base)
+        except ValueError as e:
+            sys.exit(f"tempo-tpu target={cfg.target}: {e}")
     from ..util.costmodel import NoAcceleratorError
 
     try:
@@ -1677,7 +1864,8 @@ def main(argv=None):
           f"device={dev['platform']} kind={dev['device_kind']!r} "
           f"count={dev['count']}", flush=True)
     # SIGTERM is how supervisors stop the process: drain like ^C and
-    # exit 0, so the chip is released for the next process
+    # exit 0, so the chip is released for the next process (a tree
+    # stops its children first: App.stop)
     signal.signal(signal.SIGTERM, lambda *_: threading.Thread(
         target=app.stop, daemon=True).start())
     try:

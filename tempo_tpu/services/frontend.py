@@ -69,6 +69,9 @@ BACKEND_KINDS = frozenset(
     {"search_blocks", "search_block_shard", "find_blocks",
      "metrics_query_range"})
 
+# dequeue placements as the dispatch spans spell them
+_PLACEMENT_NAMES = {"own": "owner", "steal": "stolen", "unowned": "unowned"}
+
 AFFINITY_RING_KEY = "querier-affinity"
 AFFINITY_STEAL_MS = 75.0  # default anti-starvation steal timeout
 AFFINITY_SCAN_WINDOW = 64  # queued jobs per tenant an affinity scan inspects
@@ -360,6 +363,15 @@ class _Job:
     hedge_started: bool = False
     hedge_outcome: str = ""
     lease_redispatched: bool = False  # re-enqueued by lease expiry
+    # job dispatch: who had the job in hand ("local" = this process's
+    # threads, else the remote querier's id), when (wall clock; for a
+    # remote leg the querier's own stamp of receiving it), and when its
+    # result was posted and merged -- the `job:dispatch` / `job:result`
+    # spans of the timeline (the last hand-off, for a hedged job)
+    worker: str = ""
+    handed_wall: float = 0.0
+    posted_wall: float = 0.0
+    merged_wall: float = 0.0
 
     def finish(self) -> None:
         if not self.done.is_set():  # a late hedge twin must not clobber
@@ -444,6 +456,11 @@ class Frontend:
         # overrides-driven, so without overrides there is no gate
         self.qos = QueryAdmission(overrides) if overrides is not None else None
         self._remote_workers: dict[str, float] = {}  # worker id -> last poll
+        # worker id -> the device it reported with its polls (a querier
+        # that owns a chip: services/worker); lease id -> its worker
+        self._remote_devices: dict[str, dict] = {}
+        self._lease_workers: dict[str, str] = {}
+        self._lost_at: dict[str, float] = {}  # worker id -> worker_lost()
         # backend-leg circuit breaker (util/breaker): block-scanning
         # jobs shed fast onto the shard-degradation path while the
         # backend is dying, with half-open probes for recovery
@@ -504,6 +521,17 @@ class Frontend:
             if j.dequeued_wall and j.dequeued_wall >= j.started_wall:
                 t.child("queue-wait", j.started_wall, j.dequeued_wall,
                         {}, parent=sid)
+            if j.handed_wall and j.handed_wall >= j.started_wall:
+                # enqueue -> a worker has it in hand: the queue wait
+                # plus, for a remote querier, the poll's way back
+                t.child("job:dispatch", j.started_wall, j.handed_wall,
+                        {"worker": j.worker, "remote": j.worker != "local",
+                         "placement": _PLACEMENT_NAMES.get(j.placement,
+                                                           j.placement)},
+                        parent=sid)
+            if j.posted_wall and j.merged_wall >= j.posted_wall:
+                t.child("job:result", j.posted_wall, j.merged_wall,
+                        {"worker": j.worker}, parent=sid)
 
     # --------------------------------------------------- affinity routing
     def _affinity_members(self) -> list[InstanceDesc]:
@@ -592,6 +620,21 @@ class Frontend:
             if p:
                 TEL.record_affinity(p)
 
+    def _note_done(self, job) -> None:
+        """A worker produced this job's result: the dispatch counters
+        and the `job:dispatch` / `job:result` rows of the stages table
+        (the spans are emitted with the trace, _emit_self_trace)."""
+        from ..util.kerneltel import TEL
+
+        now = time.time()
+        TEL.record_dispatch(job.worker or "local",
+                            now - (job.handed_wall or now))
+        if job.handed_wall and job.started_wall:
+            TEL.record_stage("job:dispatch", job.handed_wall - job.started_wall)
+        if job.posted_wall:
+            job.merged_wall = now
+            TEL.record_stage("job:result", now - job.posted_wall)
+
     # ------------------------------------------------------ per-tenant QoS
     def _qos_admit(self, tenant: str, est_bytes: int) -> int:
         """Admit one query against the tenant's QoS budgets; returns the
@@ -664,6 +707,7 @@ class Frontend:
         for _, j in live:
             if not j.dequeued_wall:
                 j.dequeued_wall = now_wall
+            j.worker, j.handed_wall = "local", now_wall
             j.exec_seq += 1
             seqs[id(j)] = j.exec_seq
             if j.exec_seq >= 2:
@@ -713,6 +757,7 @@ class Frontend:
                 if not j.done.is_set():
                     j.result = r
                 self.stats_jobs_local += 1
+                self._note_done(j)
                 j.finish()
         else:
             for t, j in live:
@@ -823,6 +868,7 @@ class Frontend:
             job.hedge_started = True
         if not job.dequeued_wall:
             job.dequeued_wall = time.time()
+        job.worker, job.handed_wall = "local", time.time()
         token = (TEL.set_active_trace(job.trace)
                  if job.trace is not None else None)
         stoken = (set_current_span(job.span_id)
@@ -836,6 +882,7 @@ class Frontend:
             if not job.done.is_set():
                 job.result = res
             self.stats_jobs_local += 1
+            self._note_done(job)
         except Exception as e:
             # retry only transient failures (reference retries 5xx
             # only, modules/frontend/retry.go); a parse error or bad
@@ -901,7 +948,8 @@ class Frontend:
 
     REMOTE_BATCH_MAX = 8  # same-key jobs merged into one wire pull
 
-    def poll_job(self, wait_s: float = 5.0, worker_id: str = ""):
+    def poll_job(self, wait_s: float = 5.0, worker_id: str = "",
+                 device: dict | None = None):
         """Long-poll dequeue for a remote querier worker
         (frontend_processor.go's stream recv). Returns a wire job dict
         or None on timeout. Same-key jobs queued at poll time merge into
@@ -912,9 +960,12 @@ class Frontend:
         past the steal timeout. The wire job carries the dequeue
         placement so the remote process attributes its staged-cache
         hits."""
+        began = time.monotonic()
         if worker_id:
             with self._lease_lock:
-                self._remote_workers[worker_id] = time.monotonic()
+                self._remote_workers[worker_id] = began
+                if device:
+                    self._remote_devices[worker_id] = device
         self._requeue_expired()
         allowed = (lambda t: self._tenant_allowed(t, worker_id)) if worker_id else None
         deadline = time.monotonic() + wait_s
@@ -953,6 +1004,8 @@ class Frontend:
             for _, j in pairs:
                 if not j.dequeued_wall:
                     j.dequeued_wall = now_wall
+                # (the querier's own stamp replaces this with its result)
+                j.worker, j.handed_wall = worker_id or "remote", now_wall
                 j.exec_seq += 1
                 seqs.append(j.exec_seq)
                 if j.exec_seq >= 2:
@@ -961,6 +1014,15 @@ class Frontend:
             with self._lease_lock:
                 self._leases[jid] = (pairs, time.monotonic() + self.lease_s,
                                      seqs)
+                self._lease_workers[jid] = worker_id
+                orphan = self._lost_at.get(worker_id, 0.0) >= began
+            if orphan:
+                # the worker died while this poll of its waited here (the
+                # handler outlives the socket by up to wait_s): nobody
+                # would read the answer, and the lease would hold the job
+                # for lease_s -- a hedge twin can meet the same fate
+                self._requeue_expired(lost_lease=jid)
+                return None
             placement = pairs[0][1].placement
             # deadline propagation, gRPC-style RELATIVE budget: the
             # remaining seconds at dispatch ride the wire job, so the
@@ -999,7 +1061,8 @@ class Frontend:
     def complete_job(self, jid: str, ok: bool, result: dict | None = None,
                      error: str = "", retryable: bool = False,
                      self_spans: list | None = None,
-                     skipped: bool = False) -> None:
+                     skipped: bool = False, received_unix: float = 0.0,
+                     posted_unix: float = 0.0) -> None:
         """Remote worker posts a job result (or a `multi` result list,
         demuxed per leased job). Unknown/expired lease ids are dropped
         (the job was re-dispatched or timed out). self_spans: the remote
@@ -1007,6 +1070,7 @@ class Frontend:
         trace (they were recorded against its span ids)."""
         with self._lease_lock:
             lease = self._leases.pop(jid, None)
+            self._lease_workers.pop(jid, None)
         if lease is None:
             return
         pairs, _, lease_seqs = lease
@@ -1060,6 +1124,13 @@ class Frontend:
                         job, lease_seqs[i] if i < len(lease_seqs) else 1)
                     job.result = decoded
                     self.stats_jobs_remote += 1
+                    # the querier's clock is this host's: its stamps of
+                    # taking the job and of posting the result bound
+                    # the two crossings of the wire
+                    if received_unix:
+                        job.handed_wall = max(received_unix, job.dequeued_wall)
+                    job.posted_wall = posted_unix
+                    self._note_done(job)
             # breaker food is results that exercised the backend AND
             # (on failure) look transient -- deterministic failures
             # (bad query, missing object) say nothing about its health
@@ -1084,14 +1155,38 @@ class Frontend:
                 job.error = RuntimeError(job_error or "remote job failed")
             job.finish()
 
-    def _requeue_expired(self) -> None:
+    def attached_workers(self) -> dict[str, dict]:
+        """Remote queriers that polled within worker_expiry_s and said
+        which device they own: worker id -> {platform, device_kind,
+        count}."""
+        now = time.monotonic()
+        with self._lease_lock:
+            return {w: dict(self._remote_devices[w])
+                    for w, t in self._remote_workers.items()
+                    if now - t < self.worker_expiry_s
+                    and w in self._remote_devices}
+
+    def worker_lost(self, worker_id: str) -> None:
+        """A querier is known to be gone (its supervisor saw it exit):
+        forget it as a cache domain and put what it had leased back on
+        the queue now instead of at the lease's end."""
+        with self._lease_lock:
+            self._remote_workers.pop(worker_id, None)
+            self._remote_devices.pop(worker_id, None)
+            self._lost_at[worker_id] = time.monotonic()
+        self._requeue_expired(lost_worker=worker_id)
+
+    def _requeue_expired(self, lost_worker: str = "", lost_lease: str = "") -> None:
         now = time.monotonic()
         expired = []
         with self._lease_lock:
             for jid, (pairs, exp, _seqs) in list(self._leases.items()):
-                if exp < now:
+                if exp < now or jid == lost_lease or (
+                        lost_worker and
+                        self._lease_workers.get(jid) == lost_worker):
                     expired.extend(pairs)
                     del self._leases[jid]
+                    self._lease_workers.pop(jid, None)
         for tenant, job in expired:
             if not (job.done.is_set() or job.cancelled):
                 try:
@@ -1650,6 +1745,10 @@ class Frontend:
                 n_jobs = 2  # the shard/merge path is the production path: keep it hot
             per_job = -(-nb // n_jobs)
             jobs: list[_Job] = []
+            try:
+                metas = self.querier.db.blocklist.metas(tenant)
+            except AttributeError:  # a stub querier (tests)
+                metas = []
             for lo in range(0, nb, per_job):
                 hi = min(lo + per_job, nb)
                 sub = MetricsRequest(
@@ -1658,10 +1757,16 @@ class Frontend:
                     end_ms=req.start_ms + hi * req.step_ms,
                     step_ms=req.step_ms,
                 )
+                # placed like a search of the first block the sub-range
+                # covers: whole-block columns then stage where that
+                # block's other columns already live
+                lead = next((m.block_id for m in metas if m.overlaps_time(
+                    sub.start_ms // 1000, -(-sub.end_ms // 1000))), None)
                 jobs.append(_Job(
                     kind="metrics_query_range",
                     payload={"req": metrics_request_to_dict(sub)},
                     fn=self.querier.metrics_query_range, args=(tenant, sub),
+                    affinity_key=lead,
                 ))
             attach_trace(jobs, trace)
             self._run_jobs(tenant, jobs)

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -132,10 +133,15 @@ def execute_job(querier: Querier, tenant: str, kind: str, payload: dict) -> dict
 class QuerierWorker:
     """Long-poll worker loops against one or more frontend addresses."""
 
+    # the device this process resolved at start, said with every poll:
+    # the frontend counts a querier as attached once it knows
+    device: dict | None = None
+
     def __init__(self, querier: Querier, frontend_addrs: list[str],
                  token: str = "", concurrency: int = 4, poll_wait_s: float = 5.0,
-                 worker_id: str = ""):
+                 worker_id: str = "", device: dict | None = None):
         self.querier = querier
+        self.device = device
         self.addrs = [a.rstrip("/") for a in frontend_addrs]
         self.token = token
         self.poll_wait_s = poll_wait_s
@@ -164,6 +170,9 @@ class QuerierWorker:
     BACKOFF_CAP_S = 5.0
 
     def _post(self, addr: str, path: str, payload: dict, timeout: float) -> dict | None:
+        """POST JSON, answer the decoded reply. A job crossing the wire
+        -- a result going out (`job:encode`), a job coming in
+        (`job:decode`) -- is timed with its bytes."""
         from ..chaos import plane as chaos_plane
 
         if chaos_plane.tap("rpc.worker", key=path) is chaos_plane.DROP:
@@ -171,12 +180,19 @@ class QuerierWorker:
         headers = {"Content-Type": "application/json"}
         if self.token:
             headers["X-Tempo-Internal-Token"] = self.token
-        req = urllib.request.Request(
-            addr + path, data=json.dumps(payload).encode(), headers=headers
-        )
+        if "id" in payload:
+            with TEL.stage("job:encode") as st:
+                data = json.dumps(payload).encode()
+                st.attrs["bytes"] = len(data)
+        else:
+            data = json.dumps(payload).encode()
+        req = urllib.request.Request(addr + path, data=data, headers=headers)
         with urllib.request.urlopen(req, timeout=timeout) as r:
             body = r.read()
-            return json.loads(body) if body else None
+        if path.endswith("/poll") and len(body) > 2:  # not an idle poll's `{}`
+            with TEL.stage("job:decode", bytes=len(body)):
+                return json.loads(body)
+        return json.loads(body) if body else None
 
     def _loop(self, addr: str) -> None:
         import random
@@ -186,7 +202,8 @@ class QuerierWorker:
             try:
                 job = self._post(addr, "/internal/jobs/poll",
                                  {"wait_s": self.poll_wait_s,
-                                  "worker_id": self.worker_id},
+                                  "worker_id": self.worker_id,
+                                  "device": self.device},
                                  timeout=self.poll_wait_s + 10.0)
             except (urllib.error.URLError, ConnectionError, OSError):
                 # full jitter: sleep U(0, backoff), then double the cap
@@ -218,7 +235,7 @@ class QuerierWorker:
                 except (urllib.error.URLError, ConnectionError, OSError):
                     pass
                 continue
-            out = {"id": job["id"]}
+            out = {"id": job["id"], "received_unix": time.time()}
             # the frontend's dequeue placement (own/steal/unowned) rides
             # the wire job so THIS process's staged-cache hits attribute
             # to owner-vs-stolen routing in its own kerneltel
@@ -259,6 +276,7 @@ class QuerierWorker:
                 spans = recorder.to_wire()
                 if spans:
                     out["self_spans"] = spans
+            out["posted_unix"] = time.time()
             try:
                 self._post(addr, "/internal/jobs/result", out, timeout=10.0)
             except (urllib.error.URLError, ConnectionError, OSError):

@@ -21,6 +21,7 @@ import urllib.error
 import urllib.request
 
 from ..db.search import SearchRequest, SearchResponse
+from ..util.kerneltel import TEL
 from ..wire import otlp_json
 from ..wire.model import Trace
 
@@ -259,17 +260,28 @@ def handle_internal(app, path: str, payload: dict, raw_body: bytes = b"",
         if app.frontend is None:
             return 404, {"error": f"target {app.cfg.target} hosts no frontend"}
         job = app.frontend.poll_job(wait_s=float(payload.get("wait_s", 5.0)),
-                                    worker_id=payload.get("worker_id", ""))
-        return 200, (job or {})
+                                    worker_id=payload.get("worker_id", ""),
+                                    device=payload.get("device"))
+        if not job:
+            return 200, {}
+        # the frontend's side of the wire: a job encoded once, here
+        with TEL.stage("job:encode", kind=job["kind"]) as st:
+            body = json.dumps(job).encode()
+            st.attrs["bytes"] = len(body)
+        TEL.add_wire_bytes(len(body))
+        return 200, (body, "application/json")
     if path == "/internal/jobs/result":
         if app.frontend is None:
             return 404, {"error": f"target {app.cfg.target} hosts no frontend"}
+        TEL.add_wire_bytes(len(raw_body or b""))
         app.frontend.complete_job(
             payload.get("id", ""), bool(payload.get("ok")),
             result=payload.get("result"), error=payload.get("error", ""),
             retryable=bool(payload.get("retryable")),
             self_spans=payload.get("self_spans"),
             skipped=bool(payload.get("skipped")),
+            received_unix=float(payload.get("received_unix") or 0.0),
+            posted_unix=float(payload.get("posted_unix") or 0.0),
         )
         return 200, {}
     if path == "/internal/genpush":

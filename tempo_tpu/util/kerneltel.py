@@ -309,6 +309,11 @@ class KernelTelemetry:
             help="staged-cache lookups by job placement (own/steal/"
                  "unowned/none) and result")
         self._affinity: dict[str, int] = {}
+        # job dispatch (services/frontend): jobs by where they ran, per
+        # worker [jobs, busy seconds], bytes over the frontend -> querier wire
+        self._dispatch_jobs: dict[str, int] = {"local": 0, "remote": 0}
+        self._dispatch_workers: dict[str, list] = {}
+        self._dispatch_wire_bytes = 0
         self._qos_sheds: dict[str, dict[str, int]] = {}
         self._staged_by_placement: dict[str, list[int]] = {}
         # live-head staging (ops/livestage): slot/row occupancy by
@@ -944,6 +949,38 @@ class KernelTelemetry:
         except Exception:
             pass
 
+    # ------------------------------------------------------- job dispatch
+    def record_stage(self, name: str, seconds: float) -> None:
+        """One sample of a stage measured by its caller (an interval
+        that no `with` body spans, e.g. enqueue -> handed to a worker):
+        the `stages` table only; the caller adds the span."""
+        try:
+            self._add_stage(name, max(0.0, seconds))
+        except Exception:
+            pass
+
+    def record_dispatch(self, worker: str, busy_s: float) -> None:
+        """One job completed by `worker` ("local" = this process's own
+        threads, else a remote querier's id), busy from the hand-off to
+        its result."""
+        with self._lock:
+            self._dispatch_jobs["local" if worker == "local" else "remote"] += 1
+            row = self._dispatch_workers.setdefault(worker, [0, 0.0])
+            row[0] += 1
+            row[1] += max(0.0, busy_s)
+
+    def add_wire_bytes(self, n: int) -> None:
+        with self._lock:
+            self._dispatch_wire_bytes += int(n)
+
+    def dispatch_stats(self) -> dict:
+        with self._lock:
+            return {"jobs": dict(self._dispatch_jobs),
+                    "by_worker": {w: {"jobs": r[0],
+                                      "busy_seconds": round(r[1], 6)}
+                                  for w, r in sorted(self._dispatch_workers.items())},
+                    "wire_bytes": self._dispatch_wire_bytes}
+
     def affinity_stats(self) -> dict:
         """Affinity + QoS aggregates for /status/kernels and the bench
         differential row."""
@@ -1352,6 +1389,7 @@ class KernelTelemetry:
             "hedging": self.hedge_stats(),
             "retries": self.retry_stats(),
             "affinity": self.affinity_stats(),
+            "dispatch": self.dispatch_stats(),
             "query_costs": self.query_cost_stats(),
             "selftrace": self.selftrace_stats(),
             "batching": self.batch_stats(),

@@ -343,6 +343,14 @@ class Profiler:
                     self._store = ArtifactStore(env)
             return self._store
 
+    def put_artifact(self, kind: str, data: bytes, suffix: str = ".bin") -> str:
+        """Publish bytes a caller assembled (a tree's merged device
+        trace) in the store the captures use -> artifact id."""
+        store = self._store_or_env()
+        if store is None:
+            raise ProfilerUnavailable("no profile artifact store configured")
+        return store.put(kind, data, suffix=suffix)
+
     def artifact_bytes(self, aid: str) -> bytes | None:
         store = self._store_or_env()
         return store.get(aid) if store is not None else None

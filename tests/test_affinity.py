@@ -276,6 +276,39 @@ def test_crashed_owner_jobs_complete_via_steal():
         fe.stop()
 
 
+def test_poll_waiting_for_a_lost_worker_leases_nothing():
+    """Regression (PR 26): a querier is killed while its long poll waits
+    in the frontend; the handler outlives the socket by up to wait_s. A
+    job that poll takes must go back on the queue at once, not sit in a
+    lease nobody will answer for lease_s (its hedge twin could meet the
+    querier's other waiting poll)."""
+    import threading
+
+    fe = _dispatcher(lease_s=30.0)
+    try:
+        _attach(fe, "w-live", "w-dead")
+        got = []
+        t = threading.Thread(target=lambda: got.append(
+            fe.poll_job(wait_s=3.0, worker_id="w-dead")))
+        t.start()
+        time.sleep(0.2)  # the poll is waiting on the empty queue
+        fe.worker_lost("w-dead")
+        job = _job()
+        fe.queue.enqueue(TENANT, job)
+        t.join(timeout=5)
+        assert not t.is_alive() and got == [None]
+        assert fe._leases == {} and fe._lease_workers == {}
+        wire = fe.poll_job(wait_s=1.0, worker_id="w-live")
+        assert wire is not None
+        fe.complete_job(wire["id"], ok=True, result={"traces": [], "metrics": {}})
+        assert job.done.is_set() and job.error is None
+        # the worker comes back under the same id: its new polls lease
+        fe.queue.enqueue(TENANT, _job())
+        assert fe.poll_job(wait_s=1.0, worker_id="w-dead") is not None
+    finally:
+        fe.stop()
+
+
 def test_sick_owner_does_not_monopolize_retries():
     """Regression: a fast-failing but ALIVE owner polls again first and
     would win its own job back inside the steal window on every retry,
