@@ -217,7 +217,7 @@ def _collect_seeded(blk, req, planned, seed, tm_row, counts_row, key_dev,
             return state.pop()
         return select_topk_device(tm_row, key_dev, counts_row, k)
 
-    return _collect_topk(blk, req, planned.needs_verify, selector, limit,
+    return _collect_topk(blk, req, planned, selector, limit,
                          materialize=False)
 
 

@@ -7,7 +7,9 @@ the page-dictionary pre-filter of parquetquery predicates.go:38-89) and
 emits a trace-level condition tree; the filter evaluates it over the
 block's columns; the top `limit` candidates BY TRACE START TIME are
 selected before any host materialization (ops/select.py), and only
-those are exactly re-verified (device encodings are conservative).
+those are settled exactly: by traceql.hosteval when a condition of the
+query is conservative on the device (_verify_candidates), and always by
+_candidates for the request's time window and duration bounds.
 
 Two execution engines share the plan + verify contract:
   - device (ops/filter + ops/stage): staged padded columns, jit kernel,
@@ -219,6 +221,10 @@ def response_from_dict(d: dict) -> SearchResponse:
 
 
 def _plan_for_block(blk: BackendBlock, req: SearchRequest, allow_struct: bool = True):
+    """allow_struct=False is the replan of a struct query for a slice of
+    the span axis (row-group shard, streamed chunk): the relation folds to
+    a trace-AND that hosteval settles, and the plan says so
+    (verify_reason) for the routing counter."""
     start_rel = None
     if req.start or req.end:
         base_ms = blk.meta.start_time_unix_nano // 1_000_000
@@ -230,11 +236,12 @@ def _plan_for_block(blk: BackendBlock, req: SearchRequest, allow_struct: bool = 
         )
     # struct nodes need the block to carry the parent-row column
     # (pre-upgrade blocks don't)
+    on_shard = not allow_struct
     allow_struct = allow_struct and blk.pack.has("span.parent_idx")
     from ..util.kerneltel import TEL
 
     with TEL.stage("plan:compile", block=blk.meta.block_id[:8]):  # parse + plan
-        return plan_search_request(
+        planned = plan_search_request(
             blk.dictionary,
             req.tags,
             query=req.query,
@@ -243,6 +250,9 @@ def _plan_for_block(blk: BackendBlock, req: SearchRequest, allow_struct: bool = 
             start_rel_ms=start_rel,
             allow_struct=allow_struct,
         )
+    if on_shard and planned.needs_verify:
+        planned.verify_reason = "struct_on_shard"
+    return planned
 
 
 # --------------------------------------------------- candidate selection
@@ -269,10 +279,33 @@ def _start_key_dev(blk: BackendBlock, nb: int):
     return key
 
 
-def _verify_candidates(blk: BackendBlock, req: SearchRequest, sids, needs_verify: bool):
-    """Exact host re-check of TraceQL candidates when the device filter
-    was conservative. Bounded: callers pass at most the escalation k."""
-    if not (needs_verify and req.query and len(sids)):
+def _route_verify(req: SearchRequest, planned) -> bool:
+    """Who settles one block's candidates: True when a condition OF THE
+    QUERY is conservative (PlannedQuery.needs_verify) and hosteval must
+    re-check them, False when the device proved every query condition
+    exactly or there is no query to evaluate. Recorded as the routing
+    decision ("verify", "hosteval" | "skip", reason), one per collected
+    block. The request's window and duration bounds are never a reason
+    to verify: _candidates owns their exact re-check."""
+    from ..util.kerneltel import TEL
+
+    if not req.query:
+        engine, reason = "skip", "no_query"
+    elif not planned.needs_verify:
+        engine, reason = "skip", "exact_plan"
+    else:
+        engine, reason = "hosteval", planned.verify_reason or "lossy_cond"
+    TEL.record_routing("verify", engine, reason)
+    return engine == "hosteval"
+
+
+def _verify_candidates(blk: BackendBlock, req: SearchRequest, sids, verify: bool):
+    """Exact host re-check (traceql.hosteval) of TraceQL candidates when
+    a condition of the query was conservative on the device (verify:
+    what _route_verify returned). Bounded: callers pass at most the
+    escalation k. The request's time window and duration bounds are NOT
+    settled here -- hosteval does not evaluate them; _candidates does."""
+    if not (verify and len(sids)):
         return sids
     import time as _time
 
@@ -302,12 +335,20 @@ def _verify_candidates(blk: BackendBlock, req: SearchRequest, sids, needs_verify
 def _candidates(
     blk: BackendBlock, req: SearchRequest, sids: list[int], counts: dict[int, int]
 ) -> list[tuple]:
-    """Exact host re-check of time/duration + LIGHTWEIGHT candidate
-    records (start_ns, trace_id hex, dur_ms, matched, blk, sid):
-    everything the global merge sorts/dedupes on, with the dictionary
-    lookups + SearchResult construction deferred to the winners
-    (_materialize). O(len(sids)) -- callers cap it at the escalation k,
-    never the full match count."""
+    """THE exact re-check of the request's time window and duration
+    bounds + LIGHTWEIGHT candidate records (start_ns, trace_id hex,
+    dur_ms, matched, blk, sid): everything the global merge sorts/dedupes
+    on, with the dictionary lookups + SearchResult construction deferred
+    to the winners (_materialize). O(len(sids)) -- callers cap it at the
+    escalation k, never the full match count.
+
+    Owner of the window: the device compares trace.start_ms against
+    bounds widened by 1 ms (plan_search_request) and neither the plan's
+    needs_verify nor hosteval covers that, so EVERY path that turns
+    selected sids into results must come through here (the collects
+    below do; tests/test_search_window.py drives each engine with a
+    window that cuts a block). A row dropped here leaves the collect
+    short of the limit, and the collect widens k."""
     ti = blk.search_index
     if not len(sids):
         return []
@@ -355,19 +396,22 @@ def _materialize(cand: tuple) -> SearchResult:
 
 
 
-def _collect_topk(blk: BackendBlock, req: SearchRequest, needs_verify: bool,
+def _collect_topk(blk: BackendBlock, req: SearchRequest, planned,
                   selector, limit: int, materialize: bool = True):
     """Escalating top-k collect: select k candidates (newest first),
-    verify exactly, and only widen k when verification rejected enough
-    to fall short of the limit. selector(k) -> (sids, counts, n_match).
-    materialize=False returns candidate records (_candidates) for a
-    caller doing its own global merge -- the fused engine materializes
-    only the cross-block winners."""
+    settle them exactly -- hosteval when the plan says a query condition
+    is conservative (_route_verify), then always _candidates for the
+    window and duration bounds -- and only widen k when either rejected
+    enough to fall short of the limit. selector(k) -> (sids, counts,
+    n_match). materialize=False returns candidate records (_candidates)
+    for a caller doing its own global merge -- the fused engine
+    materializes only the cross-block winners."""
     nt = blk.meta.total_traces
     if nt == 0:
         return []
     from ..util.kerneltel import TEL
 
+    verify = _route_verify(req, planned)
     with TEL.stage("topk:collect", block=blk.meta.block_id[:8], limit=limit):
         k = min(k_bucket(max(2 * limit, 32)), nt)
         out: list = []
@@ -378,7 +422,7 @@ def _collect_topk(blk: BackendBlock, req: SearchRequest, needs_verify: bool,
             seen.update(s for s, _ in fresh)
             if fresh:
                 ok = _verify_candidates(
-                    blk, req, np.asarray([s for s, _ in fresh], dtype=np.int64), needs_verify
+                    blk, req, np.asarray([s for s, _ in fresh], dtype=np.int64), verify
                 )
                 okset = {int(s) for s in ok}
                 out.extend(
@@ -628,7 +672,7 @@ def search_block(
         st.attrs.update(
             bucket=(int(info[1]) if info and info[0] == "filter" else n_rows),
             compile=use_device and TEL.totals()[0] > compiles0)
-    results = _collect_topk(blk, req, planned.needs_verify, selector, limit)
+    results = _collect_topk(blk, req, planned, selector, limit)
     results.sort(key=lambda r: -r.start_time_unix_nano)
     resp.traces = results[:limit]
     resp.inspected_spans = n_spans_seen
@@ -837,7 +881,7 @@ def search_blocks_fused(
         def selector(k):
             return select_topk_host(tm, key, counts, k)
 
-        return ("cand", _collect_topk(blk, req, p.needs_verify, selector, limit,
+        return ("cand", _collect_topk(blk, req, p, selector, limit,
                                       materialize=False), n_spans)
 
     # device staging IO + host scans overlap across one pool pass;
@@ -943,6 +987,7 @@ def _collect_topk_multi(blocks, plans, offsets, req: SearchRequest, selector,
         return []
     from ..util.kerneltel import TEL
 
+    verify = [_route_verify(req, p) for p in plans]
     with TEL.stage("topk:collect", blocks=len(blocks), limit=limit):
         k = min(k_bucket(max(2 * limit, 32)), total)
         out: list = []
@@ -960,9 +1005,9 @@ def _collect_topk_multi(blocks, plans, offsets, req: SearchRequest, selector,
                 bi = int(np.searchsorted(offsets, g, side="right")) - 1
                 per_block.setdefault(bi, []).append((g - int(offsets[bi]), int(c)))
             for bi, pairs in per_block.items():
-                blk, p = blocks[bi], plans[bi]
+                blk = blocks[bi]
                 sids = np.asarray([s for s, _ in pairs], dtype=np.int64)
-                ok = _verify_candidates(blk, req, sids, p.needs_verify)
+                ok = _verify_candidates(blk, req, sids, verify[bi])
                 okset = {int(s) for s in ok}
                 out.extend(
                     _candidates(blk, req, [s for s, c in pairs if s in okset], dict(pairs))
@@ -1227,7 +1272,7 @@ def _search_group_device(items, tree, conds, req: SearchRequest, mesh, resp: Sea
         def selector(k, mask=mask, cnt=cnt, key=key):
             return select_topk_host(mask, key, cnt, k)
 
-        results.extend(_collect_topk(blk, req, p.needs_verify, selector, limit))
+        results.extend(_collect_topk(blk, req, p, selector, limit))
         resp.inspected_spans += int(n_spans[bi])
         resp.inspected_bytes += blk.pack.bytes_read - io0[bi]
     return results

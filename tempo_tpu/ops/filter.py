@@ -54,7 +54,9 @@ OPS = (
 
 @dataclass(frozen=True)
 class Cond:
-    """One predicate. Hashable => part of the jit key."""
+    """One predicate. Hashable => part of the jit key. needs_verify:
+    this condition of the query may over-match on the device and hosteval
+    must re-check (defined once, on traceql.plan.PlannedQuery)."""
 
     target: str
     col: str  # device column ('span.dur_us', 'res.service_id', ...) or

@@ -27,7 +27,7 @@ longer change under an unchanged blocklist generation. A later request
 merges: a 1h range refreshed every 10s re-executes seconds of data,
 not the hour. Extension stays in the under-limit regime (a truncated
 result set is not a complete prefix); the search time filter is
-trace-start within [start, end] (db/search._verify_candidates), so
+trace-start within [start, end] (settled exactly by db/search._candidates), so
 splitting at `cut` partitions exactly.
 
 Kill switch: TEMPO_RESULT_CACHE=0 makes the frontend skip construction

@@ -168,6 +168,34 @@ def _str_col_cond(p: Plan, d: Dictionary, target: str, col: str, op: str, value)
     raise ParseError(f"unsupported string op {op}")
 
 
+_I32_LO, _I32_HI = -(2**31) + 1, 2**31 - 1  # what the int column clamps to
+
+
+def _int_column_compare(mop: str, value) -> tuple[str, int, bool]:
+    """(op, operand, lossy) for `int column <mop> value`, never
+    under-matching: the device filter may over-match (lossy => the cond
+    is needs_verify and hosteval settles it) but must not lose a row.
+    Exact for an int operand inside the clamp. At or past the clamp, rows
+    clamp to the same code as the operand, so a strict compare widens
+    (gt -> ge, lt -> le, ne -> ne_clamped). A float operand is compared
+    through the integers next to it: x < 7.5 <=> x <= 7, x > 7.5 <=>
+    x >= 8, x != 7.5 holds for every int."""
+    fv = float(value)
+    whole = fv == int(fv)
+    lossy = not whole or not isinstance(value, int) or not (_I32_LO < fv < _I32_HI)
+    if not lossy:
+        return mop, int(value), False
+    lo = int(np.clip(np.floor(fv), _I32_LO, _I32_HI))
+    hi = int(np.clip(np.ceil(fv), _I32_LO, _I32_HI))
+    if mop in ("lt", "le"):
+        return "le", lo, True
+    if mop in ("gt", "ge"):
+        return "ge", hi, True
+    if mop == "ne":
+        return ("ne_clamped", lo, True) if whole else ("ge", _I32_LO, True)
+    return mop, lo, True  # eq: over-matches for a fraction, hosteval drops it
+
+
 def _attr_cond(p: Plan, d: Dictionary, table_target: str, key: str, op: str, lit: Static) -> tuple:
     """Generic attr-table condition (sattr or rattr)."""
     kcode = d.lookup(key)
@@ -198,35 +226,17 @@ def _attr_cond(p: Plan, d: Dictionary, table_target: str, key: str, op: str, lit
             raise ParseError("booleans support = and != only")
         mapped = "eq" if op == "=" else "ne"
         return p.cond(Cond(target=table_target, col="bool", op=mapped), key=kcode, v0=1 if lit.value else 0)
-    if lit.kind in ("int", "duration"):
-        v = int(lit.value)
-        clamped = not (-(2**31) < v < 2**31)
+    if lit.kind in ("int", "duration", "float"):
         mop = _OP_MAP[op] if op != "!=" else "ne"
+        iop, iv, lossy = _int_column_compare(mop, lit.value)
         int_c = p.cond(
-            Cond(target=table_target, col="int", op=mop, needs_verify=clamped),
-            key=kcode,
-            v0=int(np.clip(v, -(2**31) + 1, 2**31 - 1)),
-        )
+            Cond(target=table_target, col="int", op=iop, needs_verify=lossy),
+            key=kcode, v0=iv)
         # numbers also match float-typed attrs (TraceQL numeric compare)
         flt_c = p.cond(
             Cond(target=table_target, col="float", op=mop, is_float=True, needs_verify=True),
-            key=kcode,
-            f0=float(v),
-        )
+            key=kcode, f0=float(lit.value))
         return _fold("or", [int_c, flt_c])
-    if lit.kind == "float":
-        mop = _OP_MAP[op] if op != "!=" else "ne"
-        flt_c = p.cond(
-            Cond(target=table_target, col="float", op=mop, is_float=True, needs_verify=True),
-            key=kcode,
-            f0=float(lit.value),
-        )
-        int_c = p.cond(
-            Cond(target=table_target, col="int", op=mop, needs_verify=True),
-            key=kcode,
-            v0=int(np.clip(lit.value, -(2**31) + 1, 2**31 - 1)),
-        )
-        return _fold("or", [flt_c, int_c])
     raise ParseError(f"unsupported literal kind {lit.kind}")
 
 
@@ -432,12 +442,30 @@ def _plan_expr(p: Plan, d: Dictionary, expr) -> tuple:
 
 @dataclass
 class PlannedQuery:
+    """One block's device plan.
+
+    needs_verify -- here and on ops.filter.Cond -- means: a condition OF
+    THE QUERY (float attribute, clamped int or duration, a `~` sibling
+    tree, a construct with no device form, a struct relation planned
+    without its struct node) may over-match on the device, and
+    traceql.hosteval must re-check every candidate on its materialized
+    trace (db/search._verify_candidates). It does NOT cover the request's
+    own bounds: the start/end window (trace.start_ms, widened by 1 ms)
+    and min/max duration are conservative on the device too, but
+    hosteval never looks at them -- db/search._candidates settles both
+    exactly on trace.start_ns / trace.end_ns for every exit path, tag
+    searches included, so they never raise this flag."""
+
     tree: tuple | None  # trace-level tree (see ops.filter); None => match-all
     conds: tuple
     rows: list
     tables: dict[int, np.ndarray]
     prune: bool = False  # statically false for this block
     needs_verify: bool = False
+    # why, for the ("verify", "hosteval", reason) routing decision; ""
+    # reads as "lossy_cond". db/search._plan_for_block writes
+    # "struct_on_shard" on the replan of a struct query for a shard
+    verify_reason: str = ""
     # extra engine columns the TREE (not the conds) requires -- e.g.
     # span.parent_idx for compiled ('struct', ...) nodes
     extra_cols: tuple = ()
@@ -644,9 +672,14 @@ def plan_search_request(
             p, "trace", "trace.dur_us", "trace.dur_lo", "<=",
             max_duration_ms * 1_000_000))
     if start_rel_ms is not None:
+        # conservative (the staged column is block-relative milliseconds,
+        # the caller widened the bounds by 1 ms) but NOT needs_verify:
+        # hosteval does not evaluate the window; db/search._candidates
+        # re-checks it exactly on trace.start_ns, and the escalating
+        # collect widens k when that drops a row the +-1 ms let through
         lo, hi = start_rel_ms
         children.append(
-            p.cond(Cond(target="trace", col="trace.start_ms", op="range", needs_verify=True), v0=lo, v1=hi)
+            p.cond(Cond(target="trace", col="trace.start_ms", op="range"), v0=lo, v1=hi)
         )
     planned = _finish(p, children)
     if force_verify and not planned.prune:

@@ -105,6 +105,20 @@ def make_traces(
     return out
 
 
+def restart_trace(trace: Trace, start_ns: int) -> Trace:
+    """Shift every span (and event) of `trace` in place so that its
+    earliest span starts at exactly `start_ns`: make_trace draws every
+    start inside one second, and a time-window test needs traces at
+    chosen nanoseconds around the window's edges."""
+    shift = start_ns - trace.time_range_nanos()[0]
+    for _, _, sp in trace.all_spans():
+        sp.start_unix_nano += shift
+        sp.end_unix_nano += shift
+        for ev in sp.events:
+            ev.time_unix_nano += shift
+    return trace
+
+
 # ------------------------------------------------------------ synth block
 SYNTH_BASE_TIME_NS = 1_700_000_000_000_000_000
 

@@ -74,11 +74,18 @@ def test_mesh_batched_equals_sequential_randomized():
         picks = [str(rng.choice(_QUERIES)) for _ in range(12)]
         reqs = [SearchRequest(query=q, limit=200) for q in picks]
         expected = [_dicts(search_block(blk, r)) for r in reqs]
-        with ThreadPoolExecutor(len(reqs)) as ex:
-            futs = [ex.submit(db.search_blocks, TENANT, [m], r) for r in reqs]
-            got = [_dicts(f.result()) for f in futs]
-        for q, e, g in zip(picks, expected, got):
-            assert e == g, f"mesh-batched != sequential for {q!r} (seed {seed})"
+        # a leader holds its window open only when another submitter is
+        # already inside the executor: on a loaded box (other xdist
+        # workers) every thread of a burst can arrive alone, so a burst
+        # that formed no group is sent again, a bounded number of times
+        for _ in range(5):
+            with ThreadPoolExecutor(len(reqs)) as ex:
+                futs = [ex.submit(db.search_blocks, TENANT, [m], r) for r in reqs]
+                got = [_dicts(f.result()) for f in futs]
+            for q, e, g in zip(picks, expected, got):
+                assert e == g, f"mesh-batched != sequential for {q!r} (seed {seed})"
+            if TEL.mesh_batch_stats()["launches"] > mesh0:
+                break
         db.close()
     assert TEL.mesh_batch_stats()["launches"] > mesh0, \
         "no window ever took the mesh-batched route"

@@ -629,3 +629,43 @@ def test_structural_orphan_siblings(tmp_path):
     for mode in ("host", "device"):
         got = search_block(blk, SearchRequest(query=q, limit=10), mode=mode)
         assert len(got.traces) == 1, mode
+
+
+@pytest.mark.parametrize("query", [
+    "{ span.bytes.sent > 4000000000 }",   # operand past the int32 clamp
+    "{ span.bytes.sent < 4000000000 }",
+    "{ span.bytes.sent != 5000000000 }",
+    "{ span.bytes.sent = 5000000000 }",
+    "{ span.bytes.sent != 2147483647 }",  # operand AT the clamp
+    "{ span.bytes.sent < -3000000000 }",
+    "{ span.bytes.sent < 7.5 }",          # float operand, int attribute
+    "{ span.bytes.sent > 6.5 }",
+    "{ span.bytes.sent != 7.5 }",
+    "{ span.bytes.sent = 7.5 }",
+    "{ span.bytes.sent >= 7.0 }",
+    "{ span.bytes.sent > 5 }",            # the exact case stays exact
+])
+def test_int_attribute_compare_never_under_matches(tmp_path, query):
+    """The int column clamps to int32 and holds whole numbers: an operand
+    at or past the clamp, or with a fraction, is compared conservatively
+    on the device (never losing a row) and settled by hosteval. Both
+    engines return the wire oracle's set."""
+    from tempo_tpu.backend.mem import MemBackend
+    from tempo_tpu.db import TempoDB, TempoDBConfig
+    from tempo_tpu.db.search import SearchRequest, _plan_for_block, search_block
+    from tempo_tpu.traceql.hosteval import trace_matches
+    from tempo_tpu.traceql.parser import parse
+    from tempo_tpu.util.testdata import make_traces
+
+    traces = make_traces(30, seed=3, n_spans=4)
+    for i, (_, t) in enumerate(traces):
+        next(t.all_spans())[2].attrs["bytes.sent"] = (5_000_000_000, 3_000_000_000, 7)[i % 3]
+    db = TempoDB(TempoDBConfig(wal_path=str(tmp_path / "w")), backend=MemBackend())
+    blk = db.open_block(db.write_block(TENANT, traces))
+    want = {tid.hex() for tid, t in traces if trace_matches(parse(query), t)}
+    int_cond = _plan_for_block(blk, SearchRequest(query=query)).conds[0]
+    assert int_cond.col == "int" and int_cond.needs_verify == (query != "{ span.bytes.sent > 5 }")
+    for mode in ("host", "device"):
+        got = {t.trace_id for t in
+               search_block(blk, SearchRequest(query=query, limit=1000), mode=mode).traces}
+        assert got == want, (query, mode, len(got), len(want))

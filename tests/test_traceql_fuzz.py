@@ -127,6 +127,49 @@ def test_fuzz_host_device_oracle_agree(tmp_path, seed, n_cases):
     assert checked == n_cases
 
 
+@pytest.mark.parametrize("seed,n_cases", _depths([606, 707]))
+def test_fuzz_windowed_host_device_oracle_agree(tmp_path, seed, n_cases):
+    """The same three-way agreement under a start/end window that cuts the
+    block: traces spread over six seconds, many within a millisecond of
+    the window's edges (the device compares milliseconds widened by one;
+    db/search._candidates settles the nanoseconds). Plans whose query
+    conditions are exact no longer host-verify under a window, plans with
+    a lossy condition still do, and both must return the oracle's set."""
+    from tempo_tpu.db.search import _plan_for_block
+    from tempo_tpu.util.testdata import restart_trace
+
+    ns, base_s = 10**9, 1_700_000_000
+    start, end = base_s + 2, base_s + 4
+    rng = random.Random(seed)
+    traces = []
+    for tid, t in make_traces(50, seed=seed, n_spans=8):
+        edge = rng.choice([start, end]) * ns
+        at = rng.choice([
+            base_s * ns + rng.randrange(6 * ns),
+            edge + rng.choice([-1_000_000, -999_999, -1, 0, 1, 999_999, 1_000_001]),
+        ])
+        traces.append((tid, restart_trace(t, at)))
+    db = TempoDB(TempoDBConfig(wal_path=str(tmp_path / "w")), backend=MemBackend())
+    db.write_block(TENANT, traces)
+    blk = db.open_block(db.blocklist.metas(TENANT)[0])
+    inside = {tid.hex() for tid, t in traces
+              if start * ns <= t.time_range_nanos()[0] <= end * ns}
+    assert 5 < len(inside) < 45
+
+    verified = 0
+    for _ in range(n_cases):
+        q = _query(rng)
+        ast = parse(q)
+        want = {tid.hex() for tid, t in traces if trace_matches(ast, t)} & inside
+        req = SearchRequest(query=q, limit=1000, start=start, end=end)
+        p = _plan_for_block(blk, req)
+        verified += bool(not p.prune and p.needs_verify)
+        for mode in ("host", "device"):
+            got = {t.trace_id for t in search_block(blk, req, mode=mode).traces}
+            assert got == want, (q, mode, sorted(got ^ want)[:4])
+    assert 0 < verified < n_cases, f"{verified} of {n_cases} plans verify"
+
+
 @pytest.mark.parametrize("seed,n_cases", _depths([404, 505]))
 def test_fuzz_mesh_path_agrees(tmp_path, seed, n_cases):
     """Fourth leg: the stacked MESH program (blocks over dp, span AND
