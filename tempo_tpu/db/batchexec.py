@@ -40,6 +40,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..util.profiler import timed_lock
+from . import route
 
 DEFAULT_WINDOW_MS = 3.0
 DEFAULT_MAX_BATCH = 16
@@ -200,28 +201,8 @@ class _SearchItem:
     limit: int
 
 
-def _collect_seeded(blk, req, planned, seed, tm_row, counts_row, key_dev,
-                    limit: int):
-    """db/search._collect_topk with the FIRST selection pre-computed by
-    the fused batched top-k (the seed was sliced to exactly the k the
-    collect loop asks for first); escalation (verification rejected
-    enough candidates) falls back to per-query device selects on this
-    query's mask row. Returns candidate records (materialize=False)."""
-    from ..ops.select import select_topk_device
-    from .search import _collect_topk
-
-    state = [seed]
-
-    def selector(k):
-        if state:
-            return state.pop()
-        return select_topk_device(tm_row, key_dev, counts_row, k)
-
-    return _collect_topk(blk, req, planned, selector, limit,
-                         materialize=False)
-
-
-def _sequential_search(it: _SearchItem):
+def _seq_or_exc(it: _SearchItem):
+    """One item on the single-job path, under the plan the probe made."""
     from dataclasses import replace
 
     from .search import search_block
@@ -229,7 +210,11 @@ def _sequential_search(it: _SearchItem):
     # honor the route's default limit (search_blocks passes the config
     # default; search_block alone would fall back to the module default)
     req = it.req if it.req.limit else replace(it.req, limit=it.limit)
-    return search_block(it.blk, req, groups_range=it.groups_range)
+    try:
+        return search_block(it.blk, req, groups_range=it.groups_range,
+                            planned=it.planned)
+    except Exception as e:
+        return e
 
 
 def _run_search_group(key, items: list, mesh_fn=None) -> list:
@@ -266,13 +251,6 @@ def _log_fused_error(kind: str, e: Exception) -> None:
         traceback="".join(traceback.format_exception(e)))
 
 
-def _seq_or_exc(it: _SearchItem):
-    try:
-        return _sequential_search(it)
-    except Exception as e:
-        return e
-
-
 def _mesh_batch_enabled() -> bool:
     """TEMPO_MESH_BATCH=0 pins window leaders to the single-chip fused
     launch even on a multi-device mesh (the legacy-path escape hatch the
@@ -292,7 +270,7 @@ def _run_search_group_fused(items: list, mesh_fn=None) -> list:
     from ..ops.select import k_bucket
     from ..ops.stage import stage_block
     from ..util.kerneltel import TEL
-    from .search import SearchResponse, _materialize
+    from .search import SearchResponse, collect_seeded
 
     blk = items[0].blk
     shape = items[0].lowered.shape
@@ -348,9 +326,8 @@ def _run_search_group_fused(items: list, mesh_fn=None) -> list:
             kq = ks[qi]
             seed = (sids_k[:kq][valid_k[:kq]], cnts_k[:kq][valid_k[:kq]],
                     n_match)
-            out = _collect_seeded(blk, it.req, it.planned, seed,
-                                  tm[qi], counts[qi], key_dev, it.limit)
-            results = [_materialize(c) for c in out]
+            results = collect_seeded(blk, it.req, it.planned, seed,
+                                     tm[qi], counts[qi], key_dev, it.limit)
             results.sort(key=lambda r: -r.start_time_unix_nano)
             resp = SearchResponse()
             resp.traces = results[:it.limit]
@@ -368,33 +345,6 @@ def _run_search_group_fused(items: list, mesh_fn=None) -> list:
             break
     TEL.record_demux("search", len(items))
     return responses
-
-
-def batched_search_block(batcher: BatchExecutor, blk, req,
-                         groups_range=None, promote_touches: int = 2,
-                         default_limit: int | None = None):
-    """Route one block search through the batching executor when
-    eligible; None means "take today's path unchanged":
-
-      * the plan must lower to a predicate program (ops/multiquery);
-      * the block must be warm -- staged columns resident, or touched
-        promote_touches times (search_blocks_fused's promotion rule), or
-        device-pinned for row-group shard jobs (search_block's rule);
-      * tres-eligible plans keep the cheaper host membership scan, and
-        stream-sized scans keep the chunked path.
-
-    The sequential engine's per-query host_scan_cheaper estimate is
-    deliberately NOT mirrored: it weighs one host scan against one
-    device round trip, but under the batcher the round trip amortizes
-    over the window (RTT/occupancy), which is the point of the
-    subsystem -- a lone query on a warm block pays at most one RTT over
-    the host estimate, bounded by the admission window policy."""
-    probe = _probe_search_entry(batcher, blk, req, groups_range,
-                                promote_touches, default_limit)
-    if probe is None or not isinstance(probe, tuple):
-        return probe  # ineligible (None) or a static empty response
-    key, item = probe
-    return batcher.submit(key, item)
 
 
 # --------------------------------------------------------------- find path
@@ -525,98 +475,47 @@ class QueryBatchers:
 
 
 def batched_search_block_many(batcher: BatchExecutor, entries: list,
-                              promote_touches: int = 2,
-                              default_limit: int | None = None) -> list:
+                              default_limit: int | None = None,
+                              refused=None) -> list:
     """Many (blk, req, groups_range) searches from ONE caller thread,
-    grouped by coalesce key and submitted together so a single worker
-    draining a burst still forms full batches (the frontend's
-    batch-aware dequeue lands here). Returns per-entry SearchResponse,
-    None where the entry was ineligible (caller falls back), or the
-    entry's own Exception (caller routes it through its per-job error
-    path)."""
+    each planned here, once. Those db/route finds batchable group by
+    coalesce key and submit together, so a single worker draining a
+    burst still forms full batches (the frontend's batch-aware dequeue
+    lands here). Returns per entry a SearchResponse, the entry's own
+    Exception (caller routes it through its per-job error path), or for
+    an entry the window refuses what refused(blk, req, groups_range,
+    planned) returns: the caller's single-job path under the plan made
+    here (None without one). default_limit: for limit-less requests
+    (search_default_limit parity on the search_blocks route)."""
+    from .search import DEFAULT_LIMIT, SearchResponse, plan_job
+
     out: list = [None] * len(entries)
-    # batched_search_block with a one-item window would lose the mates;
-    # instead lower each entry, bucket by key, and submit_many per key
     staged: dict = {}
+    turned_away: list = []
     for i, (blk, req, groups_range) in enumerate(entries):
-        probe = _probe_search_entry(batcher, blk, req, groups_range,
-                                    promote_touches, default_limit)
-        if probe is None:
+        planned = plan_job(blk, req, groups_range)
+        if planned is None:  # out of the window, or pruned
+            out[i] = SearchResponse()
             continue
-        if isinstance(probe, tuple):
-            key, item = probe
-            staged.setdefault(key, []).append((i, item))
-        else:  # an immediate empty response (prune / out of range)
-            out[i] = probe
+        lowered = route.route_batch(blk, planned, groups_range).lowered
+        if lowered is None:
+            turned_away.append((i, planned))
+            continue
+        needed = route.stage_columns(planned)
+        item = _SearchItem(
+            blk=blk, req=req, planned=planned, lowered=lowered, needed=needed,
+            groups_range=list(groups_range) if groups_range is not None else None,
+            limit=req.limit or default_limit or DEFAULT_LIMIT,
+        )
+        key = ("search", blk.meta.tenant_id, blk.meta.block_id,
+               tuple(groups_range) if groups_range is not None else None,
+               tuple(needed + ["trace.start_ms"]), lowered.shape)
+        staged.setdefault(key, []).append((i, item))
     for key, pairs in staged.items():
         results = batcher.submit_many(key, [it for _, it in pairs])
         for (i, _), r in zip(pairs, results):
             out[i] = r
+    if refused is not None:
+        for i, planned in turned_away:
+            out[i] = refused(*entries[i], planned)
     return out
-
-
-def _probe_search_entry(batcher, blk, req, groups_range, promote_touches,
-                        default_limit: int | None = None):
-    """Eligibility probe shared with batched_search_block: returns
-    (key, item) when batchable, a SearchResponse for static empties,
-    or None to fall back. default_limit overrides db/search's module
-    default for limit-less requests (TempoDBConfig.search_default_limit
-    parity on the search_blocks route)."""
-    from ..ops.filter import required_columns
-    from ..ops.multiquery import lower_plan
-    from ..util.kerneltel import TEL
-    from .search import (
-        _STREAM_MIN_STAGE_BYTES,
-        DEFAULT_LIMIT,
-        SearchResponse,
-        _plan_for_block,
-        _tres_eligible,
-    )
-
-    if batcher is None or not batcher.enabled:
-        return None
-    if not blk.meta.overlaps_time(req.start, req.end):
-        return SearchResponse()
-    planned = _plan_for_block(blk, req)
-    if not planned.prune and groups_range is not None and planned.has_struct:
-        planned = _plan_for_block(blk, req, allow_struct=False)
-    if planned.prune:
-        return SearchResponse()
-    lowered = lower_plan(planned)
-    if lowered is None:
-        TEL.record_routing("search_batch", "fallback", "ineligible_plan")
-        return None
-    if _tres_eligible(blk, planned):
-        TEL.record_routing("search_batch", "fallback", "tres_host")
-        return None
-    needed = required_columns(planned.conds) + list(planned.extra_cols)
-    from ..block import schema as S
-
-    span_ax = blk.pack.axes.get(S.AX_SPAN)
-    n_rows = span_ax.n_rows if span_ax else 0
-    n_span_cols = max(1, sum(
-        1 for n in needed if n.startswith(("span.", "sattr."))))
-    if n_rows * 4 * n_span_cols > _STREAM_MIN_STAGE_BYTES:
-        TEL.record_routing("search_batch", "fallback", "stream_scan")
-        return None
-    from ..ops.stage import is_staged
-
-    stage_cols = needed + ["trace.start_ms"]
-    staged_hit = is_staged(blk, stage_cols, groups_range)
-    touches = getattr(blk, "search_touches", 0)
-    hot = (staged_hit
-           or (groups_range is not None and getattr(blk, "device_pinned", False))
-           or touches + 1 >= promote_touches)
-    if not hot:
-        TEL.record_routing("search_batch", "fallback", "cold_block")
-        return None
-    blk.search_touches = touches + 1
-    item = _SearchItem(
-        blk=blk, req=req, planned=planned, lowered=lowered, needed=needed,
-        groups_range=list(groups_range) if groups_range is not None else None,
-        limit=req.limit or default_limit or DEFAULT_LIMIT,
-    )
-    key = ("search", blk.meta.tenant_id, blk.meta.block_id,
-           tuple(groups_range) if groups_range is not None else None,
-           tuple(stage_cols), lowered.shape)
-    return key, item

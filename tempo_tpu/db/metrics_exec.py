@@ -47,6 +47,7 @@ from ..traceql.ast import (
     Scope,
 )
 from ..traceql.plan import plan_metrics_filter
+from . import route
 
 # one source of truth for enum label names: the exact evaluator's maps
 # (themselves the inverse of ast.STATUS_NAMES/KIND_NAMES) -- a drifted
@@ -582,21 +583,13 @@ def _metrics_block(blk, q, req, resp, mode, planned, b_off, nb, t0_rel,
     has_val = q.agg.field is not None
     if groups is not None and has_val:
         vals = _value_column(blk, q.agg.field)
-    if mode == "exact":
-        exact, exact_reason = True, "forced"
-    elif planned.needs_verify:
-        exact, exact_reason = True, "lossy_plan"
-    elif groups is None:
-        exact, exact_reason = True, "unplannable_by"
-    elif has_val and vals is None:
-        exact, exact_reason = True, "unplannable_value"
-    else:
-        exact, exact_reason = False, ""
-    if exact:
-        TEL.record_routing("metrics", "exact", exact_reason)
+    exact = route.route_metrics_exact(
+        mode, planned, by_ok=groups is not None,
+        value_ok=not has_val or vals is not None)
+    if exact is not None:
         _metrics_block_exact(blk, q, req, resp, planned, b_off, nb)
         resp.inspected_bytes += blk.pack.bytes_read - io0
-        span_attrs.update(engine="exact", reason=exact_reason, compile=False)
+        span_attrs.update(engine="exact", reason=exact.reason, compile=False)
         return
     gid, labels = groups
     if not labels:
@@ -609,22 +602,13 @@ def _metrics_block(blk, q, req, resp, mode, planned, b_off, nb, t0_rel,
     # level metrics kernels never touch it -- don't read or stage it
     needed = [n for n in required_columns(planned.conds)
               if n != "trace.span_off"] + ["span.start_ms"]
-    # the device kernel buckets in int32 (block-relative ms): a step or
-    # origin past int32 ms (~24.8 days) runs on the int64 host engine
-    # instead -- identical results, no overflow
+    # the device kernel buckets in int32 (block-relative ms)
     i32_ok = req.step_ms < 2**31 and -(2**31) < t0_rel < 2**31
-    from ..ops.stage import has_staged, stage_block
-
-    use_device = i32_ok and (mode == "device" or (
-        mode == "auto"
-        and (getattr(blk, "device_pinned", False) or has_staged(blk))
-    ))
     n_spans = blk.pack.axes["span"].n_rows if "span" in blk.pack.axes else 0
-    if use_device:
+    if route.route_metrics(blk, mode, i32_ok).engine == "device":
+        from ..ops.stage import stage_block
         from ..ops.timeseries import eval_timeseries_device
 
-        TEL.record_routing("metrics", "device",
-                           "forced" if mode == "device" else "hot_block")
         staged = stage_block(blk, needed)
         outs = eval_timeseries_device(
             query, staged, operands, gid, val, pres,
@@ -636,10 +620,6 @@ def _metrics_block(blk, q, req, resp, mode, planned, b_off, nb, t0_rel,
     else:
         from ..ops.timeseries import eval_timeseries_host
 
-        TEL.record_routing(
-            "metrics", "host",
-            "forced" if mode == "host"
-            else ("cold_block" if i32_ok else "i32_range"))
         col_names = [n for n in needed
                      if not n.startswith("span@") and blk.pack.has(n)]
         if not all(blk.pack.has_cached_array(n) for n in col_names):

@@ -31,16 +31,7 @@ _N_CPU = os.cpu_count() or 2
 
 from ..block import schema as S
 from ..block.reader import BackendBlock
-from ..ops.filter import (
-    Operands,
-    T_RATTR,
-    T_RES,
-    T_SPAN,
-    T_TRACE,
-    _ATTR_VALUE_COL,
-    eval_block,
-    required_columns,
-)
+from ..ops.filter import Operands, eval_block, required_columns
 from ..ops.hostfilter import eval_block_host
 from ..ops.select import (
     k_bucket,
@@ -49,16 +40,12 @@ from ..ops.select import (
     select_topk_host,
     select_topk_host_multi,
 )
-from ..ops.stage import has_staged, is_staged, stage_block
+from ..ops.stage import stage_block
 from ..traceql.plan import plan_search_request
 from ..util.distinct import DistinctStringCollector
+from . import route
 
 DEFAULT_LIMIT = 20
-# stream row-group chunks only when the staged columns would exceed this
-# (bounds device memory); below it a single staged eval wins -- one kernel
-# dispatch + one result transfer instead of one per chunk, which matters
-# when host<->device latency is high
-_STREAM_MIN_STAGE_BYTES = 512 << 20
 
 _INTRINSIC_NAME = "name"
 _WELL_KNOWN_RES = {
@@ -72,50 +59,6 @@ _WELL_KNOWN_RES = {
 # column IO for the host evaluation path (reads overlap across columns;
 # shared across queries -- each read is one ranged GET + zstd decode)
 _host_io_pool = ThreadPoolExecutor(max_workers=8, thread_name_prefix="search-io")
-
-# ---------------------------------------------------------------- engine cost
-# The device engine costs ~one link round trip per query (fused select's
-# single fetch) regardless of block count; the host engine costs
-# bytes/rate with ZERO round trips (cost model shared with the
-# generator's reduce: util/linkcost.py). A host-rate EMA updated by
-# every cold host-engine block scan completes the estimate.
-from ..util.linkcost import link_rtt_ms as _link_rtt_ms
-
-_HOST_RATE_BPS: float = 1.5e9  # EMA, seeded at DDR-ish single-core scan rate
-_HOST_RATE_SEEDED = False  # ledger seed applied (once per process)
-
-
-def _note_host_rate(n_bytes: int, seconds: float) -> None:
-    global _HOST_RATE_BPS
-    if seconds > 1e-5 and n_bytes > (1 << 20):
-        # lossy EMA on the hot host-scan path: racing writers converge
-        # on the same steady state and a lock would serialize every scan
-        # tempo: ignore[global-mutation-unlocked] intentional lock-free EMA
-        _HOST_RATE_BPS = 0.7 * _HOST_RATE_BPS + 0.3 * (n_bytes / seconds)
-
-
-def seed_host_rate_from_ledger() -> None:
-    """Seed the cold-scan host-rate EMA from the CostLedger's measured
-    block_scan entry (tempo-tpu-cli calibrate) instead of the DDR-ish
-    constant -- the first routing decisions of a fresh process then
-    start from THIS box's measured scan rate. Later scans keep updating
-    the EMA as before; called once by TempoDB init (idempotent)."""
-    global _HOST_RATE_BPS, _HOST_RATE_SEEDED
-    if _HOST_RATE_SEEDED:
-        return
-    # racing initializers write the same ledger value
-    # tempo: ignore[global-mutation-unlocked] once-at-init seed
-    _HOST_RATE_SEEDED = True
-    try:
-        from ..util.costledger import KEY_BLOCK_SCAN, ledger
-
-        entry = ledger().get(KEY_BLOCK_SCAN)
-        rate = float(entry.get("host_rate_bps", 0.0)) if entry else 0.0
-        if rate > 0:
-            # tempo: ignore[global-mutation-unlocked] same seed-once write
-            _HOST_RATE_BPS = rate
-    except Exception:
-        pass  # routing falls back to the constant seed
 
 
 @dataclass
@@ -253,6 +196,32 @@ def _plan_for_block(blk: BackendBlock, req: SearchRequest, allow_struct: bool = 
     if on_shard and planned.needs_verify:
         planned.verify_reason = "struct_on_shard"
     return planned
+
+
+def plan_job(blk: BackendBlock, req: SearchRequest, groups_range=None):
+    """The plan one block job runs under, made once and handed on; None
+    when the answer is empty without a scan (out of the request's
+    window, or pruned by the dictionary)."""
+    if not blk.meta.overlaps_time(req.start, req.end):
+        return None
+    planned = _plan_for_block(blk, req)
+    if not planned.prune and groups_range is not None and planned.has_struct:
+        # struct nodes resolve parent links by GLOBAL row index; a
+        # row-group slice would sever links across group boundaries, so
+        # shards take the conservative plan (trace-AND + host verify),
+        # whose fold may prove "no match"
+        planned = _plan_for_block(blk, req, allow_struct=False)
+    return None if planned.prune else planned
+
+
+def _live_plans(blocks: list[BackendBlock], req: SearchRequest, pool=None):
+    """(block, plan) of the blocks a multi-block engine has to scan: in
+    the request's window, not pruned by their dictionary. Planning pulls
+    each block's dictionary + footer: the pool overlaps the IO."""
+    in_range = [b for b in blocks if b.meta.overlaps_time(req.start, req.end)]
+    plans = (pool.map(lambda b: _plan_for_block(b, req), in_range)
+             if pool is not None else (_plan_for_block(b, req) for b in in_range))
+    return [(blk, p) for blk, p in zip(in_range, plans) if not p.prune]
 
 
 # --------------------------------------------------- candidate selection
@@ -433,40 +402,23 @@ def _collect_topk(blk: BackendBlock, req: SearchRequest, planned,
             k = min(k_bucket(k * 4), nt)
 
 
+def collect_seeded(blk: BackendBlock, req: SearchRequest, planned, seed,
+                   tm_row, counts_row, key_dev, limit: int):
+    """_collect_topk with the FIRST selection pre-computed by the batch
+    window's fused top-k (db/batchexec: the seed was sliced to exactly
+    the k the collect loop asks for first); escalation falls back to
+    per-query device selects on this query's mask row."""
+    state = [seed]
+
+    def selector(k):
+        if state:
+            return state.pop()
+        return select_topk_device(tm_row, key_dev, counts_row, k)
+
+    return _collect_topk(blk, req, planned, selector, limit)
+
+
 # ---------------------------------------------------- per-block search
-
-
-def _tres_eligible(blk: BackendBlock, p) -> bool:
-    """Res/trace-only condition trees can evaluate over the tres
-    membership axis (one row per (trace, resource) pair, builder.py
-    build_tres) instead of the span axis: identical trace mask and
-    matched-span counts from a ~10x smaller decode."""
-    return (blk.pack.has("tres.res") and bool(p.conds)
-            and not getattr(p, "has_struct", False)  # struct needs span rows
-            and all(c.target in (T_RES, T_RATTR, T_TRACE) for c in p.conds))
-
-
-def _tres_needed(conds) -> list[str]:
-    need = {"tres.res", "tres.nspans", "trace.tres_off"}
-    for c in conds:
-        if c.target in (T_TRACE, T_RES):
-            need.add(c.col)
-        elif c.target == T_RATTR:
-            need.update({"rattr.res", "rattr.key_id", "rattr.vtype", "res.service_id"})
-            if c.col in _ATTR_VALUE_COL:
-                need.add(f"rattr.{_ATTR_VALUE_COL[c.col]}")
-    return sorted(need)
-
-
-def _host_plan(blk: BackendBlock, p, groups_range) -> tuple[list[str], bool]:
-    """(columns the host engine will read, tres-mode flag). tres mode is
-    whole-block only -- row-group shards slice the span axis."""
-    if groups_range is None and _tres_eligible(blk, p):
-        return _tres_needed(p.conds), True
-    needed = required_columns(p.conds) + list(getattr(p, "extra_cols", ()))
-    host_needed = ([n for n in needed if n != "span.trace_sid"]
-                   if "trace.span_off" in needed else needed)
-    return host_needed, False
 
 
 def _host_eval(blk: BackendBlock, p, operands, groups_range, plan=None):
@@ -474,9 +426,9 @@ def _host_eval(blk: BackendBlock, p, operands, groups_range, plan=None):
     (trace_mask, counts, cols_read). Covered spans are the caller's to
     report: tres mode still inspects every span's data (via its
     membership summary), so inspected_spans stays the span-axis count.
-    plan: a precomputed _host_plan result (callers that already built it
-    for warm_columns pass it through)."""
-    host_needed, tres = plan if plan is not None else _host_plan(blk, p, groups_range)
+    plan: a precomputed route.host_plan result (callers that already
+    built it for warm_columns pass it through)."""
+    host_needed, tres = plan if plan is not None else route.host_plan(blk, p, groups_range)
     cols = _host_cols(blk, host_needed, groups_range)
     if tres:
         # evaluate the same condition tree over the tres axis: entries
@@ -492,13 +444,9 @@ def _host_eval(blk: BackendBlock, p, operands, groups_range, plan=None):
             int(cols["tres.res"].shape[0]), blk.meta.total_traces,
         )
         return tm, counts, cols
-    span_ax = blk.pack.axes.get(S.AX_SPAN)
-    if groups_range is not None and span_ax is not None:
-        n_rows = sum(span_ax.offsets[g + 1] - span_ax.offsets[g] for g in groups_range)
-    else:
-        n_rows = span_ax.n_rows if span_ax else 0
     tm, counts = eval_block_host(
-        (p.tree, p.conds), cols, operands, n_rows, blk.meta.total_traces
+        (p.tree, p.conds), cols, operands, route.job_rows(blk, groups_range),
+        blk.meta.total_traces
     )
     return tm, counts, cols
 
@@ -546,67 +494,28 @@ def search_block(
     req: SearchRequest,
     groups_range: list[int] | None = None,
     mode: str = "auto",
+    planned=None,
 ) -> SearchResponse:
     """Search one block (optionally one row-group shard of it).
 
-    mode: 'device' | 'host' | 'auto'. auto picks the device engine for
-    blocks the storage layer keeps hot (TempoDB.open_block pins its
-    cached readers) or that already hold staged device columns, and the
-    host engine for cold one-shot readers, where column upload + a
-    dispatch round trip would dominate a single scan."""
+    mode: 'device' | 'host' | 'auto' (route.route_search picks).
+    planned: the job's plan where the caller made it (plan_job)."""
     resp = SearchResponse()
-    if not blk.meta.overlaps_time(req.start, req.end):
-        return resp
-    planned = _plan_for_block(blk, req)
-    if planned.prune:
-        return resp
-    if groups_range is not None and planned.has_struct:
-        # struct nodes resolve parent links by GLOBAL row index; a
-        # row-group slice would sever links across group boundaries, so
-        # shards take the conservative plan (trace-AND + host verify)
-        planned = _plan_for_block(blk, req, allow_struct=False)
-        if planned.prune:  # the conservative fold may prove "no match"
+    if planned is None:
+        planned = plan_job(blk, req, groups_range)
+        if planned is None:
             return resp
     limit = req.limit or DEFAULT_LIMIT
     operands = Operands.build(planned.rows, planned.tables or None)
-    needed = required_columns(planned.conds) + list(planned.extra_cols)
+    needed = route.stage_columns(planned)
     pack = blk.pack
     io0 = pack.bytes_read  # per-query IO delta (pack counts lifetime bytes)
-    span_ax = pack.axes.get(S.AX_SPAN)
-    if groups_range is not None and span_ax is not None:
-        n_rows = sum(span_ax.offsets[g + 1] - span_ax.offsets[g] for g in groups_range)
-    else:
-        n_rows = span_ax.n_rows if span_ax else 0
-
-    n_span_cols = max(1, sum(1 for n in needed if n.startswith(("span.", "sattr."))))
-
-    def _host_cheaper() -> bool:
-        """Auto mode weighs one device round trip against the host scan,
-        with the SAME cost model as search_blocks_fused: the tres plan
-        and cached host arrays scan at memory speed (serverless +
-        row-group shard jobs land here). Called only after the cheap
-        pinned/staged gate passed -- the RTT probe's first use inits the
-        device backend."""
-        host_cols_n, tres = _host_plan(blk, planned, groups_range)
-        if tres or all(blk.pack.has_cached_array(n) for n in host_cols_n
-                       if blk.pack.has(n)):
-            est_bytes = blk.meta.total_traces * 4 * 12 if tres else 0
-        else:
-            est_bytes = n_rows * 4 * n_span_cols
-        return est_bytes / _HOST_RATE_BPS * 1e3 < _link_rtt_ms()
+    n_rows = route.job_rows(blk, groups_range)
 
     from ..util.kerneltel import TEL
 
-    hot = getattr(blk, "device_pinned", False) or has_staged(blk)
-    if mode != "auto":
-        use_device, reason = mode == "device", "forced"
-    elif not hot:
-        use_device, reason = False, "cold_block"
-    elif _host_cheaper():
-        use_device, reason = False, "host_scan_cheaper"
-    else:
-        use_device, reason = True, "hot_block"
-    TEL.record_routing("search_block", "device" if use_device else "host", reason)
+    rt = route.route_search(blk, planned, groups_range, mode)
+    use_device, reason = rt.engine != "host", rt.reason
     # per-block stage with kernel attrs: a slow query's flame view shows
     # which block ran where and whether it recompiled
     with TEL.stage("block:search", block=blk.meta.block_id[:8],
@@ -615,7 +524,7 @@ def search_block(
         compiles0 = TEL.totals()[0]  # delta covers every chunk of a streamed eval
 
         if use_device:
-            if n_rows * 4 * n_span_cols > _STREAM_MIN_STAGE_BYTES:
+            if rt.engine == "stream":
                 # large scan: stream row-group chunks, prefetching the next
                 # chunk's IO while the device filters the current one
                 if planned.has_struct:  # streaming slices the span axis too
@@ -656,7 +565,7 @@ def search_block(
             if groups_range is None:
                 from ..ops.stream import staged_warm
 
-                plan = _host_plan(blk, planned, None)
+                plan = route.host_plan(blk, planned, None)
                 # single-unit form of the cold pipeline: coalesced ranged
                 # fetch + one threaded decode, with per-stage kerneltel
                 staged_warm(
@@ -690,100 +599,44 @@ def search_blocks_fused(
     req: SearchRequest,
     pool=None,
     default_limit: int = DEFAULT_LIMIT,
-    promote_touches: int = 2,
+    plans: list | None = None,
 ) -> SearchResponse | None:
     """Search many blocks with at most ONE device sync.
 
-    Engine choice is per block, by temperature: a block whose staged
-    device columns are already resident (or that has been searched
-    promote_touches times -- provably hot, worth the one-time staging
-    upload) evaluates on device; everything colder evaluates on host
-    with the vectorized numpy engine, which costs ZERO device round
-    trips and no staging upload. Device blocks share one fused
-    cross-block top-k
-    (one sync covers the whole group); host blocks run per-block
-    top-k collects in the IO pool. A cold one-shot scan therefore never
+    Engine choice is per block, by temperature (route.route_fused): a
+    block worth staging evaluates on device; everything colder evaluates
+    on host with the vectorized numpy engine, which costs ZERO device
+    round trips and no staging upload. Device blocks share one fused
+    cross-block top-k (one sync covers the whole group); host blocks run
+    per-block top-k collects in the IO pool. A cold one-shot scan therefore never
     touches the device, and a hot working set costs ~one RTT per query
     regardless of block count -- the single-chip counterpart of the mesh
     program in parallel/search.py, and the production engine behind
     TempoDB.search_blocks / the frontend's block-batch jobs.
 
+    plans: one a block where the caller has made them (plan_job).
     Returns None only when the combined staged footprint of the
     device-eligible blocks exceeds the device budget -- the caller
     falls back to per-block (streamed) search."""
     resp = SearchResponse()
     limit = req.limit or default_limit
-    in_range = [b for b in blocks if b.meta.overlaps_time(req.start, req.end)]
     # TempoDB already gates its io_pool on core count + backend locality;
     # this covers direct callers handing in an ungated pool
-    if (pool is not None and _N_CPU == 1 and in_range
-            and not getattr(in_range[0].backend, "is_remote", True)):
+    if (pool is not None and _N_CPU == 1 and blocks
+            and not getattr(blocks[0].backend, "is_remote", True)):
         pool = None
-    plans = (
-        list(pool.map(lambda b: _plan_for_block(b, req), in_range))
-        if pool is not None
-        else [_plan_for_block(b, req) for b in in_range]
-    )
-    live = [(blk, p) for blk, p in zip(in_range, plans) if not p.prune]
+    live = (list(zip(blocks, plans)) if plans is not None
+            else _live_plans(blocks, req, pool))
     if not live:
         return resp
 
-    # whole-query engine choice first: if scanning every live block on
-    # host is estimated cheaper than ONE device round trip (measured:
-    # util/linkcost), promotion is a loss no matter how hot the blocks
-    # are; per-block temperature only matters when the device can win
-    scan_bytes = 0
-    for blk, p in live:
-        host_cols_n, tres = _host_plan(blk, p, None)
-        # a block whose host columns sit in the array cache scans at
-        # memory speed -- its bytes don't count against the host engine
-        if all(blk.pack.has_cached_array(n) for n in host_cols_n
-               if blk.pack.has(n)):
-            continue
-        if tres:
-            # tres axis rows ~= resources-per-trace * traces, tiny next
-            # to the span axis; 3 int32 columns is the honest estimate
-            scan_bytes += blk.meta.total_traces * 4 * 12
-        else:
-            n_span = sum(1 for n in host_cols_n if n.startswith(("span.", "sattr.")))
-            scan_bytes += blk.pack.axes[S.AX_SPAN].n_rows * 4 * max(1, n_span)
-    host_est_ms = scan_bytes / _HOST_RATE_BPS * 1e3
-    prefer_host = host_est_ms < _link_rtt_ms()
-
     from ..util.kerneltel import TEL
 
-    dev_items: list[tuple[BackendBlock, object]] = []
-    host_items: list[tuple[BackendBlock, object]] = []
-    decisions: list[tuple[str, str]] = []  # recorded only if we RUN here
-    est = 0
-    for blk, p in live:
-        blk.search_touches = getattr(blk, "search_touches", 0) + 1
-        needed = (tuple(required_columns(p.conds)) + tuple(p.extra_cols)
-                  + ("trace@gkey_s",))
-        staged_hit = is_staged(blk, needed)
-        hot = not prefer_host and (staged_hit or blk.search_touches >= promote_touches)
-        if hot:
-            n_span_cols = max(1, sum(
-                1 for n in needed if n.startswith(("span.", "sattr."))
-            ))
-            est += blk.pack.axes[S.AX_SPAN].n_rows * 4 * n_span_cols
-            dev_items.append((blk, p))
-            decisions.append(("device", "staged_hit" if staged_hit else "promoted"))
-        else:
-            # hot is false either because the whole query prefers host or
-            # because this block is cold (staged miss, below promotion)
-            host_items.append((blk, p))
-            decisions.append(("host", "host_scan_cheaper" if prefer_host
-                              else "cold_block"))
-    if est > _DEVICE_SEARCH_MAX_BYTES:
-        # caller falls back to per-block (streamed) search, which records
-        # its own per-block decisions -- recording the per-block choices
-        # above too would double-count every evaluation
-        TEL.record_routing("search_fused", "fallback", "pre_io_budget",
-                           n=len(dev_items))
+    routes = route.route_fused(live)
+    if routes is None:
         return None
-    for engine, reason in decisions:
-        TEL.record_routing("search_fused", engine, reason)
+    dev_items = [it for it, r in zip(live, routes) if r.engine == "device"]
+    host_items = [it for it, r in zip(live, routes) if r.engine == "host"]
 
     io0 = {id(blk): blk.pack.bytes_read for blk, _ in live}
     results: list[tuple] = []  # _candidates records until the final merge
@@ -798,7 +651,7 @@ def search_blocks_fused(
     cold_ids: set[int] = set()
     cold_wants: list[tuple[BackendBlock, list[str]]] = []
     for blk, p in host_items:
-        plan = _host_plan(blk, p, None)
+        plan = route.host_plan(blk, p, None)
         host_plans[id(blk)] = plan
         if not all(blk.pack.has_cached_array(n) for n in plan[0]
                    if blk.pack.has(n)):
@@ -838,7 +691,7 @@ def search_blocks_fused(
         # hit still runs the host engine as a cold scan), but the rate
         # EMA only learns from scans that paid their own IO: a block the
         # prefetch served (fully or partly) times at somewhere between
-        # memory and IO speed and would inflate _HOST_RATE_BPS,
+        # memory and IO speed and would inflate route's host-rate EMA,
         # misrouting the next lone cold block toward the host engine
         plan = host_plans[id(blk)]
         host_needed = plan[0]
@@ -863,7 +716,7 @@ def search_blocks_fused(
                         host_needed + list(blk.SEARCH_TRACE_COLS) + ["trace.start_ms"])
             tm, counts, cols = _host_eval(blk, p, operands, None, plan=plan)
             if paid_io:
-                _note_host_rate(sum(a.nbytes for a in cols.values()),
+                route.note_host_rate(sum(a.nbytes for a in cols.values()),
                                 _time.perf_counter() - t0)
             key = _start_key_host(blk)
 
@@ -1019,8 +872,6 @@ def _collect_topk_multi(blocks, plans, offsets, req: SearchRequest, selector,
 
 # ---- stacked multi-block device search (parallel/search.py)
 
-_DEVICE_SEARCH_MAX_BYTES = 512 << 20  # stacked-column budget before falling back
-
 
 def _count_struct_nodes(tree) -> int:
     """Struct ('>' / '>>' / '~') nodes in a condition tree. Each one
@@ -1114,18 +965,7 @@ def search_blocks_device(
     the stacked columns (plus struct all_gather replication) exceed the
     device budget -- the caller falls back to per-block search_block."""
     resp = SearchResponse()
-    in_range = [b for b in blocks if b.meta.overlaps_time(req.start, req.end)]
-    # plan fan-out pulls each block's dictionary + footer: overlap the IO
-    plans = (
-        list(pool.map(lambda b: _plan_for_block(b, req), in_range))
-        if pool is not None
-        else [_plan_for_block(b, req) for b in in_range]
-    )
-    live: list[tuple[BackendBlock, object]] = []
-    for blk, p in zip(in_range, plans):
-        if p.prune:
-            continue
-        live.append((blk, p))
+    live = _live_plans(blocks, req, pool)
     if not live:
         return resp
 
@@ -1180,7 +1020,7 @@ def _search_group_device(items, tree, conds, req: SearchRequest, mesh, resp: Sea
             )
             attr_b[pre] = sp * bucket(max(1, -(-max(a_max, 1) // sp)))
     est = _stacked_words_est(items, needed, tree, sp, S_b, NT_b, attr_b)
-    if Bp * est * 4 > _DEVICE_SEARCH_MAX_BYTES:
+    if Bp * est * 4 > route.JOB_STAGE_BUDGET_BYTES:
         from ..util.kerneltel import TEL
 
         TEL.record_routing("search_mesh", "fallback", "pre_io_budget",

@@ -44,7 +44,7 @@ class TempoDBConfig:
     device_find: bool = True  # batched/sharded device Find (ops/find, parallel/find)
     device_search: bool = True  # stacked multi-block device search (parallel/search)
     # searches of a block before its columns are staged on device (first
-    # touches run the zero-RTT host engine; see search_blocks_fused)
+    # touches run the zero-RTT host engine; db/route reads it off the readers)
     device_promote_touches: int = 2
     # cross-query batching executor (db/batchexec): None fields resolve
     # from the TEMPO_BATCH / TEMPO_BATCH_WINDOW_MS / TEMPO_BATCH_MAX env
@@ -52,6 +52,12 @@ class TempoDBConfig:
     batch_window_ms: float | None = None
     batch_max: int | None = None
     compaction: comp.CompactorConfig = field(default_factory=comp.CompactorConfig)
+
+
+def _raised(r):
+    if isinstance(r, Exception):
+        raise r
+    return r
 
 
 class TempoDB:
@@ -102,7 +108,7 @@ class TempoDB:
         self.polls = Counter("tempo_blocklist_polls_total")
         # measured-crossover routing: seed the cold-scan host-rate EMA
         # from the persisted CostLedger (util/costledger) once
-        from .search import seed_host_rate_from_ledger
+        from .route import seed_host_rate_from_ledger
 
         seed_host_rate_from_ledger()
 
@@ -137,9 +143,10 @@ class TempoDB:
 
                 blk = open_block_versioned(self.backend, meta)
                 # cached readers are long-lived over immutable blocks:
-                # mark them device-worthy so search_block's auto mode
-                # stages (and keeps) their columns on the accelerator
+                # mark them device-worthy so db/route's auto mode stages
+                # (and keeps) their columns on the accelerator
                 blk.device_pinned = self.cfg.device_search
+                blk.promote_touches = self.cfg.device_promote_touches
                 if len(self._block_cache) >= self.cfg.block_cache_blocks:
                     self._block_cache.pop(next(iter(self._block_cache)))
                 self._block_cache[key] = blk
@@ -264,120 +271,95 @@ class TempoDB:
         metas = [m for m in self.blocklist.metas(tenant) if m.overlaps_time(req.start, req.end)]
         return self.search_blocks(tenant, metas, req)
 
-    def search_blocks(self, tenant: str, metas: list[BlockMeta], req: SearchRequest,
-                      _skip_batcher: bool = False) -> SearchResponse:
+    def search_blocks(self, tenant: str, metas: list[BlockMeta], req: SearchRequest) -> SearchResponse:
         """Search a set of blocks as one unit -- the execution engine
-        behind both TempoDB.search and the frontend's block-batch jobs.
-        Single chip: fused per-block kernels + ONE cross-block device
-        top-k sync (db/search.search_blocks_fused). Mesh: the stacked
-        sharded program (parallel/search.py). Falls back to per-block
-        search when the device budget or plan shape demands it.
-        _skip_batcher: the caller already probed batch eligibility for
-        this query and got a fallback -- don't plan and count it twice."""
-        resp = SearchResponse()
-        if not metas:
-            return resp
-        if (self.cfg.device_search and len(metas) == 1
-                and self.batchers.enabled and not _skip_batcher):
-            # single-block unit: concurrent queries against the same hot
-            # block coalesce into one fused multi-query launch
-            from .batchexec import batched_search_block
+        behind both TempoDB.search and the frontend's block-batch jobs."""
+        return _raised(self._run_search_jobs([(metas, req, None)])[0])
 
-            got = batched_search_block(
-                self.batchers.search, self.open_block(metas[0]), req,
-                promote_touches=self.cfg.device_promote_touches,
-                default_limit=self.cfg.search_default_limit)
-            if got is not None:
-                return got
+    def search_block_shard(self, tenant: str, meta: BlockMeta, req: SearchRequest, groups_range) -> SearchResponse:
+        """One sharded search job (frontend's StartPage/TotalPages analog)."""
+        return _raised(self._run_search_jobs([([meta], req, groups_range)], shard=True)[0])
+
+    def search_block_shard_multi(self, items: list) -> list:
+        """Many (tenant, meta, req, groups_range) shard jobs at once."""
+        return self._run_search_jobs(
+            [([m], req, groups) for _, m, req, groups in items], shard=True)
+
+    def search_blocks_multi(self, items: list) -> list:
+        """Many (tenant, metas, req) search jobs at once -- the frontend's
+        batch-aware dequeue hands a whole burst here so even a single
+        worker thread forms full fused batches."""
+        return self._run_search_jobs([(metas, req, None) for _, metas, req in items])
+
+    def _run_search_jobs(self, jobs: list, shard: bool = False) -> list:
+        """The one search job entry: (metas, req, groups_range) jobs, all
+        row-group shard jobs (one block each) or all block-set jobs.
+        One-block jobs go to the batch window (db/batchexec), where
+        concurrent ones against one hot block coalesce into one fused
+        launch; a job it refuses, and any other, runs `outside` under the
+        plan the window made, if it made one. Returns a SearchResponse a
+        job or, where the window ran a job that failed, its Exception."""
+        from .batchexec import batched_search_block_many
+
+        def outside(blocks, req, groups, planned=None):
+            if shard:
+                return search_block(blocks[0], req, groups_range=groups, planned=planned)
+            return self._search_block_set(blocks, req, planned)
+
+        jobs = [([self.open_block(m) for m in metas], req, groups)
+                for metas, req, groups in jobs]
+        out: list = [None] * len(jobs)
+        if self.cfg.device_search and self.batchers.enabled:
+            ones = [i for i, (blocks, _, _) in enumerate(jobs) if len(blocks) == 1]
+            got = batched_search_block_many(
+                self.batchers.search,
+                [(jobs[i][0][0], *jobs[i][1:]) for i in ones],
+                default_limit=None if shard else self.cfg.search_default_limit,
+                refused=lambda blk, *rest: outside([blk], *rest))
+            for i, r in zip(ones, got):
+                out[i] = r
+        return [outside(*job) if r is None else r for job, r in zip(jobs, out)]
+
+    def _search_block_set(self, blocks: list[BackendBlock], req: SearchRequest,
+                          planned=None) -> SearchResponse:
+        """A block-set job outside the batch window. Single chip: fused
+        per-block kernels + ONE cross-block device top-k sync
+        (search_blocks_fused). Mesh: the stacked sharded program
+        (parallel/search.py). Per-block search when the device budget or
+        plan shape demands it. planned: a one-block job's plan."""
+        resp = SearchResponse()
+        if not blocks:
+            return resp
+        limit = req.limit or self.cfg.search_default_limit
         if self.cfg.device_search:
-            if self.mesh.devices.size > 1 and len(metas) > 1:
+            if self.mesh.devices.size > 1 and len(blocks) > 1:
                 from .search import search_blocks_device
 
                 got = search_blocks_device(
-                    [self.open_block(m) for m in metas], req, self.mesh,
+                    blocks, req, self.mesh,
                     default_limit=self.cfg.search_default_limit, pool=self.io_pool,
                 )
             else:
                 from .search import search_blocks_fused
 
                 got = search_blocks_fused(
-                    [self.open_block(m) for m in metas], req,
-                    pool=self.io_pool, default_limit=self.cfg.search_default_limit,
-                    promote_touches=self.cfg.device_promote_touches,
+                    blocks, req, pool=self.io_pool,
+                    default_limit=self.cfg.search_default_limit,
+                    plans=None if planned is None else [planned],
                 )
             if got is not None:  # None -> oversize / plan-shape fallback
                 return got
-        fallback = (self.io_pool.map(lambda m: search_block(self.open_block(m), req), metas)
-                    if self.io_pool is not None
-                    else (search_block(self.open_block(m), req) for m in metas))
-        for r in fallback:
-            resp.merge(r, req.limit or self.cfg.search_default_limit)
-            if len(resp.traces) >= (req.limit or self.cfg.search_default_limit):
+
+        def one(blk):
+            return search_block(blk, req, planned=planned)
+
+        for r in (self.io_pool.map(one, blocks) if self.io_pool is not None
+                  else map(one, blocks)):
+            resp.merge(r, limit)
+            if len(resp.traces) >= limit:
                 break
         resp.traces.sort(key=lambda t: -t.start_time_unix_nano)
         return resp
-
-    def search_block_shard(self, tenant: str, meta: BlockMeta, req: SearchRequest, groups_range) -> SearchResponse:
-        """One sharded search job (frontend's StartPage/TotalPages analog).
-        Concurrent shard jobs over the same row-group range coalesce
-        through the batching executor; ineligible plans run unchanged."""
-        blk = self.open_block(meta)
-        if self.cfg.device_search and self.batchers.enabled:
-            from .batchexec import batched_search_block
-
-            got = batched_search_block(
-                self.batchers.search, blk, req, groups_range=groups_range,
-                promote_touches=self.cfg.device_promote_touches)
-            if got is not None:
-                return got
-        return search_block(blk, req, groups_range=groups_range)
-
-    def search_block_shard_multi(self, items: list) -> list:
-        """Many (tenant, meta, req, groups_range) shard jobs at once;
-        same-shard jobs submit to the batcher together."""
-        from .batchexec import batched_search_block_many
-
-        out: list = [None] * len(items)
-        if self.cfg.device_search and self.batchers.enabled:
-            entries = [(self.open_block(m), req, groups)
-                       for (tenant, m, req, groups) in items]
-            out = batched_search_block_many(
-                self.batchers.search, entries,
-                promote_touches=self.cfg.device_promote_touches)
-        for i, (tenant, m, req, groups) in enumerate(items):
-            if out[i] is None:
-                out[i] = search_block(self.open_block(m), req,
-                                      groups_range=groups)
-        return out
-
-    def search_blocks_multi(self, items: list) -> list:
-        """Execute many (tenant, metas, req) search jobs at once -- the
-        frontend's batch-aware dequeue hands a whole burst here so even
-        a single worker thread forms full fused batches. Single-block
-        jobs group by coalesce key and join the batcher window together;
-        everything else runs the normal per-job path."""
-        from .batchexec import batched_search_block_many
-
-        out: list = [None] * len(items)
-        singles: list[tuple[int, tuple]] = []
-        for i, (tenant, metas, req) in enumerate(items):
-            if (self.cfg.device_search and self.batchers.enabled
-                    and len(metas) == 1):
-                singles.append((i, (self.open_block(metas[0]), req, None)))
-        if singles:
-            got = batched_search_block_many(
-                self.batchers.search, [e for _, e in singles],
-                promote_touches=self.cfg.device_promote_touches,
-                default_limit=self.cfg.search_default_limit)
-            for (i, _), r in zip(singles, got):
-                out[i] = r
-        for i, (tenant, metas, req) in enumerate(items):
-            if out[i] is None:
-                # single-block entries were already probed (and refused)
-                # by the batcher above: go straight to the engine
-                out[i] = self.search_blocks(tenant, metas, req,
-                                            _skip_batcher=len(metas) == 1)
-        return out
 
     # ------------------------------------------------------------ metrics
     def metrics_query_range(self, tenant: str, req) -> "object":

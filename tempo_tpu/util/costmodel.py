@@ -59,7 +59,7 @@ import time
 # Published per-chip peaks, keyed by the `device_kind` jax reports.
 # Source: Google Cloud documentation, "TPU v5e" -- 197 TFLOP/s bf16,
 # 16 GB of HBM at 819 GB/s. A kind missing here has no roofline:
-# /status/cost says "unknown" and bench.py refuses to run.
+# /status/cost says "unknown".
 DEVICE_PEAKS = {
     "TPU v5 lite": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12,
                     "hbm_bytes": 16e9},
@@ -596,7 +596,7 @@ def check_device(platform: str, asked_platforms: str | None) -> None:
 def resolve_device() -> dict:
     """Resolve the jax backend ONCE for this process and name it:
     {"platform", "device_kind", "count"} as jax reports them. Every
-    entry point that launches kernels (services/app targets, bench.py)
+    entry point that launches kernels (the services/app targets)
     calls this before serving; the "listening" line and the status JSON
     repeat what it returned. Raises NoAcceleratorError per
     check_device."""

@@ -117,15 +117,14 @@ def test_batched_launch_reduction_and_identity():
     from tempo_tpu.db.batchexec import batched_search_block_many
 
     # warm: stages the block + compiles both fused and sequential programs
-    warm = batched_search_block_many(db.batchers.search, [(blk, req, None)],
-                                     promote_touches=1)
+    warm = batched_search_block_many(db.batchers.search, [(blk, req, None)])
     assert warm[0] is not None
     seq_ref = search_block(blk, req, mode="device")
     assert _dicts(warm[0]) == _dicts(seq_ref)
 
     l0 = TEL.launch_count()
     outs = batched_search_block_many(
-        db.batchers.search, [(blk, req, None)] * 16, promote_touches=1)
+        db.batchers.search, [(blk, req, None)] * 16)
     batched_launches = TEL.launch_count() - l0
     assert all(o is not None for o in outs)
     for o in outs:

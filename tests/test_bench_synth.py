@@ -1,19 +1,15 @@
-"""Conformance: the bench's synthetic block is indistinguishable from a
-builder-produced block to the read path (same column set, working find +
-search), so bench numbers measure the real format."""
-
-import sys
-from pathlib import Path
+"""Conformance: the synthetic block (util/testdata.synth_block) is
+indistinguishable from a builder-produced block to the read path (same
+column set, working find + search), so numbers taken over synthetic
+corpora measure the real format."""
 
 import numpy as np
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))  # bench.py
-
-from tempo_tpu.backend import MemBackend  # noqa: E402
-from tempo_tpu.block import build_block_from_traces  # noqa: E402
-from tempo_tpu.block.reader import BackendBlock, open_block  # noqa: E402
-from tempo_tpu.db.search import SearchRequest, search_block  # noqa: E402
-from tempo_tpu.util.testdata import make_traces, synth_block  # noqa: E402
+from tempo_tpu.backend import MemBackend
+from tempo_tpu.block import build_block_from_traces
+from tempo_tpu.block.reader import BackendBlock, open_block
+from tempo_tpu.db.search import SearchRequest, search_block
+from tempo_tpu.util.testdata import make_traces, synth_block
 
 
 def test_synth_block_matches_builder_columns():
@@ -47,23 +43,3 @@ def test_synth_block_find_and_search():
     expect = {ids[s].tobytes().hex()
               for s in np.unique(sid_col[svc_col[res_idx] == code])}
     assert {r.trace_id for r in resp.traces} == expect
-
-
-def test_tel_close_workers_normalizes_device_time_share(monkeypatch):
-    """Concurrent sections accumulate device seconds across Q threads
-    while wall time doesn't: without the workers divisor the share reads
-    ~Q (BENCH_r06 search_concurrent reported 3.85). With it, a section
-    whose threads were device-busy the whole time reads <= ~1."""
-    import time as _time
-
-    from bench import _tel_close
-    from tempo_tpu.util import kerneltel as kt
-
-    mark = (0, 0.0, _time.perf_counter() - 0.1)  # section wall ~0.1s
-    # 4 threads x ~0.09s device time each inside that 0.1s wall
-    monkeypatch.setattr(kt.TEL, "totals", lambda: (0, 0.36))
-    raw = _tel_close(mark)
-    assert raw["device_time_share"] > 2.0  # the r06 artifact, reproduced
-    share = _tel_close(mark, workers=4)["device_time_share"]
-    assert 0.0 < share <= 1.05
-    assert abs(share - raw["device_time_share"] / 4) < 0.05

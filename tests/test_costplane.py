@@ -433,21 +433,21 @@ def test_live_engine_seeds_from_ledger_env_wins(tmp_path, monkeypatch):
 
 
 def test_host_rate_seed_from_ledger(tmp_path, monkeypatch):
-    from tempo_tpu.db import search as search_mod
+    from tempo_tpu.db import route as route_mod
 
     costledger.configure(str(tmp_path / "ledger.json"))
     costledger.ledger().update(costledger.KEY_BLOCK_SCAN,
                                host_rate_bps=9.9e9)
-    monkeypatch.setattr(search_mod, "_HOST_RATE_SEEDED", False)
-    monkeypatch.setattr(search_mod, "_HOST_RATE_BPS", 1.5e9)
-    search_mod.seed_host_rate_from_ledger()
-    assert search_mod._HOST_RATE_BPS == 9.9e9
+    monkeypatch.setattr(route_mod, "_HOST_RATE_SEEDED", False)
+    monkeypatch.setattr(route_mod, "_HOST_RATE_BPS", 1.5e9)
+    route_mod.seed_host_rate_from_ledger()
+    assert route_mod._HOST_RATE_BPS == 9.9e9
     # idempotent: a second call (another TempoDB) never re-seeds over
     # the EMA the process has been learning since
-    search_mod._note_host_rate(100 << 20, 0.01)
-    learned = search_mod._HOST_RATE_BPS
-    search_mod.seed_host_rate_from_ledger()
-    assert search_mod._HOST_RATE_BPS == learned
+    route_mod.note_host_rate(100 << 20, 0.01)
+    learned = route_mod._HOST_RATE_BPS
+    route_mod.seed_host_rate_from_ledger()
+    assert route_mod._HOST_RATE_BPS == learned
 
 
 # --------------------------------------------------- app status surfaces

@@ -561,12 +561,8 @@ def test_tres_membership_axis(tmp_path):
     axis, drives the res-only host fast path to identical results, and
     survives compaction with remapped res indices."""
     from tempo_tpu.block.builder import build_tres
-    from tempo_tpu.db.search import (
-        SearchRequest,
-        _host_plan,
-        _plan_for_block,
-        search_block,
-    )
+    from tempo_tpu.db.route import host_plan
+    from tempo_tpu.db.search import SearchRequest, _plan_for_block, search_block
 
     db = _db(tmp_path)
     db.cfg.compaction.min_input_blocks = 2
@@ -593,7 +589,7 @@ def test_tres_membership_axis(tmp_path):
     assert svc is not None
     req = SearchRequest(tags={"service.name": svc}, limit=100)
     p = _plan_for_block(blk, req)
-    host_needed, tres_mode = _host_plan(blk, p, None)
+    host_needed, tres_mode = host_plan(blk, p, None)
     assert tres_mode and "tres.res" in host_needed
     got = search_block(blk, req, mode="host")
 

@@ -296,7 +296,7 @@ def test_fallback_paths_byte_identical(monkeypatch):
     m1 = db1.write_block(TENANT, traces)
     blk1 = db1.open_block(m1)
     outs = batched_search_block_many(
-        db1.batchers.search, [(blk1, req, None)] * 4, promote_touches=1)
+        db1.batchers.search, [(blk1, req, None)] * 4)
     for o in outs:
         assert _dicts(o) == ref
     r1 = TEL.routing_counts()
@@ -316,7 +316,7 @@ def test_fallback_paths_byte_identical(monkeypatch):
     db2.batchers = QueryBatchers(enabled=True, window_ms=200.0,
                                  mesh_fn=lambda: None)
     outs2 = batched_search_block_many(
-        db2.batchers.search, [(blk2, req, None)] * 4, promote_touches=1)
+        db2.batchers.search, [(blk2, req, None)] * 4)
     for o in outs2:
         assert _dicts(o) == ref
     db2.close()

@@ -492,13 +492,13 @@ def test_slow_query_e2e_chaos_to_artifact(tmp_path, monkeypatch, capsys):
         # blocks with cached host arrays otherwise always scan host and
         # a slow-LAUNCH rule would have nothing to slow), and pay the
         # device compile storm outside the chaos window
-        from tempo_tpu.db import search as search_mod
+        from tempo_tpu.db import route as route_mod
 
         q = urllib.parse.quote('{ duration > 1ms }')
         for _ in range(3):
             urllib.request.urlopen(f"{base}/api/search?q={q}&limit=10",
                                    timeout=60)
-        monkeypatch.setattr(search_mod, "_link_rtt_ms", lambda: -1.0)
+        monkeypatch.setattr(route_mod, "link_rtt_ms", lambda: -1.0)
         urllib.request.urlopen(f"{base}/api/search?q={q}&limit=10",
                                timeout=120)
         time.sleep(0.3)  # clear the capture stampede guard

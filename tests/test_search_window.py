@@ -22,6 +22,7 @@ import pytest
 
 from tempo_tpu.backend.mem import MemBackend
 from tempo_tpu.block.reader import BackendBlock
+from tempo_tpu.db import route as route_mod
 from tempo_tpu.db import search as search_mod
 from tempo_tpu.db.search import (
     SearchRequest,
@@ -121,9 +122,9 @@ def _fused(rtt_ms):
     def run(db, tenant, req, monkeypatch):
         # the router weighs a host scan against one link round trip: a
         # huge estimate keeps every block on the host engine, a negative
-        # one sends every block to the device (promote_touches=1)
-        monkeypatch.setattr(search_mod, "_link_rtt_ms", lambda: rtt_ms)
-        got = search_blocks_fused(_blocks(db, tenant), req, promote_touches=1)
+        # one sends every block to the device (the db promotes at 1 touch)
+        monkeypatch.setattr(route_mod, "link_rtt_ms", lambda: rtt_ms)
+        got = search_blocks_fused(_blocks(db, tenant), req)
         assert got is not None
         return got
     return run
@@ -140,8 +141,7 @@ def _batchexec(db, tenant, req, monkeypatch=None):
     for b in _blocks(db, tenant):
         mate = replace(req, limit=(req.limit or 20) + 1)
         got = batched_search_block_many(
-            db.batchers.search, [(b, req, None), (b, mate, None)],
-            promote_touches=1)[0]
+            db.batchers.search, [(b, req, None), (b, mate, None)])[0]
         if isinstance(got, Exception):
             raise got
         resps.append(got if got is not None else search_block(b, req))
@@ -155,7 +155,7 @@ def _mesh(db, tenant, req, monkeypatch=None):
 
 
 def _streamed(db, tenant, req, monkeypatch):
-    monkeypatch.setattr(search_mod, "_STREAM_MIN_STAGE_BYTES", 0)
+    monkeypatch.setattr(route_mod, "JOB_STAGE_BUDGET_BYTES", 0)
     return _per_block(db, tenant, req, mode="device")
 
 
@@ -358,7 +358,7 @@ def test_verify_routing_counter(world, monkeypatch):
     assert delta(lambda: search_block(blk, struct, groups_range=groups[:2],
                                       mode="host")) == {("hosteval", "struct_on_shard"): 1}
     # the fused engine: one decision per block of the global collect
-    monkeypatch.setattr(search_mod, "_link_rtt_ms", lambda: 1e9)
+    monkeypatch.setattr(route_mod, "link_rtt_ms", lambda: 1e9)
     assert delta(lambda: search_blocks_fused(_blocks(db, TWO), SearchRequest(
         query='{ duration > 1us }', **win))) == {("skip", "exact_plan"): 2}
     rows = [r for r in TEL.snapshot()["routing"] if r["layer"] == "verify"]
@@ -399,7 +399,7 @@ def test_results_are_built_only_behind_candidates():
     """Static half of the invariant: in db/, SearchResult objects are built
     by _materialize from the records _candidates made (and by the live
     head, which settles its own per-trace index), and _materialize is
-    called only by the collects and the two merges that take their records.
+    called only by the collects and the fused merge that takes their records.
     A new engine that builds results itself has to come here and say who
     re-checks its window."""
     root = pathlib.Path(search_mod.__file__).parent
@@ -418,8 +418,7 @@ def test_results_are_built_only_behind_candidates():
     assert builders == {"search._materialize", "search.response_from_dict",
                         "live_engine._collect"}, builders
     assert callers == {"search._collect_topk", "search._collect_topk_multi",
-                       "search.search_blocks_fused",
-                       "batchexec._run_search_group_fused"}, callers
+                       "search.search_blocks_fused"}, callers
     src = (root / "search.py").read_text()
     for fn in ("_collect_topk", "_collect_topk_multi"):
         body = src.split(f"def {fn}(")[1].split("\ndef ")[0]

@@ -408,14 +408,20 @@ def test_is_staged_agrees_with_the_routes(route):
         # below the promotion threshold, under the key shape it had
         from types import SimpleNamespace
 
-        from tempo_tpu.db.batchexec import _probe_search_entry
+        from tempo_tpu.db.batchexec import batched_search_block_many
 
-        key, item = _probe_search_entry(
-            SimpleNamespace(enabled=True), blk, _SEARCHES["duration_gt"], None,
-            promote_touches=99)
+        taken = []
+        window = SimpleNamespace(enabled=True, submit_many=lambda key, items: (
+            taken.append((key, items)) or [None] * len(items)))
+        blk.promote_touches = 99
+        batched_search_block_many(window, [(blk, _SEARCHES["duration_gt"], None)])
+        (key, (item,)), = taken
         assert key[3] is None and key[4] == tuple(needed)
         assert item.needed == base
         _, _, cold = _open(seed=44)
-        assert _probe_search_entry(
-            SimpleNamespace(enabled=True), cold, _SEARCHES["duration_gt"], None,
-            promote_touches=99) is None
+        cold.promote_touches = 99
+        plans = []
+        batched_search_block_many(
+            window, [(cold, _SEARCHES["duration_gt"], None)],
+            refused=lambda blk, req, groups, planned: plans.append(planned))
+        assert len(taken) == 1 and plans[0].conds == item.planned.conds
