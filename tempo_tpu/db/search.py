@@ -931,7 +931,9 @@ def _stacked_words_est(items, needed: list[str], tree, sp: int,
         n_val_cols = sum(
             1 for n in needed if n.startswith(f"{pre}.") and not n.endswith((".span", ".res"))
         )
-        est += a_b * n_val_cols + (S_b + 1 if pre == "sattr" else 0)  # values + off
+        # values + off: the stacked program's own columns (flat rows and
+        # offsets, built below), not ops/stage's slot-major form
+        est += a_b * n_val_cols + (S_b + 1 if pre == "sattr" else 0)
     from ..parallel.search import struct_pack_enabled
 
     if struct_pack_enabled():
@@ -1058,7 +1060,7 @@ def _search_group_device(items, tree, conds, req: SearchRequest, mesh, resp: Sea
             # owner rows (grouped by owner) -> per-owner offset column,
             # replicated along sp; the kernel aggregates with cumsum +
             # offset gathers (parallel/search.owner_counts). Mirrors
-            # ops/stage.py's single-device offsetting.
+            # ops/stage.py's single-device offsetting of `rattr.res`.
             n_seg_b = S_b if n == "sattr.span" else R_b
             out = np.zeros((Bp, n_seg_b + 1), dtype=np.int32)
             for bi, cols in enumerate(per_block):

@@ -218,6 +218,23 @@ def _cond_mask(c: Cond, i, cols, ops_i, ops_f, tables, n_spans_b, n_res_b, valid
             vt_ok = cols[f"{pre}.vtype"] == _VT_CODE[c.col]
             row_hit = key_match & vt_ok & _cmp(c.op, vcol, v0, v1, f0, f1, c.is_float, table)
         if pre == T_SATTR:
+            over = cols.get("sattr.over")
+            if over is not None:
+                # slot-major (ops/stage._assemble): planes of n_spans_b,
+                # plane j holding every span's j-th attribute row, then
+                # the slice's overflow rows, owned by `over`. A span
+                # matches iff any of its slots hits: an element-wise OR
+                # a plane and a scatter of the overflow rows alone (none
+                # where counts are even) -- no cumsum over attribute
+                # rows, no span-length gather
+                n_head = row_hit.shape[0] - over.shape[0]
+                hit = jnp.zeros(n_spans_b, bool)
+                for lo in range(0, n_head, n_spans_b):
+                    hit |= row_hit[lo:lo + n_spans_b]
+                if over.shape[0]:  # a padded row's owner is n_spans_b: dropped
+                    hit = hit.at[over].max(row_hit[n_head:], mode="drop",
+                                           indices_are_sorted=True)
+                return hit & valid_span
             if "sattr.off" in cols:  # grouped-by-span rows: scan, no scatter
                 return (_offset_counts(row_hit, cols["sattr.off"]) > 0) & valid_span
             owner = jnp.clip(cols["sattr.span"], 0, n_spans_b - 1)
@@ -234,6 +251,28 @@ def _cond_mask(c: Cond, i, cols, ops_i, ops_f, tables, n_spans_b, n_res_b, valid
         idx = jnp.clip(cols["span.res_idx"], 0, n_res_b - 1)
         return res_mask[idx] & (cols["span.res_idx"] >= 0) & valid_span
     raise ValueError(f"bad target {c.target}")
+
+
+def attr_reduce_route(conds, cols) -> tuple[str, str] | None:
+    """How a launch over `cols` turns generic span-attribute row hits
+    into a span mask (_cond_mask's T_SATTR branch) -> (engine, reason),
+    counted as the `attr_reduce` routing row; None when no condition has
+    that target. Read off the columns: `slots` is what ops/stage stages
+    (`skewed_counts` when the slice has overflow rows to scatter),
+    `offsets` a caller's own flat rows."""
+    if not any(c.target == T_SATTR for c in conds):
+        return None
+    from ..util.kerneltel import TEL
+
+    over = cols.get("sattr.over")
+    if over is not None:
+        route = ("slots", "skewed_counts" if over.shape[0] else "dense_counts")
+    elif "sattr.off" in cols:
+        route = ("offsets", "flat_rows")
+    else:
+        route = ("offsets", "no_offsets")
+    TEL.record_routing("attr_reduce", *route)
+    return route
 
 
 def normalize_tree(tree: CondTree, conds: tuple[Cond, ...]) -> CondTree:
@@ -470,7 +509,8 @@ def eval_block(
     ns, nt = np.int32(n_spans), np.int32(n_traces)
     with TEL.launch(
         "filter",
-        ("filter", tree, conds, table_idxs, n_spans_b, n_res_b, n_traces_b, span_out),
+        ("filter", tree, conds, table_idxs, n_spans_b, n_res_b, n_traces_b, span_out,
+         attr_reduce_route(conds, cols)),
         n_spans_b,
         cost=lambda: costmodel.spec(fn, cols, operands.ints, operands.floats,
                                     table_list, ns, nt),

@@ -9,12 +9,17 @@ bucket, and uploads.
 The staged cache holds device COLUMNS, not column sets. Each (immutable)
 block object carries a store keyed by (device column name, group range
 or None) -> one padded device array. Span- and sattr-axis columns and
-what is derived from them per slice (`sattr.off`, the rebased
+what is derived from them per slice (`sattr.over`, the rebased
 `trace.span_off`, `span@<res column>`) key on the group range; trace-,
 res- and rattr-axis columns and `trace@gkey_s` do not depend on the
 range and key on None, so row-group shards and whole-block requests
 share them. `column_keys` is the one place a request name becomes a
-device name. A request resolves every column it names against the
+device name. A slice's generic span-attribute columns (`sattr.*`) reach
+the device slot-major (`_assemble`): one array a column, `K` planes of
+`n_spans_b` (plane `j` = every span's `j`-th attribute row) and then
+the rows beyond a span's `K`-th, whose owners are `sattr.over`; the
+kernel ORs over the planes and scatters only the overflow rows
+(ops/filter._cond_mask). A request resolves every column it names against the
 store, stages only the missing ones (host chunk pool first, then the
 backend) and gets a fresh StagedBlock view over the shared arrays: a
 column is read, assembled and uploaded once however the routes spell
@@ -242,6 +247,11 @@ def plan_stage(needed: list[str]) -> StagePlan:
     start_ms_for_gkey_only = want_gkey and "trace.start_ms" not in read_names
     if start_ms_for_gkey_only:
         read_names = read_names + ["trace.start_ms"]
+    # a generic-attribute value column is placed by its owners (_assemble),
+    # so the owner rows are read beside it even when only it is missing
+    if "sattr.span" not in read_names and any(
+            n.startswith("sattr.") for n in read_names):
+        read_names = read_names + ["sattr.span"]
     return StagePlan(read_names, materialize, want_gkey, start_ms_for_gkey_only)
 
 
@@ -280,9 +290,10 @@ def read_stage_columns(blk: BackendBlock, plan: StagePlan,
     return host, _n_res(blk, plan.read_names)
 
 
-# request names whose device column has another name: owner-row columns
-# reach the device as cumulative offsets (_assemble)
-_DEVICE_NAME = {"sattr.span": "sattr.off", "rattr.res": "rattr.off"}
+# request names whose device column has another name: of the owner-row
+# columns the device gets what _assemble derives -- the owners of a
+# slice's overflow rows, and cumulative offsets on the resource axis
+_DEVICE_NAME = {"sattr.span": "sattr.over", "rattr.res": "rattr.off"}
 
 
 def column_keys(blk: BackendBlock, needed, groups) -> dict[str, tuple]:
@@ -309,6 +320,17 @@ def column_keys(blk: BackendBlock, needed, groups) -> dict[str, tuple]:
             continue
         keys[n] = (device_name, gkey if ranged else None)
     return keys
+
+
+def _head_planes(cnt: np.ndarray, n_rows: int, n_spans_b: int) -> int:
+    """K, the dense planes a slice's generic attributes get: as many as
+    its longest span needs, but no more than the flat rows' bucket would
+    hold -- so the planes never cost more device memory than the rows
+    did flat, however long the tail. Rows beyond a span's K-th are the
+    slice's overflow (_assemble). From the slice's own counts: uniform
+    counts leave no overflow, one span with 40 attributes a few rows of
+    it, attributes rarer than spans (K = 0) all of them."""
+    return min(int(cnt.max()), bucket(max(n_rows, 1)) // n_spans_b)
 
 
 @lru_cache(maxsize=1024)
@@ -499,20 +521,40 @@ def _assemble(blk, plan, groups, host, n_res):
     want_gkey = plan.want_gkey
     start_ms_for_gkey_only = plan.start_ms_for_gkey_only
 
-    # owner-offset columns: rows of every child table are grouped by
-    # owner, so the kernel aggregates with cumsum + offset gathers
-    # (ops/filter._offset_counts) -- the owner row columns themselves
-    # never need to reach the device.
+    # rows of every child table are grouped by owner; the owner row
+    # columns themselves never need to reach the device
     real_rows: dict[str, int] = {}  # pre-padding lengths (telemetry)
     if "sattr.span" in host:
-        owners = np.clip(host["sattr.span"] - span_base, 0, max(n_spans, 1) - 1)
-        cnt = np.bincount(owners, minlength=max(n_spans, 1)) if owners.size else np.zeros(
-            max(n_spans, 1), dtype=np.int64
-        )
-        off = np.concatenate([[0], np.cumsum(cnt)]).astype(np.int32)
-        real_rows["sattr.off"] = int(off.shape[0])
-        host["sattr.off"] = pad_rows(off, n_spans_b + 1, off[-1] if off.size else 0)
-        del host["sattr.span"]
+        # slot-major: one array a value column -- K planes of n_spans_b,
+        # plane j holding every span's j-th attribute row and PAD_I32
+        # where a span has fewer (a PAD key matches no key code, so an
+        # empty slot can never hit), then the overflow rows (a span's
+        # rows from its K-th on) padded to their bucket, their owners in
+        # `sattr.over` (n_spans_b = no span: the kernel drops it). The
+        # kernel ORs over the planes and scatters only the overflow
+        # (ops/filter._cond_mask): no cumsum over attribute rows, no
+        # span-length gather.
+        owners = np.clip(host.pop("sattr.span") - span_base, 0, max(n_spans, 1) - 1)
+        cnt = np.bincount(owners, minlength=max(n_spans, 1))
+        n_rows = int(owners.shape[0])
+        k = _head_planes(cnt, n_rows, n_spans_b)
+        # where each row goes: its span's place in the plane of its slot
+        # (rows are grouped by owner: the j-th row of a span is slot j),
+        # or the next free overflow row
+        slot = np.arange(n_rows, dtype=np.int32) - np.repeat(
+            (np.cumsum(cnt) - cnt).astype(np.int32), cnt)
+        dest = slot.astype(np.intp) * n_spans_b + owners
+        over = np.flatnonzero(slot >= k)
+        n_over = int(over.shape[0])
+        over_b = bucket(n_over) if n_over else 0
+        dest[over] = k * n_spans_b + np.arange(n_over)
+        for name in [n for n in host if n.startswith("sattr.")]:
+            arr = host[name]
+            host[name] = np.full(k * n_spans_b + over_b, PAD_I32, dtype=arr.dtype)
+            host[name][dest] = arr
+            real_rows[name] = n_rows
+        real_rows["sattr.over"] = n_over
+        host["sattr.over"] = pad_rows(owners[over], over_b, n_spans_b)
     if "rattr.res" in host:
         owners = np.clip(host["rattr.res"], 0, max(n_res, 1) - 1)
         cnt = np.bincount(owners, minlength=max(n_res, 1)) if owners.size else np.zeros(
@@ -538,14 +580,12 @@ def _assemble(blk, plan, groups, host, n_res):
             # rows collapse to empty segments (count 0)
             arr = (np.clip(arr, span_base, span_hi) - span_base).astype(np.int32)
             arr = pad_rows(arr, n_traces_b + 1, arr[-1] if arr.size else 0)
-        elif name in ("sattr.off", "rattr.off"):
+        elif pref == "sattr" or name == "rattr.off":
             pass  # already padded above
         elif name == "trace@gkey_s":
             arr = pad_rows(arr, n_traces_b, np.int32(-(2**31)))
         elif pref == "span":
             arr = pad_rows(arr, n_spans_b, PAD_I32)
-        elif pref == "sattr":
-            arr = pad_rows(arr, bucket(max(arr.shape[0], 1)), PAD_I32)
         elif pref == "rattr":
             arr = pad_rows(arr, bucket(max(arr.shape[0], 1)), PAD_I32)
         elif pref == "res":
@@ -567,9 +607,12 @@ def upload_stage(blk: BackendBlock, plan: StagePlan, staged: StagedBlock,
     """The host->device phase: one batched transfer + the query-
     independent res->span materialization. Adds to what `staged.cols`
     already holds (stage_block seeds it with the resident columns, so a
-    missing `span@X` gathers from a resident `X` or `span.res_idx`)."""
+    missing `span@X` gathers from a resident `X` or `span.res_idx`, and
+    what is resident is not sent again: the `sattr.over` of owners read
+    only to place a value column staged later)."""
     from ..util.kerneltel import TEL
 
+    padded = {n: a for n, a in padded.items() if n not in staged.cols}
     nbytes = sum(int(a.nbytes) for a in padded.values())
     # THE host->device transfer, whether a warm staging miss or a
     # stream-pipeline unit (whose `stream:upload` stage is around this
@@ -582,7 +625,7 @@ def upload_stage(blk: BackendBlock, plan: StagePlan, staged: StagedBlock,
     # summed per column -- columns live on different axes)
     TEL.record_transfer(
         nbytes,
-        sum(real_rows.values()),
+        sum(real_rows[n] for n in padded),
         sum(int(a.shape[0]) for a in padded.values()),
     )
 

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .device import bucket, pad_rows, scoped
-from .filter import Cond, Operands, T_TRACE, _cmp, _cond_mask
+from .filter import Cond, Operands, T_TRACE, _cmp, _cond_mask, attr_reduce_route
 from .hostfilter import eval_span_mask_host
 
 
@@ -152,7 +152,8 @@ def eval_timeseries_device(query, staged, operands: Operands,
     with TEL.launch(
         "timeseries",
         ("ts", tree, conds, table_idxs, has_val, staged.n_spans_b,
-         staged.n_res_b, staged.n_traces_b, G_b, B_b),
+         staged.n_res_b, staged.n_traces_b, G_b, B_b,
+         attr_reduce_route(conds, staged.cols)),
         staged.n_spans_b,
         cost=lambda: costmodel.spec(fn, staged.cols, operands.ints,
                                     operands.floats, tabs, gid_p, val_p,

@@ -220,7 +220,7 @@ def test_shards_share_range_free_columns():
         assert shard.cols[name] is not whole.cols[name], name
     _assert_same(shard, stage_block(blk, needed, SHARD, cache=False))
     keys = stage.column_keys(blk, needed + ["sattr.span", "rattr.res"], SHARD)
-    assert keys["sattr.span"] == ("sattr.off", tuple(SHARD))
+    assert keys["sattr.span"] == ("sattr.over", tuple(SHARD))
     assert keys["rattr.res"] == ("rattr.off", None)
     assert keys["span@res.service_id"] == ("span@res.service_id", tuple(SHARD))
     assert keys["trace@gkey_s"] == ("trace@gkey_s", None)
@@ -425,3 +425,210 @@ def test_is_staged_agrees_with_the_routes(route):
             window, [(cold, _SEARCHES["duration_gt"], None)],
             refused=lambda blk, req, groups, planned: plans.append(planned))
         assert len(taken) == 1 and plans[0].conds == item.planned.conds
+
+
+# ------------------- (f) sattr.* slot-major: K planes, then overflow rows
+
+_ATTR_NEEDED = ["sattr.span", "sattr.key_id", "sattr.vtype", "sattr.str_id",
+                "span.trace_sid", "trace.span_off"]
+_SATTR_VALUES = [n for n in _ATTR_NEEDED if n.startswith("sattr.")][1:]
+
+
+def _open_uniform(monkeypatch, attrs_per_span=2):
+    """The benchmark corpus's shape (util/testdata.synth_block: every span
+    owns `attrs_per_span` rows), cut into small row groups."""
+    from tempo_tpu.block import schema as S
+    from tempo_tpu.util.testdata import synth_block
+
+    monkeypatch.setattr(S, "DEFAULT_ROW_GROUP_SPANS", 300)
+    backend = MemBackend()
+    meta, _ = synth_block(backend, TENANT, np.random.default_rng(7), 100, 12,
+                          n_res=16, attrs_per_span=attrs_per_span)
+    blk = open_block(backend, TENANT, meta.block_id)
+    assert blk.pack.axes["span"].n_groups >= 4
+    return blk
+
+
+def _open_skewed():
+    """make_traces' block with one span owning 40 attribute rows (in the
+    shard's second row group)."""
+    traces = make_traces(120, seed=41, n_spans=10)
+    _, _, sp = list(traces[60][1].all_spans())[3]
+    sp.attrs.update({f"extra{i}": f"v{i}" for i in range(35)})
+    backend = MemBackend()
+    meta = build_block_from_traces(backend, TENANT, traces, row_group_spans=256)
+    blk = open_block(backend, TENANT, meta.block_id)
+    owners = blk.pack.read_groups("sattr.span", SHARD)
+    assert np.bincount(owners).max() == 40
+    return blk
+
+
+def _attr_reduce_rows():
+    return {k[1:]: n for k, n in TEL.routing_counts().items()
+            if k[0] == "attr_reduce"}
+
+
+def _launch_attr_eq(blk, st, query=None):
+    """`{ span.<k> = "<v>" }`, by default for the block's first string
+    attribute row (util/testdata.synth_columns names its own keys)."""
+    if query is None:
+        row = int(np.flatnonzero(blk.pack.read("sattr.vtype") == 0)[0])
+        k, v = (blk.dictionary.string(int(blk.pack.read(c)[row]))
+                for c in ("sattr.key_id", "sattr.str_id"))
+        query = f'{{ span.{k} = "{v}" }}'
+    p = _plan_for_block(blk, SearchRequest(query=query))
+    assert any(c.target == "sattr" for c in p.conds)
+    return eval_block((p.tree, p.conds), st.cols,
+                      Operands.build(p.rows, p.tables or None), st.n_spans,
+                      st.n_traces, st.n_spans_b, st.n_res_b, st.n_traces_b,
+                      span_out=False)
+
+
+def _slice_rows(blk, groups):
+    """-> (group list, owners rebased to the slice, its span count)."""
+    span_ax = blk.pack.axes["span"]
+    glist = groups or list(range(span_ax.n_groups))
+    base = span_ax.offsets[glist[0]]
+    return (glist, blk.pack.read_groups("sattr.span", glist) - base,
+            span_ax.offsets[glist[-1] + 1] - base)
+
+
+@pytest.mark.parametrize("groups", [None, SHARD], ids=["block", "shard"])
+def test_uniform_counts_leave_no_overflow(groups, monkeypatch):
+    """Two attributes on every span: two planes of n_spans_b, plane j =
+    every span's j-th row, no overflow row and no offsets column staged
+    or resident, no more bytes than the flat rows took, and the launch
+    says `slots dense_counts`."""
+    blk = _open_uniform(monkeypatch)
+    view = stage_block(blk, _ATTR_NEEDED, groups)
+    assert "sattr.off" not in view.cols and "sattr.span" not in view.cols
+    assert view.cols["sattr.over"].shape == (0,)
+    glist, owners, n_spans = _slice_rows(blk, groups)
+    assert n_spans == view.n_spans
+    for n in _SATTR_VALUES:
+        raw = blk.pack.read_groups(n, glist)
+        got = np.asarray(view.cols[n]).reshape(2, view.n_spans_b)
+        np.testing.assert_array_equal(got[:, :n_spans], raw.reshape(-1, 2).T)
+        assert (got[:, n_spans:] == stage.PAD_I32).all()
+        assert got.size <= stage.bucket(raw.shape[0])
+    gkey = tuple(groups) if groups else None
+    keys = stage.column_keys(blk, _ATTR_NEEDED, groups)
+    assert keys["sattr.span"] == ("sattr.over", gkey)
+    assert all(keys[n] == (n, gkey) for n in _SATTR_VALUES)
+    assert is_staged(blk, _ATTR_NEEDED, groups)
+    before = _attr_reduce_rows()
+    out = _launch_attr_eq(blk, view)
+    after = _attr_reduce_rows()
+    assert after.get(("slots", "dense_counts"), 0) == before.get(
+        ("slots", "dense_counts"), 0) + 1
+    assert sum(after.values()) == sum(before.values()) + 1
+    # the same answer with every row an overflow row
+    monkeypatch.setattr(stage, "_head_planes", lambda *a: 0)
+    rows = stage_block(blk, _ATTR_NEEDED, groups, cache=False)
+    assert rows.cols["sattr.over"].shape == rows.cols["sattr.key_id"].shape
+    for a, b in zip(out, _launch_attr_eq(blk, rows)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("groups", [None, SHARD], ids=["block", "shard"])
+def test_a_long_span_overflows_alone(groups):
+    """One span with 40 attributes: the slice keeps as many planes as its
+    flat rows' bucket holds, only the rows beyond a span's K-th are
+    overflow rows (in row order, with their owners), and the launch says
+    `slots skewed_counts` and answers as the host twin does."""
+    from tempo_tpu.ops.hostfilter import eval_block_host
+
+    blk = _open_skewed()
+    view = stage_block(blk, _ATTR_NEEDED, groups)
+    glist, owners, n_spans = _slice_rows(blk, groups)
+    cnt = np.bincount(owners, minlength=n_spans)
+    k = stage.bucket(owners.shape[0]) // view.n_spans_b
+    assert 1 <= k < 40 == cnt.max()
+    slot = np.arange(owners.shape[0]) - np.repeat(np.cumsum(cnt) - cnt, cnt)
+    n_over = int((slot >= k).sum())
+    over_b = stage.bucket(n_over)
+    assert 40 - k <= n_over < owners.shape[0] // 2
+    over = np.asarray(view.cols["sattr.over"])
+    np.testing.assert_array_equal(over[:n_over], owners[slot >= k])
+    assert over.shape == (over_b,) and (over[n_over:] == view.n_spans_b).all()
+    for n in _SATTR_VALUES:
+        raw = blk.pack.read_groups(n, glist)
+        got = np.asarray(view.cols[n])
+        assert got.shape == (k * view.n_spans_b + over_b,)
+        planes = got[:k * view.n_spans_b].reshape(k, view.n_spans_b)
+        for j in range(k):
+            np.testing.assert_array_equal(planes[j, owners[slot == j]], raw[slot == j])
+        assert (planes == stage.PAD_I32).sum() == planes.size - (slot < k).sum()
+        np.testing.assert_array_equal(got[k * view.n_spans_b:][:n_over], raw[slot >= k])
+        assert (got[k * view.n_spans_b + n_over:] == stage.PAD_I32).all()
+    before = _attr_reduce_rows()
+    p = _plan_for_block(blk, SearchRequest(query='{ span.extra34 = "v34" }'))
+    operands = Operands.build(p.rows, p.tables or None)
+    tm, cnt_t = eval_block((p.tree, p.conds), view.cols, operands, view.n_spans,
+                           view.n_traces, view.n_spans_b, view.n_res_b,
+                           view.n_traces_b, span_out=False)
+    after = _attr_reduce_rows()
+    assert after.get(("slots", "skewed_counts"), 0) == before.get(
+        ("slots", "skewed_counts"), 0) + 1
+    assert sum(after.values()) == sum(before.values()) + 1
+    host = {n: blk.pack.read_groups(n, glist) for n in _ATTR_NEEDED
+            if not n.startswith("trace.")}
+    host["sattr.span"] = owners
+    span_off = blk.pack.read("trace.span_off")
+    base = blk.pack.axes["span"].offsets[glist[0]]
+    host["trace.span_off"] = (np.clip(span_off, base, base + n_spans) - base).astype(np.int32)
+    tm_h, cnt_h = eval_block_host((p.tree, p.conds), host, operands, n_spans,
+                                  view.n_traces)
+    assert tm_h.sum() == 1  # the long span's trace, through an overflow row
+    np.testing.assert_array_equal(np.asarray(tm)[:view.n_traces], tm_h)
+    np.testing.assert_array_equal(np.asarray(cnt_t)[:view.n_traces], cnt_h)
+
+
+@pytest.mark.parametrize("counts", ["uniform", "skewed"])
+def test_later_value_column_joins_the_slice_layout(counts, monkeypatch):
+    """A value column staged when the others are resident is placed by
+    the slice's owners again and lands in the same slots and overflow
+    rows; the owners' own column is resident and is not sent again: the
+    view equals staging everything at once."""
+    blk = _open_uniform(monkeypatch) if counts == "uniform" else _open_skewed()
+    first = stage_block(blk, _ATTR_NEEDED, SHARD)
+    assert (first.cols["sattr.over"].shape[0] > 0) == (counts == "skewed")
+    reads = _record_reads(monkeypatch)
+    s0 = _staging()
+    view = stage_block(blk, _ATTR_NEEDED + ["sattr.int32"], SHARD)
+    s1 = _staging()
+    assert [sorted(r) for r in reads] == [["sattr.int32", "sattr.span"]]
+    assert s1["column_misses"] - s0["column_misses"] == 1
+    assert s1["transfer_bytes_total"] - s0["transfer_bytes_total"] == (
+        view.cols["sattr.int32"].nbytes)
+    assert view.cols["sattr.int32"].shape == view.cols["sattr.key_id"].shape
+    assert all(view.cols[n] is first.cols[n] for n in first.cols)
+    _assert_same(view, stage_block(blk, _ATTR_NEEDED + ["sattr.int32"], SHARD,
+                                   cache=False))
+    assert is_staged(blk, _ATTR_NEEDED + ["sattr.int32"], SHARD)
+
+
+def test_slot_major_columns_come_back_from_the_pool(monkeypatch):
+    """Evicted slot-major columns, the empty and the filled overflow
+    owners among them, are demoted to the host pool under the keys they
+    were staged by and restaged from it as the arrays they were."""
+    monkeypatch.setenv("TEMPO_CHUNK_CACHE", "1")
+    monkeypatch.setenv("TEMPO_CHUNK_CACHE_MIN_REUSE", "1")
+    for blk in (_open_uniform(monkeypatch), _open_skewed()):
+        # asking about a slice nobody staged does no IO
+        bytes_read = blk.pack.bytes_read
+        assert not is_staged(blk, _ATTR_NEEDED, SHARD)
+        assert blk.pack.bytes_read == bytes_read
+        view = stage_block(blk, _ATTR_NEEDED, SHARD)
+        d0 = chunkpool.stats()
+        stage.set_staged_cache_budget(1)
+        stage.set_staged_cache_budget(4 << 30)
+        assert not is_staged(blk, _ATTR_NEEDED, SHARD)
+        gone = [k for k in stage.column_keys(blk, _ATTR_NEEDED, SHARD).values()
+                if k not in blk._staged_cache]  # an empty column costs nothing
+        assert len(gone) >= len(view.cols) - 1
+        assert chunkpool.stats()["demotions"] - d0["demotions"] >= len(gone)
+        reads = _record_reads(monkeypatch)
+        again = stage_block(blk, _ATTR_NEEDED, SHARD)
+        assert not reads and chunkpool.stats()["hits"] - d0["hits"] == len(gone)
+        _assert_same(again, view)
