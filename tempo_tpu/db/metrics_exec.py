@@ -474,9 +474,10 @@ def _value_column(blk: BackendBlock, expr) -> tuple[np.ndarray, np.ndarray] | No
 
 
 def _check_cardinality(n_groups: int, nb: int) -> None:
-    from ..ops.device import bucket
+    from ..ops.timeseries import acc_shape
 
-    if bucket(max(n_groups, 1)) * bucket(max(nb, 1)) > MAX_ACC_CELLS:
+    g_b, b_b = acc_shape(n_groups, nb)
+    if g_b * b_b > MAX_ACC_CELLS:
         raise ValueError(
             f"metrics series cardinality too high: {n_groups} groups x "
             f"{nb} buckets exceeds the accumulator budget; narrow the "

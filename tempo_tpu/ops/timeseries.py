@@ -5,9 +5,22 @@ predicate tree (the same data-driven condition machinery as ops/filter,
 so `{span.foo = "bar"} | rate()` and `{span.foo = "baz"} | rate()`
 share a compiled program), bucketize each surviving span's start time
 onto the request's step-aligned axis, and fold into
-`[num_groups, num_buckets]` accumulators with one segment reduce over a
-combined (group, bucket) index -- the same combined-index trick the
-span-metrics generator reduce uses (ops/reduce.py histogram scatter).
+`[num_groups, num_buckets]` accumulators over a combined (group,
+bucket) cell index. The accumulator's padded shape follows the request
+(acc_shape), and its size alone decides how the fold runs (_fold):
+
+- at most DENSE_MAX_CELLS cells (a panel's `rate()`, `by (service)`
+  over an hour): a dense histogram -- every row compared with every
+  cell and reduced over the span axis, one fused pass a statistic; no
+  scatter, and no [rows, cells] array ever exists;
+- above it (`by (name)` at full width): one segment reduce a statistic
+  over the combined index, the same trick the span-metrics generator
+  reduce uses (ops/reduce.py histogram scatter), at bucket()'s padding.
+
+A scatter costs ~9 ns a row on a v5e however few the cells (2^24 rows:
+147 ms into 65 cells as into 1,048,577), a dense fold costs by rows x
+cells (the whole 1 x 64 program 3.4 ms, 4,096 cells 74): PERF.md
+section 6 has the crossover.
 
 Only the tree/condition STRUCTURE and the padded (groups, buckets)
 shapes key the jit compile; operand values, group ids, value columns
@@ -40,6 +53,55 @@ import numpy as np
 from .device import bucket, pad_rows, scoped
 from .filter import Cond, Operands, T_TRACE, _cmp, _cond_mask, attr_reduce_route
 from .hostfilter import eval_span_mask_host
+
+
+# Largest accumulator (padded groups x padded buckets) the fold reduces
+# densely; one more cell and it scatters. Static shape alone decides.
+# At n_spans_b 2^24 on a v5e the dense count program takes 74 ms at 2^12
+# cells and 530 at 2^14 against the scatter's 149; with a value fold 340
+# at 2^12 against 731 (PERF.md section 6, PR 32).
+DENSE_MAX_CELLS = 1 << 12
+_DENSE_MIN_BUCKETS = 64  # a panel's hour at step 60 s is 60-61 buckets
+
+
+def fold_route(n_cells: int) -> tuple[str, str]:
+    """(engine, reason) of the `ts_fold` routing row: how _fold reduces
+    into a padded accumulator of `n_cells`."""
+    if n_cells <= DENSE_MAX_CELLS:
+        return "dense", "small_acc"
+    return "scatter", "large_acc"
+
+
+def acc_shape(n_groups: int, n_buckets: int) -> tuple[int, int]:
+    """Padded (G_b, B_b) of a request's accumulator -- what keys the
+    compile, what db/metrics_exec._check_cardinality caps. Powers of two
+    from 1 x 64 while the fold stays dense; past that bucket()'s
+    padding, the shapes the scatter programs always had."""
+    g = 1 << max(n_groups - 1, 0).bit_length()
+    b = max(_DENSE_MIN_BUCKETS, 1 << max(n_buckets - 1, 0).bit_length())
+    if fold_route(g * b)[0] == "dense":
+        return g, b
+    return bucket(n_groups), bucket(n_buckets)
+
+
+_FOLDS = {"sum": (jnp.sum, jax.ops.segment_sum, 0),
+          "min": (jnp.min, jax.ops.segment_min, np.inf),
+          "max": (jnp.max, jax.ops.segment_max, -np.inf)}
+
+
+def _fold(weights, seg, n_cells: int, kind: str):
+    """Reduce `weights` (sum / min / max) into `n_cells` cells by `seg`;
+    a row whose seg is n_cells lands nowhere. Small accumulators compare
+    every row with every cell and reduce over the span axis -- the
+    compiler fuses compare, select and reduce, so no [rows, cells] array
+    exists; large ones scatter."""
+    dense, scatter, identity = _FOLDS[kind]
+    if fold_route(n_cells)[0] == "scatter":
+        return scatter(weights, seg, num_segments=n_cells + 1)[:-1]
+    cells = jnp.arange(n_cells, dtype=jnp.int32)
+    hit = seg[None, :] == cells[:, None]
+    return dense(jnp.where(hit, weights[None, :],
+                           jnp.asarray(identity, weights.dtype)), axis=1)
 
 
 @lru_cache(maxsize=256)
@@ -84,24 +146,21 @@ def _compiled_ts(tree, conds: tuple[Cond, ...], table_idxs: tuple[int, ...],
         b = (cols["span.start_ms"] - t0_ms) // step_ms
         ok = sm & (b >= 0) & (b < n_buckets) & (gid >= 0)
         b32 = jnp.clip(b, 0, B_b - 1).astype(jnp.int32)
-        seg = jnp.where(ok, gid * B_b + b32, G_b * B_b)
-        nseg = G_b * B_b + 1
-        counts = jax.ops.segment_sum(ok.astype(jnp.int32), seg,
-                                     num_segments=nseg)[:-1].reshape(G_b, B_b)
+        n_cells = G_b * B_b
+        seg = jnp.where(ok, gid * B_b + b32, n_cells)
+
+        def fold(weights, segs, kind):
+            return _fold(weights, segs, n_cells, kind).reshape(G_b, B_b)
+
+        counts = fold(ok.astype(jnp.int32), seg, "sum")
         if not has_val:
             return (counts,)
         pres = ok & vpres
-        segv = jnp.where(pres, seg, G_b * B_b)
-        vcnt = jax.ops.segment_sum(pres.astype(jnp.int32), segv,
-                                   num_segments=nseg)[:-1].reshape(G_b, B_b)
-        v = jnp.where(pres, val, jnp.float32(0))
-        vsum = jax.ops.segment_sum(v, segv, num_segments=nseg)[:-1].reshape(G_b, B_b)
-        vmin = jax.ops.segment_min(
-            jnp.where(pres, val, jnp.float32(jnp.inf)), segv,
-            num_segments=nseg)[:-1].reshape(G_b, B_b)
-        vmax = jax.ops.segment_max(
-            jnp.where(pres, val, jnp.float32(-jnp.inf)), segv,
-            num_segments=nseg)[:-1].reshape(G_b, B_b)
+        segv = jnp.where(pres, seg, n_cells)
+        vcnt = fold(pres.astype(jnp.int32), segv, "sum")
+        vsum = fold(jnp.where(pres, val, jnp.float32(0)), segv, "sum")
+        vmin = fold(jnp.where(pres, val, jnp.float32(jnp.inf)), segv, "min")
+        vmax = fold(jnp.where(pres, val, jnp.float32(-jnp.inf)), segv, "max")
         return counts, vcnt, vsum, vmin, vmax
 
     return run
@@ -128,7 +187,7 @@ def eval_timeseries_device(query, staged, operands: Operands,
     Returns numpy accumulators clipped to (n_groups, n_buckets):
     (counts,) or (counts, vcnt, vsum, vmin, vmax)."""
     tree, conds = query
-    G_b, B_b = bucket(max(n_groups, 1)), bucket(max(n_buckets, 1))
+    G_b, B_b = acc_shape(n_groups, n_buckets)
     table_idxs, tabs = _table_list(operands)
     has_val = val is not None
     fn = _compiled_ts(tree, conds, table_idxs, has_val,
@@ -149,10 +208,12 @@ def eval_timeseries_device(query, staged, operands: Operands,
     t0_i = np.int32(t0)
     step_i = np.int32(max(1, step_ms))
     ns_i, nb_i = np.int32(staged.n_spans), np.int32(n_buckets)
+    route = fold_route(G_b * B_b)
+    TEL.record_routing("ts_fold", *route)
     with TEL.launch(
         "timeseries",
         ("ts", tree, conds, table_idxs, has_val, staged.n_spans_b,
-         staged.n_res_b, staged.n_traces_b, G_b, B_b,
+         staged.n_res_b, staged.n_traces_b, G_b, B_b, route,
          attr_reduce_route(conds, staged.cols)),
         staged.n_spans_b,
         cost=lambda: costmodel.spec(fn, staged.cols, operands.ints,

@@ -329,17 +329,45 @@ def test_no_span_length_gather_at_the_cells_buckets(program):
     assert ("cumsum", A_B) in flat_ops
 
 
-def test_rate_program_reduces_attributes_without_a_gather():
-    """`{ span.k = v } | rate()` gets the same span mask: the slot-major
-    program's only span-length scatter is the fold this PR leaves alone
-    (ROADMAP A15 ii)."""
+def _rate_jaxpr(has_val: bool, G_b: int, B_b: int):
+    """`{ span.k = v } | rate()` (or a value fold) over the cells' shard
+    slice at a padded accumulator shape."""
     conds = (Cond(T_SATTR, "str", "eq"),)
     cols = _abstract_cols(0, ("span.start_ms",))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
-    fn = _compiled_ts(("cond", 0), conds, (), False, S_B, R_B, T_B, 1024, 1024)
-    jaxpr = jax.make_jaxpr(fn)(
+    fn = _compiled_ts(("cond", 0), conds, (), has_val, S_B, R_B, T_B, G_b, B_b)
+    val, pres = ((jax.ShapeDtypeStruct((S_B,), np.float32),
+                  jax.ShapeDtypeStruct((S_B,), np.bool_)) if has_val
+                 else (np.zeros(0, np.float32),) * 2)
+    return jax.make_jaxpr(fn)(
         cols, i32(1, 3), jax.ShapeDtypeStruct((1, 2), np.float32), [],
-        i32(S_B), np.zeros(0, np.float32), np.zeros(0, np.float32),
-        i32(), i32(), i32(), i32()).jaxpr
-    ops = _span_length_ops(jaxpr)
+        i32(S_B), val, pres, i32(), i32(), i32(), i32()).jaxpr
+
+
+def test_rate_program_reduces_attributes_without_a_gather():
+    """`{ span.k = v } | rate()` gets the same span mask: at bucket()'s
+    1024 x 1024 the slot-major program's only span-length scatter is the
+    fold's, the program every request ran before the accumulator's
+    shape followed it (ROADMAP A15 ii)."""
+    ops = _span_length_ops(_rate_jaxpr(False, 1024, 1024))
     assert [o[0] for o in ops] == ["scatter-add"]
+
+
+@pytest.mark.parametrize("has_val", [False, True], ids=["count", "value"])
+@pytest.mark.parametrize("shape", [(1, 64), (4, 64), (64, 64), (1, 4096)],
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+def test_small_accumulator_folds_without_a_scatter(shape, has_val):
+    """A request-sized accumulator (`rate_service` is 1 x 64) is folded by
+    compares and reductions over the span axis: no scatter, gather or
+    sort anywhere in the program, and one reduction a statistic."""
+    jaxpr = _rate_jaxpr(has_val, *shape)
+    assert _span_length_ops(jaxpr) == []
+    text = str(jaxpr)
+    assert not any(p in text for p in ("scatter", "gather", "sort", "cumsum"))
+    n_stats = 5 if has_val else 1
+    assert sum(text.count(p) for p in ("reduce_sum", "reduce_min", "reduce_max")) == n_stats
+    # the value folds at bucket()'s shape: five scatters, as before
+    if has_val and shape == (1, 64):
+        big = _span_length_ops(_rate_jaxpr(True, 1024, 1024))
+        assert sorted(o[0] for o in big) == sorted(
+            ["scatter-add"] * 3 + ["scatter-min", "scatter-max"])
