@@ -132,24 +132,52 @@ def stage_bytes_est(blk: BackendBlock, p, groups_range=None) -> int:
 _TRES_SCAN_BYTES_PER_TRACE = 4 * 12
 
 
-def scan_bytes_est(blk: BackendBlock, p, groups_range=None, *, group: bool = False) -> int:
-    """What a host scan of the job would have to read: nothing when its
-    columns sit in the array cache (they scan at memory speed), the tres
-    axis for a res/trace-only tree, else the span-axis columns."""
+def _tres_bytes(blk: BackendBlock) -> int:
+    return blk.meta.total_traces * _TRES_SCAN_BYTES_PER_TRACE
+
+
+def _host_cached(blk: BackendBlock, cols) -> bool:
+    """Every column a host scan would read sits in the array cache."""
+    return all(blk.pack.has_cached_array(n) for n in cols if blk.pack.has(n))
+
+
+def scan_bytes_est(blk: BackendBlock, p, groups_range=None) -> int:
+    """What a host scan of a one-block job (search_block) would have to
+    read: the tres axis for a res/trace-only tree, nothing when its
+    columns sit in the array cache (they scan at memory speed), else the
+    span-axis columns."""
     cols, tres = host_plan(blk, p, groups_range)
-    tres_bytes = blk.meta.total_traces * _TRES_SCAN_BYTES_PER_TRACE
-    # the fused engine's sum (group) differs from search_block's estimate
-    # in two places, kept until a cell shows either side (C3): there a
-    # cached block is free even on the tres axis, and the span columns
-    # counted are the ones the host reads (no span.trace_sid)
-    if tres and not group:
-        return tres_bytes
-    if all(blk.pack.has_cached_array(n) for n in cols if blk.pack.has(n)):
-        return 0
     if tres:
-        return tres_bytes
-    return (job_rows(blk, None) * 4 * _span_axis_cols(cols) if group
-            else stage_bytes_est(blk, p, groups_range))
+        return _tres_bytes(blk)
+    if _host_cached(blk, cols):
+        return 0
+    return stage_bytes_est(blk, p, groups_range)
+
+
+# What the numpy engine sustains over a whole block whose columns sit in
+# the host array cache, in the bytes fused_host_ms counts. One rate for
+# every plan, set between what FOUR threads scanning at once (a served
+# process has that many search clients in one interpreter) sustained on
+# the chip machine's host over 1.29 M-span blocks: 2.75e9 and 3.76e9 B/s
+# for span-axis scans (an attribute equality, a duration bound), 0.57e9
+# for the tres axis of a tag search, whose cost is Python, not bytes
+# (alone: 2.98e9, 4.09e9, 3.42e9; PERF.md section 6, PR 34).
+_HOST_CACHED_RATE_BPS: float = 2.0e9
+
+
+def fused_host_ms(blk: BackendBlock, p) -> float:
+    """What a host scan of one whole block of a fused group is estimated
+    to take: the bytes the host engine reads (the tres axis for a
+    res/trace-only tree, else its span-axis columns: no span.trace_sid)
+    over the cold-scan EMA, or over the memory-speed rate when every
+    column sits in the array cache. A cached block is cheap, not free:
+    priced at nothing, a hot working set left the device for good once
+    one host scan had filled the cache (ROADMAP C3 ii)."""
+    cols, tres = host_plan(blk, p, None)
+    n_bytes = (_tres_bytes(blk) if tres
+               else job_rows(blk, None) * 4 * _span_axis_cols(cols))
+    rate = _HOST_CACHED_RATE_BPS if _host_cached(blk, cols) else _HOST_RATE_BPS
+    return n_bytes / rate * 1e3
 
 
 def _kept_hot(blk: BackendBlock) -> bool:
@@ -209,8 +237,7 @@ def route_fused(live: list[tuple[BackendBlock, object]]) -> list[Route] | None:
     if scanning every block on host is estimated cheaper than ONE device
     round trip, promotion is a loss however hot the blocks are. Every
     block counts a touch."""
-    prefer_host = _host_cheaper(
-        sum(scan_bytes_est(blk, p, group=True) for blk, p in live))
+    prefer_host = sum(fused_host_ms(blk, p) for blk, p in live) < link_rtt_ms()
     routes: list[Route] = []
     est = 0
     for blk, p in live:

@@ -33,7 +33,9 @@ from ..block import schema as S
 from ..block.reader import BackendBlock
 from ..ops.filter import Operands, eval_block, required_columns
 from ..ops.hostfilter import eval_block_host
+from ..ops.device import bucket
 from ..ops.select import (
+    group_rung,
     k_bucket,
     select_topk_device,
     select_topk_device_multi,
@@ -381,11 +383,13 @@ def _collect_topk(blk: BackendBlock, req: SearchRequest, planned,
     from ..util.kerneltel import TEL
 
     verify = _route_verify(req, planned)
-    with TEL.stage("topk:collect", block=blk.meta.block_id[:8], limit=limit):
+    with TEL.stage("topk:collect", block=blk.meta.block_id[:8], limit=limit,
+                   rounds=0) as st:
         k = min(k_bucket(max(2 * limit, 32)), nt)
         out: list = []
         seen: set[int] = set()
         while True:
+            st.attrs["rounds"] += 1  # selects run: 1 + how often k escalated
             sids, cnts, n_match = selector(k)
             fresh = [(int(s), int(c)) for s, c in zip(sids, cnts) if int(s) not in seen]
             seen.update(s for s, _ in fresh)
@@ -612,7 +616,11 @@ def search_blocks_fused(
     touches the device, and a hot working set costs ~one RTT per query
     regardless of block count -- the single-chip counterpart of the mesh
     program in parallel/search.py, and the production engine behind
-    TempoDB.search_blocks / the frontend's block-batch jobs.
+    TempoDB.search_blocks / the frontend's block-batch jobs. The
+    benchmark's `chip1-range-mix` cell (32 one-hour blocks, ranges of
+    1-24) runs it served: a `search_fused` routing row a block
+    (`fused_device_share`), stages `search:fused` and `topk:collect`
+    (`merge_ms_per_search`), the `select` launch (`select_ms_per_launch`).
 
     plans: one a block where the caller has made them (plan_job).
     Returns None only when the combined staged footprint of the
@@ -637,6 +645,18 @@ def search_blocks_fused(
         return None
     dev_items = [it for it, r in zip(live, routes) if r.engine == "device"]
     host_items = [it for it, r in zip(live, routes) if r.engine == "host"]
+
+    with TEL.stage("search:fused", blocks=len(live), device=len(dev_items),
+                   host=len(host_items)):
+        return _fused_eval(live, dev_items, host_items, req, pool, limit, resp)
+
+
+def _fused_eval(live, dev_items, host_items, req: SearchRequest, pool,
+                limit: int, resp: SearchResponse) -> SearchResponse:
+    """search_blocks_fused past its routing: the device blocks' kernels
+    and the host blocks' scans in one pool pass, the cross-block selects,
+    the merge."""
+    from ..util.kerneltel import TEL
 
     io0 = {id(blk): blk.pack.bytes_read for blk, _ in live}
     results: list[tuple] = []  # _candidates records until the final merge
@@ -801,14 +821,19 @@ def search_blocks_fused(
         cnts = [e[1] for e in evald]
         keys = [e[2] for e in evald]
         resp.inspected_spans += int(sum(e[3] for e in evald))
-        offsets = np.cumsum([0] + [int(t.shape[0]) for t in tms])
+        # the select's shape is the job's (its block count and largest
+        # trace bucket), never the router's split of it: a compile a
+        # blocklist reaches is one warm-up reaches (ops/select)
+        part_len = max(bucket(max(blk.meta.total_traces, 1)) for blk, _ in live)
+        offsets = np.arange(len(tms) + 1) * part_len
 
         def selector(k):
-            return select_topk_device_multi(tms, keys, cnts, k)
+            return select_topk_device_multi(tms, keys, cnts, k, len(live), part_len)
 
         results.extend(_collect_topk_multi(
             [blk for blk, _ in dev_items], [p for _, p in dev_items],
             offsets, req, selector, limit, materialize=False,
+            total=group_rung(len(live)) * part_len,
         ))
 
     # global merge over lightweight candidates; only the winning `limit`
@@ -830,22 +855,27 @@ def search_blocks_fused(
 
 
 def _collect_topk_multi(blocks, plans, offsets, req: SearchRequest, selector,
-                        limit: int, materialize: bool = True):
+                        limit: int, materialize: bool = True,
+                        total: int | None = None):
     """Escalating cross-block top-k collect: global winners map back to
     (block, sid) via the padded part offsets, then per-block exact
     verification + result building -- the multi-block twin of
-    _collect_topk (same materialize contract)."""
-    total = int(offsets[-1])
+    _collect_topk (same materialize contract). total: the rows the
+    selector ranks where that is more than the parts' (a device group
+    padded to its rung); it caps k."""
+    total = int(offsets[-1]) if total is None else total
     if total == 0:
         return []
     from ..util.kerneltel import TEL
 
     verify = [_route_verify(req, p) for p in plans]
-    with TEL.stage("topk:collect", blocks=len(blocks), limit=limit):
+    with TEL.stage("topk:collect", blocks=len(blocks), limit=limit,
+                   rounds=0) as st:
         k = min(k_bucket(max(2 * limit, 32)), total)
         out: list = []
         seen: set[int] = set()
         while True:
+            st.attrs["rounds"] += 1  # selects run: 1 + how often k escalated
             gids, gcnts, n_match = selector(k)
             per_block: dict[int, list[tuple[int, int]]] = {}
             fresh = 0
@@ -912,8 +942,6 @@ def _stacked_words_est(items, needed: list[str], tree, sp: int,
     parent/validity gather (+ pointer-doubling temps) per launch --
     the costmodel comm walker prices the same collectives on the wire
     and tests cross-check the two counts."""
-    from ..ops.device import bucket
-
     span_cols = [n for n in needed if n.startswith("span.")]
     est = S_b * max(1, len(span_cols))
     # trace-axis tables (span_off at NT_b+1 plus any trace.* conds) and

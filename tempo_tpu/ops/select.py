@@ -75,11 +75,17 @@ def select_topk_device(mask, key, counts, k: int):
     return sids[valid], cnts[valid], int(out[3 * k])
 
 
+def group_rung(n_blocks: int) -> int:
+    """Slots of a group's select: the power of two at or above its block
+    count (1, 2, 4, ... MAX_BLOCKS_PER_BATCH), so a blocklist's jobs reach
+    few programs whatever their sizes."""
+    return 1 << max(n_blocks - 1, 0).bit_length()
+
+
 @lru_cache(maxsize=64)
-def _compiled_select_multi(k: int, n_parts: int):
-    """Fused cross-block selection: concatenate per-block (mask, key,
-    count) vectors ON DEVICE and top-k once. n_parts is only a cache
-    discriminator; jax.jit itself re-specializes on the part shapes."""
+def _compiled_select_group(k: int, rung: int, part_len: int):
+    """Fused cross-block selection: concatenate `rung` per-block (mask,
+    key, count) vectors of `part_len` ON DEVICE and top-k once."""
 
     @jax.jit
     @scoped("select")
@@ -100,27 +106,49 @@ def _compiled_select_multi(k: int, n_parts: int):
     return sel
 
 
-def select_topk_device_multi(masks, keys, counts, k: int):
+@lru_cache(maxsize=16)
+def _absent_part(part_len: int):
+    """(mask, key, count) of a slot no device block fills: nothing matches."""
+    return (jnp.zeros(part_len, jnp.bool_), jnp.zeros(part_len, jnp.int32),
+            jnp.zeros(part_len, jnp.int32))
+
+
+@lru_cache(maxsize=64)
+def _compiled_widen(part_len: int):
+    """A block's vector brought to its group's part length (a group whose
+    blocks' trace buckets differ): zeros behind, which match nothing."""
+    return jax.jit(lambda x: jnp.pad(x, (0, part_len - x.shape[0])))
+
+
+def select_topk_device_multi(masks, keys, counts, k: int, slots: int,
+                             part_len: int):
     """Top-k across MANY blocks' device mask/key/count vectors in one
     fused program -> ONE device sync for the whole multi-block query.
-    Returns (global_idx desc-by-key, counts at winners, total n_match);
-    global_idx indexes the concatenation of the (padded) parts -- the
-    caller maps it back to (block, sid) with the part offsets."""
+
+    The program's shape is the GROUP's, not the parts': `slots` is the
+    number of blocks of the job (device- and host-routed alike) and
+    `part_len` its largest trace bucket, so the compile key is (k,
+    group_rung(slots), part_len) whichever blocks the router sent to the
+    device for this query; slots no part fills are masked out. Returns
+    (global_idx desc-by-key, counts at winners, total n_match);
+    global_idx // part_len is the part, global_idx % part_len its sid."""
 
     from ..util.kerneltel import TEL
 
-    total = int(sum(m.shape[0] for m in masks))
-    k = int(min(k, total))
+    rung = group_rung(slots)
+    k = int(min(k, rung * part_len))
+    widen = _compiled_widen(part_len)
+    parts = [tuple(x if x.shape[0] == part_len else widen(x) for x in part)
+             for part in zip(masks, keys, counts)]
+    parts += [_absent_part(part_len)] * (rung - len(parts))
+    masks, keys, counts = (tuple(col) for col in zip(*parts))
     from ..util import costmodel
 
-    sel = _compiled_select_multi(k, len(masks))
+    sel = _compiled_select_group(k, rung, part_len)
     with TEL.launch(
-        "select", ("selN", k, tuple(int(m.shape[0]) for m in masks)), k,
-        cost=lambda: costmodel.spec(
-            sel, tuple(masks), tuple(keys), tuple(counts))):
-        out = np.asarray(
-            sel(tuple(masks), tuple(keys), tuple(counts))
-        )
+        "select", ("selN", k, rung, part_len), k,
+        cost=lambda: costmodel.spec(sel, masks, keys, counts), rung=rung):
+        out = np.asarray(sel(masks, keys, counts))
     gids, cnts, valid = out[:k], out[k : 2 * k], out[2 * k : 3 * k] > 0
     return gids[valid], cnts[valid], int(out[3 * k])
 

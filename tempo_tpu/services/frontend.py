@@ -350,6 +350,8 @@ class _Job:
     # monotonic stamp its steal clock runs from (set at first enqueue),
     # and the dequeue outcome ("own"/"steal"/"unowned") it executed under
     affinity_key: str | None = None
+    # what the job's `job:<kind>` self-trace span says of its size
+    span_attrs: dict = field(default_factory=dict)
     queued_at: float = 0.0
     placement: str = ""
     # resilience plane: the query-wide retry budget this job draws
@@ -509,7 +511,7 @@ class Frontend:
             if not (j.started_wall and j.done_at):
                 continue
             attrs = {"cancelled": j.cancelled, "hedged": j.hedged,
-                     "error": j.error is not None}
+                     "error": j.error is not None, **j.span_attrs}
             if j.hedge_outcome:
                 attrs["hedge"] = j.hedge_outcome  # win | lose | unneeded
             if j.tries:
@@ -1430,6 +1432,7 @@ class Frontend:
                                tuple(m.block_id for m in part)),
                     batch_fn=self._batch_search_blocks,
                     affinity_key=part[0].block_id,
+                    span_attrs={"blocks": len(part), "bytes": batch_bytes},
                 ))
                 batch, batch_bytes = [], 0
 
@@ -1453,6 +1456,9 @@ class Frontend:
             batch.append(m)
             batch_bytes += size
         flush_batch()
+        from ..util.kerneltel import TEL
+
+        TEL.record_range(len(metas), [j.span_attrs.get("blocks", 1) for j in jobs[1:]])
         return jobs
 
     def _search(self, tenant: str, req: SearchRequest, trace=None) -> SearchResponse:
@@ -1474,6 +1480,8 @@ class Frontend:
 
     def _search_exec(self, tenant: str, req: SearchRequest,
                      trace=None) -> SearchResponse:
+        from ..util.kerneltel import TEL
+
         limit = req.limit or 20
         resp = SearchResponse()
         lock = threading.Lock()
@@ -1498,11 +1506,13 @@ class Frontend:
 
             def collect():
                 t0_merge = time.time()
+                token = TEL.set_active_trace(trace)  # this thread's stages
                 for j in jobs:
                     j.done.wait()
                     if j.error is None and j.result is not None:
-                        with lock:
+                        with TEL.stage("search:merge", jobs=len(jobs)), lock:
                             resp.merge(j.result, limit)
+                TEL.reset_active_trace(token)
                 if trace is not None:
                     # the cross-shard merge leg of the timeline
                     trace.child("merge", t0_merge, time.time(),
@@ -1520,8 +1530,14 @@ class Frontend:
             trace.add_cost("bytes_scanned", sum(
                 j.result.inspected_bytes for j in jobs
                 if j.error is None and j.result is not None))
-        resp.traces.sort(key=lambda r: -r.start_time_unix_nano)
-        resp.traces = resp.traces[:limit]
+        token = TEL.set_active_trace(trace)
+        try:
+            with TEL.stage("search:merge", jobs=len(jobs),
+                           cancelled=sum(j.cancelled for j in jobs)):
+                resp.traces.sort(key=lambda r: -r.start_time_unix_nano)
+                resp.traces = resp.traces[:limit]
+        finally:
+            TEL.reset_active_trace(token)
         return resp
 
     # ------------------------------------------------- progressive search
@@ -1760,14 +1776,21 @@ class Frontend:
                 # placed like a search of the first block the sub-range
                 # covers: whole-block columns then stage where that
                 # block's other columns already live
-                lead = next((m.block_id for m in metas if m.overlaps_time(
-                    sub.start_ms // 1000, -(-sub.end_ms // 1000))), None)
+                over = [m.block_id for m in metas if m.overlaps_time(
+                    sub.start_ms // 1000, -(-sub.end_ms // 1000))]
                 jobs.append(_Job(
                     kind="metrics_query_range",
                     payload={"req": metrics_request_to_dict(sub)},
                     fn=self.querier.metrics_query_range, args=(tenant, sub),
-                    affinity_key=lead,
+                    affinity_key=over[0] if over else None,
+                    span_attrs={"blocks": len(over)},
                 ))
+            from ..util.kerneltel import TEL
+
+            TEL.record_range(
+                sum(m.overlaps_time(req.start_ms // 1000, -(-req.end_ms // 1000))
+                    for m in metas),
+                [j.span_attrs["blocks"] for j in jobs])
             attach_trace(jobs, trace)
             self._run_jobs(tenant, jobs)
         finally:

@@ -314,6 +314,11 @@ class KernelTelemetry:
         self._dispatch_jobs: dict[str, int] = {"local": 0, "remote": 0}
         self._dispatch_workers: dict[str, list] = {}
         self._dispatch_wire_bytes = 0
+        # requests over the blocklist: how many, by blocks covered, and
+        # the block jobs built for them (record_range)
+        self._range_searches: dict[int, int] = {}
+        self._range_jobs = 0
+        self._range_job_blocks = 0
         self._qos_sheds: dict[str, dict[str, int]] = {}
         self._staged_by_placement: dict[str, list[int]] = {}
         # live-head staging (ops/livestage): slot/row occupancy by
@@ -969,6 +974,24 @@ class KernelTelemetry:
             row[0] += 1
             row[1] += max(0.0, busy_s)
 
+    def record_range(self, blocks: int, job_blocks: list[int]) -> None:
+        """One search or metrics request planned over the blocklist:
+        the blocks its range covers and the blocks of each job built for
+        it (block-batch, row-group-shard and time-shard jobs; not the
+        ingester leg)."""
+        with self._lock:
+            self._range_searches[blocks] = self._range_searches.get(blocks, 0) + 1
+            self._range_jobs += len(job_blocks)
+            self._range_job_blocks += sum(job_blocks)
+
+    def range_stats(self) -> dict:
+        with self._lock:
+            return {"searches": sum(self._range_searches.values()),
+                    "by_blocks": {str(b): n for b, n
+                                  in sorted(self._range_searches.items())},
+                    "jobs": self._range_jobs,
+                    "job_blocks": self._range_job_blocks}
+
     def add_wire_bytes(self, n: int) -> None:
         with self._lock:
             self._dispatch_wire_bytes += int(n)
@@ -1390,6 +1413,7 @@ class KernelTelemetry:
             "retries": self.retry_stats(),
             "affinity": self.affinity_stats(),
             "dispatch": self.dispatch_stats(),
+            "range": self.range_stats(),
             "query_costs": self.query_cost_stats(),
             "selftrace": self.selftrace_stats(),
             "batching": self.batch_stats(),

@@ -4,7 +4,10 @@ four call sites decided before it existed, and to planning a job once.
 TABLE was generated at the parent commit (0c17b01, before db/route): the
 same drivers as below -- search_block, search_blocks_fused, the batch
 window's probe, metrics_block -- over the same block in each state, and
-the rows the routing counter gained. A key is "<state>/<caller>"; a value
+the rows the routing counter gained (one row differs since PR 34, which
+priced a host-cached block of a fused group: `tres_cached_tiny_rtt/fused`
+was `host_scan_cheaper` while a cached block cost nothing, and no scan is
+cheaper than a round trip of 1e-9 ms). A key is "<state>/<caller>"; a value
 lists the (layer, engine, reason) rows, then what else the caller did:
 "accept" (the probe took the job), "returns_none" (the fused engine gave
 the group back), "touches+1" (blk.search_touches moved). Counts only: CPU,
@@ -98,7 +101,7 @@ TABLE = {
  "touched_P/fused": [["search_fused", "device", "promoted"], ["touches+1"]],
  "touched_P/single": [["search_block", "host", "cold_block"]],
  "tres_cached_tiny_rtt/batch": [["search_batch", "fallback", "tres_host"]],
- "tres_cached_tiny_rtt/fused": [["search_fused", "host", "host_scan_cheaper"], ["touches+1"]],
+ "tres_cached_tiny_rtt/fused": [["search_fused", "host", "cold_block"], ["touches+1"]],  # PR 34, below
  "tres_cached_tiny_rtt/single": [["search_block", "device", "hot_block"]],
  "tres_on_shard/batch": [["search_batch", "fallback", "tres_host"]],
  "tres_on_shard/single": [["search_block", "device", "hot_block"]],
@@ -289,6 +292,49 @@ def test_decision_equals_the_parents(case, stored, monkeypatch):
         assert routes == [None]
     else:
         assert said == counted
+
+
+# ------------------------------------------- a cached block is not free
+
+
+@pytest.mark.parametrize("rtt_share,want", [
+    (0.5, ("device", "staged_hit")),  # the scan costs two round trips
+    (2.0, ("host", "host_scan_cheaper")),  # it costs half of one
+], ids=["scan_dearer_than_rtt", "scan_cheaper_than_rtt"])
+@pytest.mark.parametrize("req", ["plain", "tres"])
+def test_a_staged_block_in_the_host_cache_is_priced_not_free(
+        stored, monkeypatch, req, rtt_share, want):
+    """A block of a fused group whose columns are staged AND sit in the
+    host array cache: the parent estimated its host scan at 0 bytes, so one
+    host scan sent it to the numpy engine for good (ROADMAP C3 ii). It is
+    priced at its bytes over the memory-speed rate, and stays on the device
+    while that is more than a link round trip."""
+    blk = _reader(stored, _st())
+    r = REQS[req]
+    search_block(blk, r, mode="host")  # fills the array cache
+    stage_block(blk, _stage_columns(blk, r) + ["trace@gkey_s"])
+    p = _plan_for_block(blk, r)
+    cols, tres = route_mod.host_plan(blk, p, None)
+    assert tres == (req == "tres")
+    assert all(blk.pack.has_cached_array(n) for n in cols if blk.pack.has(n))
+    ms = route_mod.fused_host_ms(blk, p)
+    assert ms > 0
+    monkeypatch.setattr(route_mod, "link_rtt_ms", lambda: ms * rtt_share)
+    [route] = route_mod.route_fused([(blk, p)])
+    assert (route.engine, route.reason) == want
+
+
+def test_a_cold_block_is_priced_at_the_cold_rate(stored, monkeypatch):
+    """The same bytes cost more while they have to be read: the cold-scan
+    EMA's rate, not the array cache's."""
+    blk = _reader(stored, _st())
+    p = _plan_for_block(blk, REQS["plain"])
+    monkeypatch.setattr(route_mod, "_HOST_RATE_BPS", 1.0e9)
+    cold = route_mod.fused_host_ms(blk, p)
+    search_block(blk, REQS["plain"], mode="host")
+    warm = route_mod.fused_host_ms(blk, p)
+    assert cold == pytest.approx(
+        warm * route_mod._HOST_CACHED_RATE_BPS / 1.0e9)
 
 
 # ---------------------------------------------------------- planned once

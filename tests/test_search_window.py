@@ -418,7 +418,7 @@ def test_results_are_built_only_behind_candidates():
     assert builders == {"search._materialize", "search.response_from_dict",
                         "live_engine._collect"}, builders
     assert callers == {"search._collect_topk", "search._collect_topk_multi",
-                       "search.search_blocks_fused"}, callers
+                       "search._fused_eval"}, callers  # search_blocks_fused past its routing
     src = (root / "search.py").read_text()
     for fn in ("_collect_topk", "_collect_topk_multi"):
         body = src.split(f"def {fn}(")[1].split("\ndef ")[0]

@@ -76,3 +76,24 @@ def test_dense_fold_compiles_for_v5e_without_a_rows_by_cells_array(
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 * column
     text = compiled.as_text()
     assert "scatter" not in text and "gather" not in text and "sort(" not in text
+
+
+@pytest.mark.parametrize("k,rung", [(16384, 4), (131072, 4)], ids=["attr-4", "tag-4"])
+def test_group_select_compiles_for_v5e_at_the_hourly_blocks_bucket(
+        one_chip, no_compile_cache, k, rung):
+    """The cross-block select of `chip1-range-mix` (PR 34): `rung` slots of
+    a 32,768-trace bucket (an hourly block of 18,750 traces), at the k of a
+    `limit` 5000 and of a tag search that asks for every match (k = every
+    row the group holds). ~15 s a compile: the sort behind `top_k`."""
+    from tempo_tpu.ops.select import _compiled_select_group
+
+    part = 1 << 15
+
+    def parts(dtype):
+        return tuple(jax.ShapeDtypeStruct((part,), dtype, sharding=one_chip)
+                     for _ in range(rung))
+
+    fn = _compiled_select_group(k, rung, part)
+    compiled = fn.lower(parts(np.bool_), parts(np.int32), parts(np.int32)).compile()
+    # the answer (3k + 1 words) and a few copies of the group's rows
+    assert compiled.memory_analysis().temp_size_in_bytes <= 64 * rung * part
