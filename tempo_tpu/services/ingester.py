@@ -275,7 +275,37 @@ class Instance:
 
     def cut_block_if_ready(self, force: bool = False, now: float | None = None):
         """Cut set -> columnar block in the backend; WAL head rotates
-        (instance.go:266-289 + CompleteBlock)."""
+        (instance.go:266-289 + CompleteBlock).
+
+        The instance lock covers the SWAP (`ingest:swap`: the cut set
+        moves to `flushing`, the head rotates, the live traces staying
+        behind are fsynced into the new head) and nothing else. The
+        decode (`ingest:cut`) and the block write (`ingest:flush`) run
+        outside it, in the caller's thread, over `cut_snapshot`: every
+        push acknowledgement and every find's ingester leg takes the
+        same lock, and neither needs anything the decode reads.
+
+        Why the snapshot may be read unlocked: a LiveTrace that sits
+        only in `flushing` is frozen. `push_segments` appends to
+        `self.live[tid]` (a new object once the trace was cut);
+        `cut_complete_traces` merges late spans into `self.cut[tid]`,
+        which the swap emptied, so they open a new entry and land in the
+        NEXT block; `_find_live_map`, `trace_segments`, `_live_groups`
+        and `_index_of` copy segment lists under the lock and write only
+        the index cache fields. A snapshot entry becomes writable again
+        only through the failure path below, which hands it back to
+        `cut` once the decode and the write are over. A second cut
+        racing this one (sweeper beside `/flush`) takes its own
+        snapshot of whatever was cut since.
+
+        Where the guarantees sit: a push is acknowledged after its
+        window is flushed into the head under the lock; the carried
+        traces are fsynced into the new head before the lock is
+        released; the old head file is deleted only after `write_block`
+        returned (the blocklist carries the block); a trace is in `live`,
+        `cut`, `flushing` or a block at every instant. A decode or a
+        write that raises puts the snapshot back into `cut` (snapshot
+        segments first) and leaves the old WAL file on disk."""
         from ..util.kerneltel import TEL
 
         now = now or time.time()
@@ -295,12 +325,10 @@ class Instance:
             size = self.head.size_bytes()
             if not (force or age >= self.cfg.max_block_age_s or size >= self.cfg.max_block_bytes):
                 return None
-            with TEL.stage("ingest:cut", traces=len(self.cut)):
-                traces = []
+            with TEL.stage("ingest:swap", traces=len(self.cut)) as swap:
                 cut_snapshot = dict(self.cut)
-                for tid, lt in self.cut.items():
-                    parts = [segment_to_trace(s) for s in lt.segments]
-                    traces.append((tid, sort_trace(combine_traces(parts)) if len(parts) > 1 else parts[0]))
+                # into flushing BEFORE leaving cut: a reader must never
+                # find a trace in neither
                 self.flushing.update(cut_snapshot)  # stay visible during the write
                 self.cut.clear()
                 # live traces staying behind move to the NEW head's WAL file so
@@ -310,6 +338,7 @@ class Instance:
                 self.head_created = now
                 carry = [(lt.trace_id, lt.start_s, lt.end_s, seg)
                          for lt in self.live.values() for seg in lt.segments]
+                swap.attrs["carried"] = len(carry)
                 if hasattr(self.head, "append_window"):
                     if carry:
                         self.head.append_window(carry)
@@ -326,14 +355,19 @@ class Instance:
                 # block lands): force the fsync
                 self.head.flush(sync=True)
         try:
+            with TEL.stage("ingest:cut", traces=len(cut_snapshot)):
+                traces = []
+                for tid, lt in cut_snapshot.items():
+                    parts = [segment_to_trace(s) for s in lt.segments]
+                    traces.append((tid, sort_trace(combine_traces(parts)) if len(parts) > 1 else parts[0]))
             with TEL.stage("ingest:flush", traces=len(traces)), timed(FLUSH_DURATION):
                 meta = self.db.write_block(self.tenant, traces)
         except Exception:
             FLUSH_FAILURES.inc()
-            # block write failed: restore the cut set for the next retry;
-            # the old WAL file stays on disk as the checkpoint. MERGE into
-            # any entry cut for the same id since the snapshot (setdefault
-            # would silently drop the snapshot's segments).
+            # decode or block write failed: restore the cut set for the
+            # next retry; the old WAL file stays on disk as the checkpoint.
+            # MERGE into any entry cut for the same id since the snapshot
+            # (setdefault would silently drop the snapshot's segments).
             with self.lock:
                 for tid, lt in cut_snapshot.items():
                     if self.flushing.get(tid) is lt:

@@ -342,13 +342,14 @@ class KernelTelemetry:
             "delta_rows": 0, "lag_count": 0, "lag_sum": 0.0, "lag_max": 0.0,
         }
         # device-native ingest (tempo_tpu/ingest): per-stage write-path
-        # seconds (decode / wal_append / stage_delta / cut / flush),
+        # seconds (decode / wal_append / lock_wait / stage_delta / swap /
+        # cut / flush),
         # window/feature-checkpoint volume, replay outcomes
         self.ingest_stage_time = Histogram(
             "tempo_ingest_stage_seconds",
             buckets=(0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0),
             help="write-path stage wall seconds by stage "
-                 "(decode/wal_append/stage_delta/cut/flush)")
+                 "(decode/wal_append/lock_wait/stage_delta/swap/cut/flush)")
         self._ingest: dict = {
             "windows": 0, "window_traces": 0,
             "window_bytes": 0, "feature_entries": 0,

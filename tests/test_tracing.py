@@ -378,10 +378,11 @@ def test_stages_table_over_http(served):
     stages = snap["stages"]
     for name in ("http:find", "http:push", "http:encode", "http:write",
                  "find:bloom", "find:lookup", "find:fetch", "rows:materialize",
-                 "ingest:lock_wait", "ingest:cut", "ingest:flush", "cut:write"):
+                 "ingest:lock_wait", "ingest:swap", "ingest:cut", "ingest:flush",
+                 "cut:write"):
         assert stages[name]["count"] >= 1, name
     assert stages["find:fetch"]["seconds"] <= stages["http:find"]["seconds"]
-    assert {"decode", "wal_append", "cut", "flush"} <= set(snap["ingest"]["stages"])
+    assert {"decode", "wal_append", "swap", "cut", "flush"} <= set(snap["ingest"]["stages"])
     # stream.stage_seconds counts the cold-read pipeline's units alone: a
     # warm staging miss uploads under stage:upload
     assert stages.get("stage:upload", {"count": 0})["count"] >= snap["stream"]["units"]
