@@ -622,15 +622,19 @@ class Frontend:
             if p:
                 TEL.record_affinity(p)
 
-    def _note_done(self, job) -> None:
+    def _note_done(self, job, busy_s: float | None = None) -> None:
         """A worker produced this job's result: the dispatch counters
         and the `job:dispatch` / `job:result` rows of the stages table
-        (the spans are emitted with the trace, _emit_self_trace)."""
+        (the spans are emitted with the trace, _emit_self_trace).
+        `busy_s`: a local job's share of its `run:<kind>` stage (so the
+        worker's busy seconds and the stage's cannot drift); a remote
+        job's is hand-off to result."""
         from ..util.kerneltel import TEL
 
         now = time.time()
-        TEL.record_dispatch(job.worker or "local",
-                            now - (job.handed_wall or now))
+        if busy_s is None:
+            busy_s = now - (job.handed_wall or now)
+        TEL.record_dispatch(job.worker or "local", busy_s)
         if job.handed_wall and job.started_wall:
             TEL.record_stage("job:dispatch", job.handed_wall - job.started_wall)
         if job.posted_wall:
@@ -722,7 +726,8 @@ class Frontend:
         ptoken = TEL.set_affinity_placement(lead.placement)
         results = None
         try:
-            results = lead.batch_fn(live)
+            with TEL.stage(f"run:{lead.kind}", jobs=len(live)) as run:
+                results = lead.batch_fn(live)
         except Exception:
             results = None
         finally:
@@ -759,7 +764,7 @@ class Frontend:
                 if not j.done.is_set():
                     j.result = r
                 self.stats_jobs_local += 1
-                self._note_done(j)
+                self._note_done(j, run.seconds / len(live))
                 j.finish()
         else:
             for t, j in live:
@@ -877,14 +882,15 @@ class Frontend:
                   if job.trace is not None and job.span_id else None)
         ptoken = TEL.set_affinity_placement(getattr(job, "placement", ""))
         try:
-            res = job.fn(*job.args)
+            with TEL.stage(f"run:{job.kind}", jobs=1) as run:
+                res = job.fn(*job.args)
             if br is not None:
                 br.record(True)
             self._note_result(job, seq)
             if not job.done.is_set():
                 job.result = res
             self.stats_jobs_local += 1
-            self._note_done(job)
+            self._note_done(job, run.seconds)
         except Exception as e:
             # retry only transient failures (reference retries 5xx
             # only, modules/frontend/retry.go); a parse error or bad

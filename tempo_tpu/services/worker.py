@@ -130,6 +130,11 @@ def execute_job(querier: Querier, tenant: str, kind: str, payload: dict) -> dict
     raise ValueError(f"unknown job kind {kind!r}")
 
 
+# the kinds execute_job runs: the names of the `run:<kind>` stages
+JOB_KINDS = frozenset({"search_recent", "search_blocks", "search_block_shard",
+                       "metrics_query_range", "find_recent", "find_blocks"})
+
+
 class QuerierWorker:
     """Long-poll worker loops against one or more frontend addresses."""
 
@@ -257,9 +262,16 @@ class QuerierWorker:
                     recorder = None
             ttoken = TEL.set_active_trace(recorder) if recorder else None
             try:
-                result = execute_job(
-                    self.querier, job.get("tenant", ""), job["kind"], job["payload"]
-                )
+                kind, n_jobs = job["kind"], 1
+                if kind == "multi":  # same-key jobs of one pull: one stage
+                    kind = job["payload"]["kind"]
+                    n_jobs = len(job["payload"]["jobs"])
+                if kind not in JOB_KINDS:
+                    kind = "unknown"  # execute_job refuses it; the table stays closed
+                with TEL.stage(f"run:{kind}", jobs=n_jobs):
+                    result = execute_job(
+                        self.querier, job.get("tenant", ""), job["kind"],
+                        job["payload"])
                 out.update(ok=True, result=result)
                 self.jobs_executed += 1
             except Exception as e:  # noqa: BLE001 - report, let frontend retry

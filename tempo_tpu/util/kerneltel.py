@@ -33,9 +33,14 @@ frontend parked in a contextvar (set_active_trace) and is the ambient
 parent of its body, and wraps it in a jax.profiler.TraceAnnotation
 ("tempo/<name>", inert without a profiler session) so a device trace's
 idle gaps are owned by layers. A stage is per block or per request,
-never per row. child_span() stays for retroactive leaves (`verify`,
-queue-wait, the result-cache hits). Everything here is advisory -- no
-method may raise into the query path.
+never per row. It reads two clocks, the wall's and its own thread's CPU
+(a row is {count, seconds, cpu_seconds}): a wall-clock stage in a
+contended interpreter measures the contention, the CPU clock the work.
+A job's whole run is the stage `run:<kind>` (services/frontend,
+services/worker); /status/kernels `interp` holds the process's CPU and
+the sampler's lateness probe (util/runtimestats). child_span() stays
+for retroactive leaves (`verify`, queue-wait, the result-cache hits).
+Everything here is advisory -- no method may raise into the query path.
 """
 
 from __future__ import annotations
@@ -49,6 +54,7 @@ from collections import OrderedDict, deque
 
 from ..chaos import plane as _chaos
 from .metrics import Counter, Gauge, Histogram
+from .runtimestats import interp_stats
 
 # device kernels run sub-ms to ~seconds: a finer low end than the
 # request-latency default buckets
@@ -77,6 +83,29 @@ _active_trace: contextvars.ContextVar = contextvars.ContextVar(
 _affinity_placement: contextvars.ContextVar = contextvars.ContextVar(
     "tempo_affinity_placement", default="")
 
+# A thread's CPU clock is a system call: 0.3 us on a plain Linux host,
+# 5.8 us on the chip's (where it also ticks in 10 ms steps), and stage
+# boundaries come in clusters -- a parent and its first child open within
+# microseconds, a last child and its parent close together. A read taken
+# on the same thread less than this long ago stands in for a new one, so
+# a stage's cpu_seconds is exact to within it at either end.
+CPU_REUSE_S = 50e-6
+_cpu_tls = threading.local()
+
+
+def _thread_cpu(now: float) -> float:
+    """This thread's CPU seconds at perf_counter() `now`."""
+    tl = _cpu_tls
+    try:
+        if now - tl.at < CPU_REUSE_S:
+            return tl.cpu
+    except AttributeError:  # this thread's first read
+        pass
+    tl.cpu = cpu = time.thread_time()
+    tl.at = now
+    return cpu
+
+
 QOS_SHED_TENANTS_MAX = 128  # per-tenant shed rows kept before _overflow
 
 
@@ -93,15 +122,20 @@ class _Stage:
     """One timed stage (TEL.stage): counter + self-trace span +
     profiler annotation. `attrs` may be filled in the body; the span
     records them as they are on exit, the annotation as on entry.
-    `seconds` holds the duration after exit; a body that finds it did
-    none of the stage's work sets `counted = False` and the table and
-    its histogram get no sample."""
+    `seconds` holds the duration after exit and `cpu_seconds` what of it
+    this thread spent on a CPU (time.thread_time: the rest it was off
+    the CPU -- waiting for the GIL, a lock, I/O, the device or the pool
+    threads it handed work to, whose CPU is in their own stages' rows);
+    the span carries it as `cpu_ms`. A body that finds it did none of
+    the stage's work sets `counted = False` and the table and its
+    histogram get no sample."""
 
-    __slots__ = ("tel", "name", "attrs", "t0", "seconds", "counted", "_span", "_ann")
+    __slots__ = ("tel", "name", "attrs", "t0", "c0", "seconds", "cpu_seconds",
+                 "counted", "_span", "_ann")
 
     def __init__(self, tel, name: str, attrs: dict):
         self.tel, self.name, self.attrs = tel, name, attrs
-        self.seconds, self.counted = 0.0, True
+        self.seconds, self.cpu_seconds, self.counted = 0.0, 0.0, True
 
     def __enter__(self):
         self._span = self._ann = None
@@ -111,25 +145,29 @@ class _Stage:
                 self._span = t.span(self.name, self.attrs)
                 self._span.__enter__()
             # only once THIS process runs jax (a session needs it anyway):
-            # control-plane processes keep stages at two clock reads
+            # control-plane processes keep stages at their clock reads
             prof = sys.modules.get("jax.profiler")
             if prof is not None:
                 self._ann = prof.TraceAnnotation("tempo/" + self.name, **self.attrs)
                 self._ann.__enter__()
         except Exception:
             pass  # observability must never fail the body
+        self.c0 = _thread_cpu(time.perf_counter())
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self.seconds = time.perf_counter() - self.t0
+        t1 = time.perf_counter()
+        self.seconds = t1 - self.t0
+        self.cpu_seconds = _thread_cpu(t1) - self.c0
         try:
             if self._ann is not None:
                 self._ann.__exit__(exc_type, exc, tb)
             if self._span is not None:
+                self._span.attrs["cpu_ms"] = round(self.cpu_seconds * 1e3, 3)
                 self._span.__exit__(exc_type, exc, tb)
             if self.counted:
-                self.tel._add_stage(self.name, self.seconds)
+                self.tel._add_stage(self.name, self.seconds, self.cpu_seconds)
         except Exception:
             pass
         return False
@@ -163,7 +201,8 @@ class _Launch(_Stage):
 class KernelTelemetry:
     def __init__(self):
         self._lock = threading.Lock()
-        # TEL.stage: name -> [count, seconds, histogram | None, labels]
+        # TEL.stage: name -> [count, seconds, histogram | None, labels,
+        # cpu seconds | None (record_stage rows have no thread to ask)]
         self._stages: dict[str, list] = {}
         self._stages_at_session: dict | None = None  # mark_session
         self._tls = threading.local()
@@ -523,7 +562,8 @@ class KernelTelemetry:
         return _Launch(self, "kernel:launch",
                        dict(attrs, op=op, bucket=str(bucket), compile=new))
 
-    def _add_stage(self, name: str, seconds: float) -> None:
+    def _add_stage(self, name: str, seconds: float,
+                   cpu_seconds: float | None = None) -> None:
         with self._lock:
             row = self._stages.get(name)
             if row is None:
@@ -533,9 +573,11 @@ class KernelTelemetry:
                 hist = {"ingest": self.ingest_stage_time,
                         "stream": self.stream_stage_time,
                         "generator": self.generator_stage_time}.get(layer)
-                row = self._stages[name] = [0, 0.0, hist, f'stage="{stage}"']
+                row = self._stages[name] = [0, 0.0, hist, f'stage="{stage}"', None]
             row[0] += 1
             row[1] += seconds
+            if cpu_seconds is not None:
+                row[4] = (row[4] or 0.0) + cpu_seconds
         if row[2] is not None:
             row[2].observe(seconds, row[3], exemplar=self._exemplar_tid())
 
@@ -547,17 +589,23 @@ class KernelTelemetry:
         self._stages_at_session = self.stage_stats()
 
     def stage_stats(self, layer: str | None = None) -> dict:
-        """The cumulative stages table: {name: {count, seconds}}; with
-        `layer`, that layer's rows keyed by the bare stage name (the
-        `ingest.stages` / `generator.stages` / `stream.stage_seconds`
-        shapes of /status/kernels)."""
+        """The cumulative stages table: {name: {count, seconds,
+        cpu_seconds}}; `cpu_seconds` (the stage's thread on a CPU) only
+        on rows that `with TEL.stage` bodies filled -- absent means not
+        measured, never 0. With `layer`, that layer's rows keyed by the
+        bare stage name (the `ingest.stages` / `generator.stages` /
+        `stream.stage_seconds` shapes of /status/kernels)."""
         with self._lock:
-            rows = {n: (r[0], r[1]) for n, r in self._stages.items()}
+            rows = {n: (r[0], r[1], r[4]) for n, r in self._stages.items()}
         if layer is not None:
             rows = {n.partition(":")[2]: r for n, r in rows.items()
                     if n.startswith(layer + ":")}
-        return {n: {"count": c, "seconds": round(s, 6)}
-                for n, (c, s) in sorted(rows.items())}
+        out = {}
+        for n, (c, s, cpu) in sorted(rows.items()):
+            out[n] = {"count": c, "seconds": round(s, 6)}
+            if cpu is not None:
+                out[n]["cpu_seconds"] = round(cpu, 6)
+        return out
 
     # ----------------------------------------------------------- kernels
     def record_launch(self, op: str, key, bucket, cost=None) -> bool:
@@ -1426,6 +1474,7 @@ class KernelTelemetry:
             "generator": self.generator_stats(),
             "stages": self.stage_stats(),
             "stages_at_session": self._stages_at_session,
+            "interp": interp_stats(),
             "slow_queries": self.slow_queries(slow_k),
         }
 

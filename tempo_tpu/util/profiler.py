@@ -53,6 +53,7 @@ import threading
 import time
 from collections import deque
 
+from . import runtimestats
 from .metrics import Counter, Histogram
 
 PROFILE_HZ_ENV = "TEMPO_PROFILE_HZ"
@@ -379,11 +380,17 @@ class Profiler:
     def _loop(self) -> None:
         period = 1.0 / self._hz
         me = threading.get_ident()
+        due = time.perf_counter() + period
         while not self._stop.wait(period):
+            # the wait is over at `due`; what passes until this line runs
+            # is the wait for the interpreter (runtimestats.GIL_WAIT),
+            # taken before the stack walk so that it holds none of it
+            runtimestats.GIL_WAIT.observe(max(0.0, time.perf_counter() - due))
             try:
                 self._sample_once(me)
             except Exception:
                 pass  # the sampler must never take the process down
+            due = time.perf_counter() + period
 
     def _sample_once(self, me: int) -> None:
         now = time.time()
@@ -467,6 +474,7 @@ class Profiler:
                 "overflow_samples": overflow,
                 "ring_samples": ring_len,
                 "tagged_threads": tagged,
+                "probe": runtimestats.probe_stats(),
                 "components": components,
                 "top_stacks": top,
             },

@@ -6,6 +6,17 @@ for free (goroutines, GC pauses, RSS), for a CPython process.
   tempo_runtime_threads                           live thread count
   tempo_runtime_rss_bytes                         resident set size
   tempo_runtime_open_fds                          open file descriptors
+  tempo_runtime_cpu_seconds_total                 CPU of the whole process
+  tempo_runtime_gil_wait_seconds                  the sampler's lateness
+
+The last two are the interpreter as a measured layer (`interp` of
+/status/kernels, interp_stats): how busy the process is, and how long a
+thread that becomes runnable waits to run. The always-on sampler
+(util/profiler) waits one period and then needs the GIL back to take its
+sample; how late it gets it is the wait every thread pays after a device
+wait, a read or a lock. It also holds what the OS adds to a wake-up
+(tens of microseconds on an idle host) and a C call that keeps the GIL
+past the switch interval reads as one long wait.
 
 Counters accumulate from the moment install() first runs (the app
 installs at start; the /metrics chokepoint installs lazily as a
@@ -38,6 +49,18 @@ RSS = Gauge("tempo_runtime_rss_bytes",
             help="resident set size of this process")
 OPEN_FDS = Gauge("tempo_runtime_open_fds",
                  help="open file descriptors of this process")
+CPU = Counter(
+    "tempo_runtime_cpu_seconds_total",
+    help="CPU seconds of this process, every thread (time.process_time)")
+# edges at one switch interval (5 ms: a wait behind one other thread)
+# and at four (behind every worker of a busy process)
+GIL_WAIT_BUCKETS = (0.001, 0.005, 0.02, 0.1, 0.5)
+GIL_WAIT = Histogram(
+    "tempo_runtime_gil_wait_seconds", buckets=GIL_WAIT_BUCKETS,
+    help="how late the always-on sampler got the interpreter back "
+         "after each period: the wait of a thread that becomes runnable")
+
+_T0 = time.monotonic()
 
 _install_lock = threading.Lock()
 _installed = False
@@ -95,7 +118,32 @@ def _open_fds() -> int:
         return 0
 
 
+def probe_stats() -> dict:
+    """The sampler's lateness probe, read off GIL_WAIT (one counter, two
+    views); all 0 with the sampler off."""
+    counts, late, ticks = GIL_WAIT.snapshot().get(
+        "", ([0] * (len(GIL_WAIT_BUCKETS) + 1), 0.0, 0))
+    return {
+        "ticks": ticks,
+        "late_seconds": round(late, 6),
+        "late_over_5ms": sum(counts[GIL_WAIT_BUCKETS.index(0.005) + 1:]),
+        "late_over_20ms": sum(counts[GIL_WAIT_BUCKETS.index(0.02) + 1:]),
+    }
+
+
+def interp_stats() -> dict:
+    """The `interp` section of /status/kernels. Every field adds up over
+    the instances of a tree."""
+    return {
+        "cpu_seconds": round(time.process_time(), 6),
+        "wall_seconds": round(time.monotonic() - _T0, 6),
+        "probe": probe_stats(),
+    }
+
+
 def refresh() -> None:
+    with _install_lock:  # two scrapes at once must not add one delta twice
+        CPU.inc(time.process_time() - CPU.get())
     THREADS.set(threading.active_count())
     RSS.set(_rss_bytes())
     OPEN_FDS.set(_open_fds())
@@ -105,7 +153,7 @@ def metrics_lines() -> list[str]:
     install()  # lazy belt-and-braces: scrape implies counting
     refresh()
     return (GC_COLLECTIONS.text() + GC_PAUSE.text() + THREADS.text()
-            + RSS.text() + OPEN_FDS.text())
+            + RSS.text() + OPEN_FDS.text() + CPU.text() + GIL_WAIT.text())
 
 
 def help_entries() -> dict[str, str]:
@@ -115,4 +163,6 @@ def help_entries() -> dict[str, str]:
         "tempo_runtime_threads": THREADS.help,
         "tempo_runtime_rss_bytes": RSS.help,
         "tempo_runtime_open_fds": OPEN_FDS.help,
+        "tempo_runtime_cpu_seconds": CPU.help,
+        "tempo_runtime_gil_wait_seconds": GIL_WAIT.help,
     }

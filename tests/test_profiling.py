@@ -399,6 +399,164 @@ def test_runtime_health_gauges():
     assert runtimestats.RSS.get() > 0
 
 
+# ---------------------------------------------------- the interpreter probe
+
+
+def _probe_delta(seconds: float, hz: float = 100.0) -> dict:
+    """What the sampler's lateness probe adds while it runs `seconds`."""
+    from tempo_tpu.util.runtimestats import interp_stats
+
+    before = interp_stats()["probe"]
+    PROF.start(hz=hz)
+    time.sleep(seconds)
+    PROF.stop()
+    after = interp_stats()["probe"]
+    return {k: after[k] - before[k] for k in after}
+
+
+def _probe_idle():
+    """Nobody else wants the interpreter: the sampler gets it back at
+    once (what is left is the OS's wake-up, well under 2 ms)."""
+    best = None
+    for _ in range(3):  # a loaded test box: the quietest of three
+        d = _probe_delta(0.4)
+        assert d["ticks"] >= 10
+        mean = d["late_seconds"] / d["ticks"]
+        best = mean if best is None else min(best, mean)
+        if best < 0.002:
+            break
+    assert best < 0.002, best
+
+
+def _probe_contended():
+    """Beside a thread spinning in Python the sampler waits one switch
+    interval for every sample: the wait a thread that becomes runnable
+    pays in a busy process."""
+    import sys
+
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(100))
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(0.05)
+    t = threading.Thread(target=spin, daemon=True)
+    t.start()
+    try:
+        d = _probe_delta(1.0, hz=50.0)
+    finally:
+        stop.set()
+        sys.setswitchinterval(prev)
+        t.join(10)
+    assert d["ticks"] >= 5
+    assert d["late_seconds"] / d["ticks"] >= 0.05 * 0.9, d
+    assert d["late_over_20ms"] >= 0.8 * d["ticks"], d
+    assert d["late_over_5ms"] >= d["late_over_20ms"]
+    # the same numbers, where the sampler's status and /metrics show them
+    assert PROF.status_snapshot()["sampler"]["probe"]["ticks"] >= d["ticks"]
+    from tempo_tpu.util import runtimestats
+
+    text = "\n".join(runtimestats.metrics_lines())
+    assert 'tempo_runtime_gil_wait_seconds_bucket{le="0.02"}' in text
+    assert "tempo_runtime_cpu_seconds_total " in text
+
+
+def _probe_off():
+    """TEMPO_PROFILE_HZ=0: no sampler, so the probe stays 0 (the readers
+    then report nothing) while the CPU and wall clocks still run."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import json, time\n"
+            "from tempo_tpu.util.profiler import PROF\n"
+            "from tempo_tpu.util.kerneltel import TEL\n"
+            "assert PROF.ensure_sampler() is False\n"
+            "time.sleep(0.15)\n"
+            "print(json.dumps(TEL.snapshot()['interp']))\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env=dict(os.environ, TEMPO_PROFILE_HZ="0"), timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    interp = json.loads(out.stdout.strip().splitlines()[-1])
+    assert interp["probe"] == {"ticks": 0, "late_seconds": 0.0,
+                               "late_over_5ms": 0, "late_over_20ms": 0}
+    assert interp["cpu_seconds"] > 0 and interp["wall_seconds"] >= 0.15
+    # in this process too: a stopped sampler adds nothing
+    from tempo_tpu.util.runtimestats import interp_stats
+
+    before = interp_stats()
+    time.sleep(0.1)
+    assert interp_stats()["probe"] == before["probe"]
+
+
+def _probe_summed():
+    """A tree's /status/kernels adds `interp` and the stages' CPU over
+    its instances; rows without the CPU clock stay without it."""
+    from tempo_tpu.services.proctree import tree_kernel_status
+
+    def inst(k: int) -> dict:
+        return {
+            "device": {"count": 1},
+            "stages": {"run:search_blocks": {"count": k, "seconds": 2.0 * k,
+                                             "cpu_seconds": 0.5 * k},
+                       "job:dispatch": {"count": k, "seconds": 0.1 * k}},
+            "interp": {"cpu_seconds": 10.0 * k, "wall_seconds": 60.0,
+                       "probe": {"ticks": 100 * k, "late_seconds": 0.25 * k,
+                                 "late_over_5ms": 3 * k, "late_over_20ms": k}},
+        }
+
+    own = inst(1)
+    own["stages"]["http:search"] = {"count": 4, "seconds": 1.0, "cpu_seconds": 0.25}
+    total = tree_kernel_status(own, [({"index": i}, inst(i + 1)) for i in (1, 2)])
+    assert total["interp"] == {
+        "cpu_seconds": 60.0, "wall_seconds": 180.0,
+        "probe": {"ticks": 600, "late_seconds": 1.5, "late_over_5ms": 18,
+                  "late_over_20ms": 6}}
+    assert total["stages"]["run:search_blocks"] == {
+        "count": 6, "seconds": 12.0, "cpu_seconds": 3.0}
+    assert total["stages"]["job:dispatch"] == {"count": 6, "seconds": pytest.approx(0.6)}
+    assert total["stages"]["http:search"]["cpu_seconds"] == 0.25
+
+
+def _probe_scrapes():
+    """Scrapes that come together add the CPU's delta once: the counter
+    never passes the process's clock and never goes backwards."""
+    import threading
+
+    from tempo_tpu.util import runtimestats
+
+    seen, stop = [], threading.Event()
+
+    def scrape():
+        while not stop.is_set():
+            runtimestats.refresh()
+            seen.append((runtimestats.CPU.get(), time.process_time()))
+
+    threads = [threading.Thread(target=scrape) for _ in range(4)]
+    for t in threads:
+        t.start()
+    time.sleep(0.2)
+    stop.set()
+    for t in threads:
+        t.join()
+    assert len(seen) > 8
+    assert all(cpu <= clock + 1e-9 for cpu, clock in seen)
+    runtimestats.refresh()
+    assert runtimestats.CPU.get() >= max(cpu for cpu, _ in seen)
+
+
+_PROBE_CASES = {"idle": _probe_idle, "contended": _probe_contended,
+                "off": _probe_off, "summed": _probe_summed,
+                "scrapes": _probe_scrapes}
+
+
+@pytest.mark.parametrize("case", sorted(_PROBE_CASES))
+def test_interp_probe(case):
+    _PROBE_CASES[case]()
+
+
 # ------------------------------------------------------ strict exposition
 
 
@@ -425,6 +583,8 @@ def test_new_families_strict_openmetrics(monkeypatch):
     assert fams.get("tempo_runtime_gc_collections") == "counter"
     assert fams.get("tempo_runtime_threads") == "gauge"
     assert fams.get("tempo_runtime_rss_bytes") == "gauge"
+    assert fams.get("tempo_runtime_cpu_seconds") == "counter"
+    assert fams.get("tempo_runtime_gil_wait_seconds") == "histogram"
     # a contended wait makes the histogram family appear too
     lk2 = timed_lock("expo_lock2")
     lk2.acquire()

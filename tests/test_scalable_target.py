@@ -221,7 +221,13 @@ SUMMED = (("jit_cache", "compiles_total"), ("staging", "cache_hits"),
 
 
 def _counters(snaps: list) -> list:
-    return [[_launches(o)] + [o[sec][key] for sec, key in SUMMED] for o in snaps]
+    """What has to stand still for a read to count: the launches, the
+    staging counters and the rows a job in flight adds to (its wire
+    handlers' and its run's)."""
+    return [[_launches(o)] + [o[sec][key] for sec, key in SUMMED]
+            + sorted((n, r["count"]) for n, r in o["stages"].items()
+                     if n.startswith(("run:", "job:")))
+            for o in snaps]
 
 
 def _settled_status(tree: Proc):
@@ -268,7 +274,28 @@ def test_status_is_the_sum_of_the_instances(pair, corpus):
     assert jobs["remote"] > 0 and jobs["local"] + jobs["remote"] == sum(
         w["jobs"] for w in total["dispatch"]["by_worker"].values())
     assert total["stages"]["job:dispatch"]["count"] >= jobs["remote"]
-    assert total["stages"]["job:encode"]["count"] >= 2 * jobs["remote"] > 0
+    # the process that ran a job recorded its run:<kind> row, once
+    runs = {n: r for n, r in total["stages"].items() if n.startswith("run:")}
+    assert sum(r["count"] for r in runs.values()) >= jobs["local"]
+    # a wire job (same-key jobs of one pull travel as ONE `multi` job) is
+    # one run:* row on the querier that pulled it; it was encoded on its
+    # way out and its result on the way back, and each was decoded on the
+    # other side (a job past its deadline is answered without a run)
+    wire_jobs = sum(r["count"] for o in own[1:]
+                    for n, r in o["stages"].items() if n.startswith("run:"))
+    wire = total["stages"]
+    assert wire["job:encode"]["count"] >= 2 * wire_jobs > 0
+    assert wire["job:encode"]["count"] == wire["job:decode"]["count"]
+    for name, row in runs.items():
+        assert row["count"] == sum(
+            o["stages"].get(name, {}).get("count", 0) for o in own), name
+        assert row["cpu_seconds"] == pytest.approx(sum(
+            o["stages"].get(name, {}).get("cpu_seconds", 0.0) for o in own), abs=1e-4)
+    assert any(o["stages"].get("run:search_blocks", {}).get("count", 0)
+               for o in own[1:]), "no querier recorded a run:search_blocks row"
+    assert set(total["interp"]) == {"cpu_seconds", "wall_seconds", "probe"}
+    assert total["interp"]["probe"]["ticks"] >= max(
+        o["interp"]["probe"]["ticks"] for o in own)
     placed = total["affinity"]["jobs"]
     assert placed.get("own", 0) + placed.get("steal", 0) > 0
     hbm = tree.get("/status/cost")["hbm"]["per_device_memory_stats"]

@@ -11,6 +11,8 @@ import socket
 import tempfile
 import threading
 import time
+import types
+import urllib.error
 import urllib.parse
 import urllib.request
 import zipfile
@@ -238,18 +240,407 @@ def _case_miss_reason():
     assert not th.is_alive()
 
 
+def _spin(cpu_s: float) -> None:
+    """Burn `cpu_s` seconds of THIS thread's CPU in Python."""
+    end = time.thread_time() + cpu_s
+    while time.thread_time() < end:
+        sum(range(200))
+
+
+def _row(name: str) -> dict:
+    return TEL.stage_stats().get(name, {"count": 0, "seconds": 0.0, "cpu_seconds": 0.0})
+
+
+def _case_cpu_busy():
+    """A body that computes was on a CPU for about as long as it took."""
+    b = _row("verify:eval")
+    with TEL.stage("verify:eval") as st:
+        _spin(0.03)
+    a = _row("verify:eval")
+    assert 0.03 <= st.cpu_seconds <= st.seconds + 1e-3
+    assert a["cpu_seconds"] - b["cpu_seconds"] == pytest.approx(st.cpu_seconds, abs=2e-6)
+    assert a["seconds"] - b["seconds"] == pytest.approx(st.seconds, abs=2e-6)
+
+
+def _case_cpu_sleeping():
+    """A body that waits is off the CPU: seconds - cpu_seconds is the wait."""
+    b = _row("stream:fetch")
+    with TEL.stage("stream:fetch") as st:
+        time.sleep(0.05)
+    a = _row("stream:fetch")
+    assert st.seconds >= 0.05 and st.cpu_seconds < 0.01
+    assert a["cpu_seconds"] - b["cpu_seconds"] < 0.01
+
+
+def _case_cpu_nested():
+    """A nested stage lies inside its parent on both clocks, and both
+    spans say `cpu_ms` (a stage without attrs of its own too)."""
+    got = {}
+
+    def body():
+        with TEL.stage("topk:collect") as outer:
+            _spin(0.01)
+            with TEL.stage("rows:materialize", rows=1) as inner:
+                _spin(0.01)
+                time.sleep(0.01)
+        got.update(outer=outer, inner=inner)
+    spans = _run_in_trace(body)
+    outer, inner = got["outer"], got["inner"]
+    assert 0.01 <= inner.cpu_seconds <= outer.cpu_seconds - 0.01 + 1e-3
+    assert inner.seconds <= outer.seconds
+    assert inner.seconds - inner.cpu_seconds >= 0.009  # the sleep
+    for name, st in (("topk:collect", outer), ("rows:materialize", inner)):
+        assert spans[name].attrs["cpu_ms"] == pytest.approx(st.cpu_seconds * 1e3, abs=1e-3)
+    assert "cpu_ms" not in outer.attrs  # the span's, not the annotation's
+
+
+def _case_cpu_pool():
+    """A stage's clock is its own thread's: work a body hands to a pool
+    is in the pool thread's stage (its row, its span's `cpu_ms`) and the
+    body reads as off the CPU while it waits for it."""
+    from tempo_tpu.util.ctxpool import ContextThreadPool
+
+    pool = ContextThreadPool(max_workers=2)
+    got = {}
+
+    def item(_):
+        with TEL.stage("block:metrics") as st:
+            _spin(0.01)
+        return st
+
+    def body():
+        with TEL.stage("run:metrics_query_range") as outer:
+            kids = list(pool.map(item, range(2)))
+        got.update(outer=outer, kids=kids)
+    b = _row("block:metrics")
+    try:
+        spans = _run_in_trace(body)
+    finally:
+        pool.shutdown()
+    outer, kids = got["outer"], got["kids"]
+    assert outer.cpu_seconds < 0.01 <= outer.seconds  # it only waited
+    assert all(k.cpu_seconds >= 0.01 for k in kids)
+    a = _row("block:metrics")
+    assert a["cpu_seconds"] - b.get("cpu_seconds", 0.0) == pytest.approx(
+        sum(k.cpu_seconds for k in kids), abs=2e-6)
+    assert set(a) == {"count", "seconds", "cpu_seconds"}
+    assert spans["block:metrics"].attrs["cpu_ms"] >= 10.0
+    assert spans["run:metrics_query_range"].attrs["cpu_ms"] < 10.0
+    # the pool's thread carried the context: its stage hangs under the body's
+    assert spans["block:metrics"].parent_span_id == spans["run:metrics_query_range"].span_id
+
+
+def _case_cpu_reuse():
+    """The CPU clock is a system call (5.8 us on the chip's host): stage
+    boundaries that follow each other within CPU_REUSE_S on one thread
+    share a read; one that comes later takes its own, and so does
+    another thread."""
+    from tempo_tpu.util import kerneltel
+
+    reads = []
+    shim = types.SimpleNamespace(**{k: getattr(time, k) for k in dir(time)
+                                    if not k.startswith("__")})
+    shim.thread_time = lambda: (reads.append(threading.get_ident()), time.thread_time())[1]
+    real, kerneltel.time = kerneltel.time, shim
+    try:
+        time.sleep(2 * kerneltel.CPU_REUSE_S)
+        with TEL.stage("topk:collect"):
+            with TEL.stage("rows:materialize"):
+                pass
+        assert len(reads) < 4, reads  # four boundaries, a cluster or two
+        time.sleep(2 * kerneltel.CPU_REUSE_S)
+        del reads[:]
+        with TEL.stage("topk:collect") as st:
+            _spin(0.003)
+        assert len(reads) == 2 and st.cpu_seconds >= 0.003
+        t = threading.Thread(target=lambda: TEL.stage("verify:eval").__enter__().__exit__(None, None, None))
+        t.start()
+        t.join()
+        assert len(set(reads)) == 2  # the other thread read its own clock
+    finally:
+        kerneltel.time = real
+
+
+def _case_cpu_raises():
+    """A body that raises is counted on both clocks."""
+    b = _row("plan:compile")
+    with pytest.raises(ValueError):
+        with TEL.stage("plan:compile"):
+            _spin(0.01)
+            raise ValueError("bad query")
+    a = _row("plan:compile")
+    assert a["count"] == b["count"] + 1
+    assert a["cpu_seconds"] >= b["cpu_seconds"] + 0.01
+    assert a["seconds"] >= b["seconds"] + 0.01
+
+
+def _case_cpu_uncounted():
+    """`counted = False` adds to neither clock."""
+    with TEL.stage("ingest:stage_delta"):
+        pass
+    b = _row("ingest:stage_delta")
+    with TEL.stage("ingest:stage_delta") as st:
+        _spin(0.005)
+        st.counted = False
+    assert _row("ingest:stage_delta") == b and st.cpu_seconds >= 0.005
+
+
+def _case_cpu_retroactive():
+    """Rows and spans measured by their caller have no thread to ask:
+    no `cpu_seconds` key, no `cpu_ms` attribute (absent, never 0)."""
+    TEL.record_stage("job:dispatch", 0.25)
+    TEL.record_stage("job:result", 0.5)
+    for name in ("job:dispatch", "job:result"):
+        row = TEL.stage_stats()[name]
+        assert set(row) == {"count", "seconds"}, row
+
+    def body():
+        t0 = time.time()
+        TEL.child_span("verify", t0, t0 + 0.1, {"rows": 2})
+        TEL.child_span("queue-wait", t0, t0 + 0.1)
+    spans = _run_in_trace(body)
+    for name in ("verify", "queue-wait"):
+        assert "cpu_ms" not in spans[name].attrs
+    assert "verify" not in TEL.stage_stats()
+
+
+def _case_cpu_at_session():
+    """The table kept when a device-trace session begins carries the CPU
+    clock, and so do the layer sections."""
+    with TEL.stage("http:find"):
+        _spin(0.002)
+    with TEL.stage("ingest:decode"):
+        pass
+    with TEL.stage("generator:window"):
+        pass
+    TEL.mark_session()
+    snap = TEL.snapshot()
+    at = snap["stages_at_session"]["http:find"]
+    assert at["cpu_seconds"] >= 0.002 and at == snap["stages"]["http:find"]
+    assert "cpu_seconds" in snap["ingest"]["stages"]["decode"]
+    assert "cpu_seconds" in snap["generator"]["stages"]["window"]
+    with TEL.stage("http:find"):
+        _spin(0.002)
+    assert TEL.snapshot()["stages"]["http:find"]["cpu_seconds"] >= at["cpu_seconds"] + 0.002
+    assert TEL.snapshot()["stages_at_session"]["http:find"] == at
+
+
 _STAGE_CASES = {
     "counter_only": _case_counter_only, "nesting": _case_nesting,
     "verify_leaf": _case_verify_leaf, "late_attrs": _case_late_attrs,
     "families": _case_families, "launch": _case_launch,
     "raises": _case_raises, "annotation": _case_annotation,
     "uncounted": _case_uncounted, "miss_reason": _case_miss_reason,
+    "cpu_busy": _case_cpu_busy, "cpu_sleeping": _case_cpu_sleeping,
+    "cpu_nested": _case_cpu_nested, "cpu_raises": _case_cpu_raises,
+    "cpu_pool": _case_cpu_pool, "cpu_reuse": _case_cpu_reuse,
+    "cpu_uncounted": _case_cpu_uncounted, "cpu_retroactive": _case_cpu_retroactive,
+    "cpu_at_session": _case_cpu_at_session,
 }
 
 
 @pytest.mark.parametrize("case", sorted(_STAGE_CASES))
 def test_stage(case):
     _STAGE_CASES[case]()
+
+
+# ------------------------------------------------------- run:<kind> stages
+
+
+class _StageLog:
+    """Every stage entered while installed, with the stages the same
+    thread was already inside: (thread id, name, names around it)."""
+
+    def __init__(self, monkeypatch):
+        from tempo_tpu.util import kerneltel
+
+        self.rows: list = []
+        tls = threading.local()
+        enter, leave = kerneltel._Stage.__enter__, kerneltel._Stage.__exit__
+
+        def _enter(st):
+            stack = tls.__dict__.setdefault("stack", [])
+            self.rows.append((threading.get_ident(), st.name, tuple(stack)))
+            stack.append(st.name)
+            return enter(st)
+
+        def _leave(st, *exc):
+            tls.stack.pop()
+            return leave(st, *exc)
+
+        monkeypatch.setattr(kerneltel._Stage, "__enter__", _enter)
+        monkeypatch.setattr(kerneltel._Stage, "__exit__", _leave)
+        monkeypatch.setattr(kerneltel._Launch, "__exit__",
+                            lambda st, *exc: (_leave(st, *exc), False)[1])
+
+    def runs(self) -> list:
+        return [r for r in self.rows if r[1].startswith("run:")]
+
+    def assert_outermost(self):
+        """No run:* inside another run:* or inside an http:* stage of
+        its own thread: with the http:* roots they are the outermost
+        stages of all request work."""
+        for _, name, around in self.runs():
+            assert not [a for a in around if a.startswith(("run:", "http:"))], (name, around)
+        for _, name, around in self.rows:
+            if name.startswith("http:") and name.split(":")[1] in (
+                    "find", "search", "metrics", "push", "flush"):
+                assert not [a for a in around if a.startswith("run:")], (name, around)
+
+
+def _run_rows() -> dict:
+    return {n: r for n, r in TEL.stage_stats().items() if n.startswith("run:")}
+
+
+def _local_busy() -> float:
+    return TEL.dispatch_stats()["by_worker"].get("local", {}).get("busy_seconds", 0.0)
+
+
+def _bare_frontend():
+    from tempo_tpu.services.frontend import Frontend
+
+    return Frontend(querier=types.SimpleNamespace(db=None), n_workers=0)
+
+
+def _site_execute_one(log):
+    """One `run:<kind>` row a job, on the thread that ran it, and the
+    local worker's busy seconds are the stage's."""
+    from tempo_tpu.services.frontend import _Job
+
+    fe = _bare_frontend()
+    try:
+        def engine():
+            with TEL.stage("block:search"):
+                _spin(0.005)
+            return "answer"
+
+        jobs = [_Job(kind=k, payload={}, fn=engine, args=())
+                for k in ("search_recent", "find_recent", "metrics_query_range")]
+        failing = _Job(kind="find_recent", payload={}, fn=lambda: 1 / 0, args=())
+        for j in jobs:
+            fe._execute_one("t", j)
+        assert [j.result for j in jobs] == ["answer"] * 3
+        assert _local_busy() == pytest.approx(
+            sum(r["seconds"] for r in _run_rows().values()), abs=1e-5)
+        fe._execute_one("t", failing)
+        assert isinstance(failing.error, ZeroDivisionError)
+    finally:
+        fe.stop()
+    rows = _run_rows()
+    assert {n: r["count"] for n, r in rows.items()} == {
+        "run:search_recent": 1, "run:find_recent": 2, "run:metrics_query_range": 1}
+    assert all(r["cpu_seconds"] >= 0.005 for n, r in rows.items() if n != "run:find_recent")
+    # the job that raised is timed, and it is not a completed job
+    assert TEL.dispatch_stats()["by_worker"]["local"]["jobs"] == 3
+    assert [n for _, n, _ in log.runs()] == [
+        "run:search_recent", "run:find_recent", "run:metrics_query_range", "run:find_recent"]
+    assert ("run:search_recent",) in [a for _, n, a in log.rows if n == "block:search"]
+
+
+def _site_execute_batch(log):
+    """Same-key jobs run as one call are one stage with jobs=N; each job
+    takes its share of it as busy seconds."""
+    from tempo_tpu.services.frontend import _Job
+
+    fe = _bare_frontend()
+    seen = {}
+    try:
+        def fused(group):
+            _spin(0.006)
+            return [f"r{i}" for i in range(len(group))]
+
+        jobs = [_Job(kind="search_blocks", payload={}, fn=None, args=(),
+                     batch_key=("k",), batch_fn=fused) for _ in range(3)]
+        tracer, shipped = _traced()
+        with tracer.trace("frontend.search", {"tenant": "t"}) as t:
+            jobs[0].trace = t
+            fe._execute_batch([("t", j) for j in jobs])
+        tracer.flush()
+        seen = _spans_of(shipped)
+        assert [j.result for j in jobs] == ["r0", "r1", "r2"]
+    finally:
+        fe.stop()
+    assert _run_rows()["run:search_blocks"]["count"] == 1
+    assert _local_busy() == pytest.approx(_run_rows()["run:search_blocks"]["seconds"], abs=1e-5)
+    assert seen["run:search_blocks"].attrs["jobs"] == 3
+    assert seen["run:search_blocks"].attrs["cpu_ms"] >= 6.0
+    assert TEL.dispatch_stats()["by_worker"]["local"]["jobs"] == 3
+    assert len(log.runs()) == 1
+
+
+def _site_worker(log):
+    """The querier's pull loop: the process that runs the job records
+    the row (one a wire job, a `multi` one under its jobs' kind)."""
+    from tempo_tpu.services import worker as W
+
+    wire_jobs = [
+        {"id": "a", "tenant": "t", "kind": "find_recent",
+         "payload": {"trace_id": "00" * 16}},
+        {"id": "b", "tenant": "t", "kind": "multi",
+         "payload": {"kind": "find_blocks", "tenants": ["t", "t"],
+                     "jobs": [{"trace_id": "00" * 16, "block_ids": []}] * 2}},
+        {"id": "c", "tenant": "t", "kind": "no_such_kind", "payload": {}},
+        # malformed wire jobs are reported as failed, like any job that
+        # raises: they must not end the pull loop
+        {"id": "d", "tenant": "t", "kind": "multi", "payload": "not a dict"},
+        {"id": "e", "tenant": "t", "kind": ["unhashable"], "payload": {}},
+    ]
+
+    class Blocklist:
+        def metas_by_id(self, tenant, ids):
+            return []
+
+    class Querier:
+        db = type("Db", (), {"blocklist": Blocklist()})()
+
+        def find_trace_by_id(self, *a, **kw):
+            _spin(0.004)
+
+        def find_in_blocks(self, *a, **kw):
+            _spin(0.002)
+
+        def find_in_blocks_multi(self, items):
+            _spin(0.004)
+            return [None] * len(items)
+
+    w = W.QuerierWorker(Querier(), ["http://frontend.invalid"], concurrency=1,
+                        worker_id="querier-1")
+    posted = []
+
+    def post(addr, path, payload, timeout):
+        if path.endswith("/poll"):
+            if wire_jobs:
+                return wire_jobs.pop(0)
+            w.stop()
+            return None
+        posted.append(payload)
+
+    w._post = post
+    w._loop("http://frontend.invalid")
+    assert [(p["id"], p["ok"]) for p in posted] == [
+        ("a", True), ("b", True), ("c", False), ("d", False), ("e", False)]
+    assert posted[1]["result"] == {"results": [{"trace": None}] * 2}
+    rows = _run_rows()
+    assert {n: r["count"] for n, r in rows.items()} == {
+        "run:find_recent": 1, "run:find_blocks": 1, "run:unknown": 1}
+    assert rows["run:find_recent"]["cpu_seconds"] >= 0.004
+    assert [a for _, n, a in log.runs()] == [(), (), ()]
+    assert "local" not in TEL.dispatch_stats()["by_worker"]  # the frontend's to count
+
+
+_RUN_SITES = {"execute_one": _site_execute_one, "execute_batch": _site_execute_batch,
+              "worker": _site_worker}
+
+
+@pytest.mark.parametrize("site", sorted(_RUN_SITES))
+def test_run_stage(site, monkeypatch):
+    TEL.reset()
+    log = _StageLog(monkeypatch)
+    try:
+        _RUN_SITES[site](log)
+        log.assert_outermost()
+    finally:
+        TEL.reset()
 
 
 # ------------------------------------------------ /debug/profile/device
@@ -388,6 +779,57 @@ def test_stages_table_over_http(served):
     assert stages.get("stage:upload", {"count": 0})["count"] >= snap["stream"]["units"]
     assert ("upload" in snap["stream"]["stage_seconds"]) is bool(
         stages.get("stream:upload"))
+
+
+def test_request_roots_are_outermost_over_http(served, monkeypatch):
+    """Served requests: every job of a find, a search and a rate() ran in
+    one run:<kind> stage on a worker's thread, none inside another or
+    under the handler's http:* stage; the roots' CPU fits in the
+    process's (`interp`), which /status/kernels publishes."""
+    base, ids = served
+
+    def status():
+        with urllib.request.urlopen(base + "/status/kernels", timeout=30) as r:
+            return json.loads(r.read())
+
+    before = status()
+    log = _StageLog(monkeypatch)
+    q = urllib.parse.quote("{ duration > 2ms }")  # not what the fixture cached
+    m = urllib.parse.quote("{ true } | rate()")
+    t_s = 1_700_000_000  # make_traces' date
+    with pytest.raises(urllib.error.HTTPError):  # an id nobody cached: a 404
+        urllib.request.urlopen(f"{base}/api/traces/{'5a' * 16}", timeout=60).read()
+    urllib.request.urlopen(f"{base}/api/search?q={q}&limit=5", timeout=120).read()
+    urllib.request.urlopen(f"{base}/api/metrics/query_range?q={m}&start={t_s - 600}"
+                           f"&end={t_s + 3000}&step=60", timeout=120).read()
+    monkeypatch.undo()
+    after = status()
+    log.assert_outermost()
+    kinds = {n for _, n, _ in log.runs()}
+    assert {"run:find_recent", "run:search_recent",
+            "run:metrics_query_range"} <= kinds, kinds
+    assert kinds & {"run:search_blocks", "run:search_block_shard"}, kinds
+    handlers = {tid for tid, n, _ in log.rows if n.startswith("http:")}
+    assert not handlers & {tid for tid, _, _ in log.runs()}
+
+    def delta(name, key):
+        return after["stages"][name][key] - before["stages"].get(name, {}).get(key, 0)
+
+    roots = [n for n in after["stages"]
+             if n.startswith("run:") or n in ("http:find", "http:search", "http:metrics")]
+    n_runs = sum(delta(n, "count") for n in roots if n.startswith("run:"))
+    jobs = after["dispatch"]["jobs"]["local"] - before["dispatch"]["jobs"]["local"]
+    assert n_runs == len(log.runs()) == jobs
+    busy = (after["dispatch"]["by_worker"]["local"]["busy_seconds"]
+            - before["dispatch"]["by_worker"]["local"]["busy_seconds"])
+    assert busy == pytest.approx(
+        sum(delta(n, "seconds") for n in roots if n.startswith("run:")), abs=1e-4)
+    ia, ib = after["interp"], before["interp"]
+    assert set(ia) == {"cpu_seconds", "wall_seconds", "probe"}
+    assert set(ia["probe"]) == {"ticks", "late_seconds", "late_over_5ms", "late_over_20ms"}
+    cpu = ia["cpu_seconds"] - ib["cpu_seconds"]
+    assert 0 < sum(delta(n, "cpu_seconds") for n in roots) <= cpu + 1e-3
+    assert ia["wall_seconds"] > ib["wall_seconds"]
 
 
 # ------------------------------------------------- named kernel scopes
