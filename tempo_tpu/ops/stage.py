@@ -82,6 +82,18 @@ def staged_cache_stats(max_entries: int = 32) -> dict:
             "budget_bytes": int(budget), "hottest": hottest}
 
 
+def staged_block_ids() -> frozenset[str]:
+    """The blocks with at least one column resident in this process's
+    staged cache: what a querier tells the frontend it holds with every
+    poll, so that a job for one of them need not wait out the steal
+    clock (services/frontend `_claimer`)."""
+    with _lru_lock:
+        blocks = {bid: wr for (bid, _key), (wr, _n) in _lru.items()}
+    ids = (getattr(getattr(wr(), "meta", None), "block_id", "")
+           for wr in blocks.values())
+    return frozenset(i for i in ids if i)
+
+
 def set_staged_cache_budget(n_bytes: int) -> None:
     global _GLOBAL_CACHE_BUDGET
     with _lru_lock:

@@ -24,7 +24,10 @@ local worker pool + every attached remote querier), and the dequeue
 prefers handing a job to its affinity owner so a block staged in one
 querier's HBM (ops/stage staged cache) stays staged there instead of
 being re-fetched, re-padded and re-uploaded by whichever worker happens
-to poll first. A bounded anti-starvation steal timeout
+to poll first. Locality is satisfied by any replica: a domain that
+reports the job's block among the blocks it holds staged columns for
+(a querier says so with every poll) may take the job at once. For the
+rest a bounded anti-starvation steal timeout
 (TEMPO_AFFINITY_STEAL_MS) lets any worker take a job its owner hasn't
 claimed in time, so a slow or dead owner never strands work; with
 affinity off (TEMPO_AFFINITY=0) or a single cache domain the dequeue
@@ -126,6 +129,7 @@ class RequestQueue:
                 # double-count affinity telemetry and misattribute
                 # staged-cache lookups on the retry worker
                 job.placement = ""
+                job.warm = False
             except AttributeError:
                 pass
             if getattr(job, "queued_at", None) == 0.0:
@@ -275,6 +279,7 @@ class RequestQueue:
         key = key_fn(job) if key_fn is not None else None
         if key is not None and max_batch > 1:
             lead_placement = getattr(job, "placement", "")
+            lead_warm = getattr(job, "warm", False)
             with self.cv:
                 for _ in range(len(self.order)):
                     if len(extras) >= max_batch - 1 or not self.order:
@@ -297,6 +302,7 @@ class RequestQueue:
                                 del q[i]
                                 try:
                                     j2.placement = lead_placement
+                                    j2.warm = lead_warm
                                 except AttributeError:
                                     pass
                                 extras.append((t2, j2))
@@ -348,12 +354,14 @@ class _Job:
     # cache-affinity scheduling: the block ID this job's placement
     # hashes on (None = placement-free, claimable by anyone), the
     # monotonic stamp its steal clock runs from (set at first enqueue),
-    # and the dequeue outcome ("own"/"steal"/"unowned") it executed under
+    # and the dequeue outcome ("own"/"steal"/"unowned") it executed under;
+    # warm = a steal taken before the clock by a domain that holds the block
     affinity_key: str | None = None
     # what the job's `job:<kind>` self-trace span says of its size
     span_attrs: dict = field(default_factory=dict)
     queued_at: float = 0.0
     placement: str = ""
+    warm: bool = False
     # resilience plane: the query-wide retry budget this job draws
     # from, the caller's wall-clock deadline (rides the wire job so
     # remote workers skip work nobody can use), and hedge attribution
@@ -461,6 +469,9 @@ class Frontend:
         # worker id -> the device it reported with its polls (a querier
         # that owns a chip: services/worker); lease id -> its worker
         self._remote_devices: dict[str, dict] = {}
+        # worker id -> the blocks its newest poll said it holds staged
+        # columns for (ops/stage.staged_block_ids in the querier)
+        self._remote_holds: dict[str, frozenset] = {}
         self._lease_workers: dict[str, str] = {}
         self._lost_at: dict[str, float] = {}  # worker id -> worker_lost()
         # backend-leg circuit breaker (util/breaker): block-scanning
@@ -526,11 +537,13 @@ class Frontend:
             if j.handed_wall and j.handed_wall >= j.started_wall:
                 # enqueue -> a worker has it in hand: the queue wait
                 # plus, for a remote querier, the poll's way back
+                dattrs = {"worker": j.worker, "remote": j.worker != "local",
+                          "placement": _PLACEMENT_NAMES.get(j.placement,
+                                                            j.placement)}
+                if j.placement == "steal":
+                    dattrs["warm"] = j.warm
                 t.child("job:dispatch", j.started_wall, j.handed_wall,
-                        {"worker": j.worker, "remote": j.worker != "local",
-                         "placement": _PLACEMENT_NAMES.get(j.placement,
-                                                           j.placement)},
-                        parent=sid)
+                        dattrs, parent=sid)
             if j.posted_wall and j.merged_wall >= j.posted_wall:
                 t.child("job:result", j.posted_wall, j.merged_wall,
                         {"worker": j.worker}, parent=sid)
@@ -553,6 +566,9 @@ class Frontend:
             for m in list(self._aff_descs):
                 if m not in live:  # churned worker ids must not accumulate
                     del self._aff_descs[m]
+            for m in list(self._remote_holds):
+                if m not in live:  # what a dropped worker held goes with it
+                    del self._remote_holds[m]
             for m in members:
                 d = self._aff_descs.get(m)
                 if d is None:
@@ -572,7 +588,13 @@ class Frontend:
         on a worker the tenant's jobs can't be handed to -- otherwise
         every such job would pay the full steal timeout for an owner
         that can never claim it. Lookups memoize per pass -- one ring
-        walk per distinct (tenant, block) per dequeue."""
+        walk per distinct (tenant, block) per dequeue. A non-owner that
+        holds staged columns of the job's block (the set its newest poll
+        reported; the local pool reads its own process's) steals at
+        once: locality is satisfied by any replica, and the steal clock
+        is for domains that would have to upload the block. A stale
+        "holds it" costs what a blind steal costs (an upload), a stale
+        "does not" the clock."""
         if not self.affinity_enabled or not member:
             return None
         members = self._affinity_members()
@@ -582,6 +604,13 @@ class Frontend:
         steal_s = self.affinity_steal_ms / 1000.0
         owners: dict[tuple[str, str], str | None] = {}
         shards: dict[str, list[InstanceDesc]] = {}
+        if member == self._local_member:
+            from ..ops.stage import staged_block_ids
+
+            held = staged_block_ids()
+        else:
+            with self._lease_lock:
+                held = self._remote_holds.get(member, frozenset())
 
         def shard_members(tenant: str) -> list[InstanceDesc]:
             ms = shards.get(tenant)
@@ -608,7 +637,12 @@ class Frontend:
                 return "unowned"
             queued = getattr(job, "queued_at", 0.0)
             if queued and now - queued < steal_s:
-                return None  # owner's job; steal clock still running
+                if key not in held:
+                    return None  # owner's job; steal clock still running
+                # a replica holder: no upload to save by waiting (the
+                # queue takes the job on this answer, so the mark is set
+                # only on a job that is handed out)
+                job.warm = True
             return "steal"
 
         return claim
@@ -620,7 +654,7 @@ class Frontend:
         for j in jobs:
             p = getattr(j, "placement", "")
             if p:
-                TEL.record_affinity(p)
+                TEL.record_affinity(p, warm=getattr(j, "warm", False))
 
     def _note_done(self, job, busy_s: float | None = None) -> None:
         """A worker produced this job's result: the dispatch counters
@@ -957,7 +991,7 @@ class Frontend:
     REMOTE_BATCH_MAX = 8  # same-key jobs merged into one wire pull
 
     def poll_job(self, wait_s: float = 5.0, worker_id: str = "",
-                 device: dict | None = None):
+                 device: dict | None = None, staged_blocks=None):
         """Long-poll dequeue for a remote querier worker
         (frontend_processor.go's stream recv). Returns a wire job dict
         or None on timeout. Same-key jobs queued at poll time merge into
@@ -965,13 +999,18 @@ class Frontend:
         dequeue), leased together. Expired leases re-enter the queue
         first. Affinity: this worker prefers jobs whose block hashes to
         it on the cache-domain ring; a peer's jobs become claimable only
-        past the steal timeout. The wire job carries the dequeue
-        placement so the remote process attributes its staged-cache
-        hits."""
+        past the steal timeout, unless `staged_blocks` -- the block ids
+        this querier holds staged columns for, replaced by every poll;
+        a poll without it (an older querier) holds nothing -- has the
+        job's block. The wire job carries the dequeue placement so the
+        remote process attributes its staged-cache hits."""
         began = time.monotonic()
         if worker_id:
             with self._lease_lock:
                 self._remote_workers[worker_id] = began
+                self._remote_holds[worker_id] = frozenset(
+                    staged_blocks if isinstance(staged_blocks, (list, tuple, set, frozenset))
+                    else ())
                 if device:
                     self._remote_devices[worker_id] = device
         self._requeue_expired()
@@ -1181,6 +1220,7 @@ class Frontend:
         with self._lease_lock:
             self._remote_workers.pop(worker_id, None)
             self._remote_devices.pop(worker_id, None)
+            self._remote_holds.pop(worker_id, None)
             self._lost_at[worker_id] = time.monotonic()
         self._requeue_expired(lost_worker=worker_id)
 

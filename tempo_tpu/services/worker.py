@@ -202,13 +202,19 @@ class QuerierWorker:
     def _loop(self, addr: str) -> None:
         import random
 
+        from ..ops.stage import staged_block_ids
+
         backoff = self.BACKOFF_BASE_S
         while not self._stop.is_set():
             try:
                 job = self._post(addr, "/internal/jobs/poll",
                                  {"wait_s": self.poll_wait_s,
                                   "worker_id": self.worker_id,
-                                  "device": self.device},
+                                  "device": self.device,
+                                  # what this process holds staged: a job
+                                  # for one of these need not wait for
+                                  # its ring owner (frontend._claimer)
+                                  "staged_blocks": sorted(staged_block_ids())},
                                  timeout=self.poll_wait_s + 10.0)
             except (urllib.error.URLError, ConnectionError, OSError):
                 # full jitter: sleep U(0, backoff), then double the cap

@@ -261,7 +261,8 @@ def handle_internal(app, path: str, payload: dict, raw_body: bytes = b"",
             return 404, {"error": f"target {app.cfg.target} hosts no frontend"}
         job = app.frontend.poll_job(wait_s=float(payload.get("wait_s", 5.0)),
                                     worker_id=payload.get("worker_id", ""),
-                                    device=payload.get("device"))
+                                    device=payload.get("device"),
+                                    staged_blocks=payload.get("staged_blocks"))
         if not job:
             return 200, {}
         # the frontend's side of the wire: a job encoded once, here

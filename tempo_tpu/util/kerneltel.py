@@ -340,6 +340,10 @@ class KernelTelemetry:
         self.affinity_jobs = Counter(
             "tempo_affinity_jobs_total",
             help="frontend dequeue placement outcomes (own/steal/unowned)")
+        self.affinity_warm_steals = Counter(
+            "tempo_affinity_warm_steals_total",
+            help="steals taken before the steal timeout by a cache "
+                 "domain that reported the job's block as staged")
         self.qos_shed = Counter(
             "tempo_qos_shed_total",
             help="queries shed with 429 by per-tenant read QoS budgets")
@@ -348,6 +352,7 @@ class KernelTelemetry:
             help="staged-cache lookups by job placement (own/steal/"
                  "unowned/none) and result")
         self._affinity: dict[str, int] = {}
+        self._affinity_warm_steals = 0
         # job dispatch (services/frontend): jobs by where they ran, per
         # worker [jobs, busy seconds], bytes over the frontend -> querier wire
         self._dispatch_jobs: dict[str, int] = {"local": 0, "remote": 0}
@@ -512,7 +517,8 @@ class KernelTelemetry:
             self.compact_bytes_inflight, self.compact_queue_depth,
             self.compact_passthrough_bytes, self.stream_stage_time,
             self.stream_units, self.stream_bytes_inflight,
-            self.affinity_jobs, self.qos_shed, self.staged_placement,
+            self.affinity_jobs, self.affinity_warm_steals, self.qos_shed,
+            self.staged_placement,
             self.livestage_rows, self.livestage_delta_bytes,
             self.livestage_lag, self.ingest_stage_time,
             self.generator_stage_time, self.generator_freshness,
@@ -942,14 +948,22 @@ class KernelTelemetry:
         return c
 
     # ------------------------------------------------- affinity scheduling
-    def record_affinity(self, outcome: str, n: int = 1) -> None:
+    def record_affinity(self, outcome: str, n: int = 1,
+                        warm: bool = False) -> None:
         """One frontend dequeue under affinity routing: the job went to
-        its owner ("own"), was taken past the steal timeout ("steal"),
-        or carried no block affinity at all ("unowned")."""
+        its owner ("own"), to another cache domain ("steal"), or carried
+        no block affinity at all ("unowned"). `warm`: a steal taken
+        BEFORE the steal timeout, by a domain that had reported the
+        job's block among the blocks it holds staged columns for; the
+        steals without it waited the timeout out."""
         try:
             self.affinity_jobs.inc(n, labels=f'outcome="{outcome}"')
+            if warm:
+                self.affinity_warm_steals.inc(n)
             with self._lock:
                 self._affinity[outcome] = self._affinity.get(outcome, 0) + n
+                if warm:
+                    self._affinity_warm_steals += n
         except Exception:
             pass
 
@@ -1063,6 +1077,7 @@ class KernelTelemetry:
                 for p, (h, m) in sorted(self._staged_by_placement.items())
             }
             return {"jobs": dict(self._affinity),
+                    "warm_steals": self._affinity_warm_steals,
                     "staged_by_placement": staged,
                     "qos_sheds": {t: dict(v)
                                   for t, v in sorted(self._qos_sheds.items())}}

@@ -348,6 +348,344 @@ def test_placement_counters_recorded():
     assert now.get("own", 0) >= base.get("own", 0) + 1
 
 
+# ------------------------------------------- warm steals (held replicas)
+
+
+def _warm_steals() -> int:
+    return TEL.affinity_stats()["warm_steals"]
+
+
+def _poll(fe: Frontend, worker: str, holds=None, wait_s: float = 0.5):
+    return fe.poll_job(wait_s=wait_s, worker_id=worker, staged_blocks=holds)
+
+
+@pytest.mark.parametrize("as_type", [list, tuple, frozenset])
+def test_holder_steals_at_once(as_type):
+    """A non-owner whose newest poll reported the job's block among the
+    blocks it holds claims the job without the steal clock: stamped
+    `steal` (a non-owner ran it), marked warm, counted once."""
+    fe = _dispatcher(affinity_steal_ms=60_000.0)
+    try:
+        _attach(fe, "w1", "w2")
+        key = _keys_by_owner(fe, {"w1"})["w1"]
+        job = _job(key=key)
+        fe.queue.enqueue(TENANT, job)
+        w0, s0 = _warm_steals(), TEL.affinity_stats()["jobs"].get("steal", 0)
+        t0 = time.monotonic()
+        wire = _poll(fe, "w2", as_type([key, "another-block"]))
+        assert wire is not None and time.monotonic() - t0 < 5.0
+        assert wire["placement"] == "steal"  # nothing new on the wire
+        assert set(wire) == {"id", "tenant", "kind", "payload", "placement",
+                             "deadline_in_s", "trace"}
+        assert job.placement == "steal" and job.warm is True
+        assert _warm_steals() == w0 + 1
+        assert TEL.affinity_stats()["jobs"]["steal"] == s0 + 1
+    finally:
+        fe.stop()
+
+
+@pytest.mark.parametrize("holds", [None, [], ["some-other-block"], "not-a-list"],
+                         ids=["no-field", "empty", "other-block", "malformed"])
+def test_cold_thief_waits_the_clock(holds):
+    """A domain that does not hold the block -- or an older querier
+    whose poll carries no set at all -- waits AFFINITY_STEAL_MS as
+    before, and its steal is not a warm one."""
+    steal_ms = 120.0
+    fe = _dispatcher(affinity_steal_ms=steal_ms)
+    try:
+        _attach(fe, "w1", "w2")
+        key = _keys_by_owner(fe, {"w1"})["w1"]
+        job = _job(key=key)
+        w0 = _warm_steals()
+        fe.queue.enqueue(TENANT, job)
+        t0 = time.monotonic()
+        assert _poll(fe, "w2", holds, wait_s=0.03) is None  # clock running
+        assert job.placement == ""
+        wire = _poll(fe, "w2", holds, wait_s=2.0)
+        waited = time.monotonic() - t0
+        assert wire is not None and wire["placement"] == "steal"
+        assert steal_ms / 1e3 <= waited < 2.0
+        assert job.warm is False and _warm_steals() == w0
+    finally:
+        fe.stop()
+
+
+def test_owner_and_unowned_do_not_need_the_set():
+    """The owner still claims as `own` and a block-free job as
+    `unowned`, whatever either side reported: neither is a warm steal."""
+    fe = _dispatcher(affinity_steal_ms=60_000.0)
+    try:
+        _attach(fe, "w1", "w2")
+        key = _keys_by_owner(fe, {"w1"})["w1"]
+        w0 = _warm_steals()
+        mine, free = _job(key=key), _job()
+        fe.queue.enqueue(TENANT, mine)
+        assert _poll(fe, "w1", [key])["placement"] == "own"
+        fe.queue.enqueue(TENANT, free)
+        assert _poll(fe, "w2", [key])["placement"] == "unowned"
+        assert not mine.warm and not free.warm and _warm_steals() == w0
+    finally:
+        fe.stop()
+
+
+def test_reported_set_is_replaced_by_the_next_poll():
+    """The frontend keeps the NEWEST set a worker id reported: a block
+    evicted since the last poll is no longer a reason to skip the clock."""
+    fe = _dispatcher(affinity_steal_ms=60_000.0)
+    try:
+        _attach(fe, "w1", "w2")
+        key = _keys_by_owner(fe, {"w1"})["w1"]
+        assert _poll(fe, "w2", [key], wait_s=0.01) is None
+        assert fe._remote_holds["w2"] == frozenset([key])
+        assert _poll(fe, "w2", ["elsewhere"], wait_s=0.01) is None
+        assert fe._remote_holds["w2"] == frozenset(["elsewhere"])
+        fe.queue.enqueue(TENANT, _job(key=key))
+        assert _poll(fe, "w2", ["elsewhere"], wait_s=0.05) is None
+        assert _poll(fe, "w2", wait_s=0.05) is None  # no field: holds nothing
+        assert fe._remote_holds["w2"] == frozenset()
+        wire = _poll(fe, "w2", [key])
+        assert wire is not None and wire["placement"] == "steal"
+    finally:
+        fe.stop()
+
+
+@pytest.mark.parametrize("how", ["worker_lost", "worker_expiry_s"])
+def test_reported_set_is_forgotten_with_the_worker(how):
+    fe = _dispatcher(worker_expiry_s=0.05 if how == "worker_expiry_s" else 60.0)
+    try:
+        assert _poll(fe, "w1", ["a"], wait_s=0.01) is None
+        assert _poll(fe, "w2", ["b"], wait_s=0.01) is None
+        assert set(fe._remote_holds) == {"w1", "w2"}
+        if how == "worker_lost":
+            fe.worker_lost("w2")
+        else:
+            time.sleep(0.08)
+            assert _poll(fe, "w1", ["a"], wait_s=0.01) is None  # w1 lives on
+            fe._affinity_members()
+        assert set(fe._remote_holds) == {"w1"}
+        assert {d.instance_id for d in fe._affinity_members()} == {"w1"}
+    finally:
+        fe.stop()
+
+
+def test_warm_steals_count_exactly_the_early_steals():
+    """`affinity.warm_steals` counts the steals taken without the clock
+    and nothing else: not the owner's jobs, not the cold steals, and a
+    holder that arrives after the clock ran out steals like anybody."""
+    steal_ms = 80.0
+    fe = _dispatcher(affinity_steal_ms=steal_ms)
+    try:
+        _attach(fe, "w1", "w2")
+        key = _keys_by_owner(fe, {"w1"})["w1"]
+        base = TEL.affinity_stats()
+        jobs = [_job(key=key) for _ in range(5)]
+        for j in jobs[:3]:
+            fe.queue.enqueue(TENANT, j)
+        assert _poll(fe, "w1", [key])["placement"] == "own"
+        assert _poll(fe, "w2", [key])["placement"] == "steal"  # early
+        wire = _poll(fe, "w2", [], wait_s=2.0)  # cold: the clock
+        assert wire["placement"] == "steal"
+        fe.queue.enqueue(TENANT, jobs[3])
+        time.sleep(steal_ms / 1e3 + 0.02)
+        assert _poll(fe, "w2", [key])["placement"] == "steal"  # late holder
+        fe.queue.enqueue(TENANT, jobs[4])
+        assert _poll(fe, "w2", [key])["placement"] == "steal"  # early
+        now = TEL.affinity_stats()
+        assert now["warm_steals"] - base["warm_steals"] == 2
+        assert now["jobs"]["steal"] - base["jobs"].get("steal", 0) == 4
+        assert now["jobs"]["own"] - base["jobs"].get("own", 0) == 1
+        assert [j.warm for j in jobs] == [False, True, False, False, True]
+    finally:
+        fe.stop()
+
+
+def test_requeued_job_sheds_its_warm_mark():
+    """A re-dispatched job carries neither its old placement nor its
+    old warm mark into the next dequeue."""
+    q = RequestQueue()
+    j = _job(key="b")
+    j.placement, j.warm = "steal", True
+    q.enqueue(TENANT, j)
+    assert j.placement == "" and j.warm is False
+
+
+def test_batch_extras_ride_a_warm_claim():
+    """Same-key window mates join the lead's claim as before: one warm
+    steal takes them all, each counted as a steal and a warm one."""
+    fe = _dispatcher(affinity_steal_ms=60_000.0)
+    try:
+        _attach(fe, "w1", "w2")
+        key = _keys_by_owner(fe, {"w1"})["w1"]
+        bk = ("search_blocks", TENANT, (key,))
+        mates = [_job(key=key, batch_key=bk) for _ in range(3)]
+        for j in mates:
+            fe.queue.enqueue(TENANT, j)
+        base = TEL.affinity_stats()
+        wire = _poll(fe, "w2", [key])
+        assert wire["kind"] == "multi" and wire["placement"] == "steal"
+        assert len(wire["payload"]["jobs"]) == 3
+        assert all(j.placement == "steal" and j.warm for j in mates)
+        now = TEL.affinity_stats()
+        assert now["warm_steals"] - base["warm_steals"] == 3
+        assert now["jobs"]["steal"] - base["jobs"].get("steal", 0) == 3
+    finally:
+        fe.stop()
+
+
+def test_local_pool_reads_its_own_process_set(monkeypatch):
+    """The local member asks ops/stage what THIS process holds: with the
+    block resident its workers take a remote owner's job at once,
+    without it they leave the job to the clock."""
+    from tempo_tpu.ops import stage
+
+    held: set = set()
+    monkeypatch.setattr(stage, "staged_block_ids", lambda: frozenset(held))
+    fe = Frontend(_StubQuerier(), n_workers=1, affinity=True,
+                  affinity_steal_ms=60_000.0)
+    try:
+        _attach(fe, "w1")
+        keys = [f"blk-{i:03d}" for i in range(64)]
+        remote = [k for k in keys if _owner_of(fe, k) == "w1"]
+        assert len(remote) >= 2
+        # the worker's first pass began while it was the only domain
+        # (the legacy dequeue): end it, the next one builds a claimer
+        first = threading.Event()
+        fe.queue.enqueue(TENANT, _job(fn=first.set))
+        assert first.wait(5.0)
+        ran = threading.Event()
+        cold = _job(key=remote[0], fn=ran.set)
+        fe.queue.enqueue(TENANT, cold)
+        assert not ran.wait(0.3) and cold.placement == ""
+        wire = _poll(fe, "w1")  # the owner takes it
+        assert wire["placement"] == "own"
+        held.add(remote[1])
+        w0 = _warm_steals()
+        warm = _job(key=remote[1], fn=ran.set)
+        # (the worker's current pass still holds the empty set: at most
+        # one dequeue timeout, 1 s, until it reads the new one)
+        fe.queue.enqueue(TENANT, warm)
+        assert ran.wait(5.0)
+        assert warm.placement == "steal" and warm.warm is True
+        assert _warm_steals() == w0 + 1
+    finally:
+        fe.stop()
+
+
+@pytest.mark.parametrize("case", ["single-domain", "affinity-off", "env-off"])
+def test_reported_sets_leave_the_legacy_dequeue_alone(case, monkeypatch):
+    """One cache domain or TEMPO_AFFINITY=0: no claimer is built, the
+    dequeue is the head-of-queue path and nothing is stamped or counted,
+    whatever the polls report."""
+    if case == "env-off":
+        monkeypatch.setenv("TEMPO_AFFINITY", "0")
+    fe = _dispatcher(affinity=False if case == "affinity-off" else
+                     None if case == "env-off" else True)
+    try:
+        workers = ["only"] if case == "single-domain" else ["w1", "w2"]
+        for w in workers:
+            assert _poll(fe, w, ["blk"], wait_s=0.01) is None
+        assert all(fe._claimer(w) is None for w in workers)
+        base = TEL.affinity_stats()
+        jobs = [_job(key=k) for k in ("blk", "other", "blk")]
+        for j in jobs:
+            fe.queue.enqueue(TENANT, j)
+        for j in jobs:  # strict FIFO, no placement
+            wire = _poll(fe, workers[-1], ["blk"])
+            assert wire is not None and wire["placement"] == ""
+            assert j.handed_wall and not j.warm and j.placement == ""
+        now = TEL.affinity_stats()
+        assert now["jobs"] == base["jobs"]
+        assert now["warm_steals"] == base["warm_steals"]
+    finally:
+        fe.stop()
+
+
+def test_warm_steals_sum_over_a_tree():
+    """A tree's /status/kernels adds `affinity.warm_steals` over its
+    instances like the rest of the section."""
+    from tempo_tpu.services.proctree import tree_kernel_status
+
+    def inst(k: int) -> dict:
+        return {"device": {"count": 1},
+                "affinity": {"jobs": {"own": 4 * k, "steal": 2 * k, "unowned": k},
+                             "warm_steals": k,
+                             "staged_by_placement": {
+                                 "steal": {"hits": 9 * k, "misses": k,
+                                           "hit_rate": 0.9}},
+                             "qos_sheds": {}}}
+
+    total = tree_kernel_status(inst(1), [({"index": i}, inst(i + 1))
+                                         for i in (1, 2, 3)])
+    assert total["affinity"]["warm_steals"] == 10
+    assert total["affinity"]["jobs"] == {"own": 40, "steal": 20, "unowned": 10}
+    assert total["affinity"]["staged_by_placement"]["steal"] == {
+        "hits": 90, "misses": 10, "hit_rate": 0.9}
+
+
+def test_dispatch_span_says_warm():
+    """`job:dispatch` keeps placement = stolen and says whether the
+    steal was a warm one; owned and unowned jobs carry no such key."""
+    spans = []
+
+    class _Trace:
+        root_id = b"r"
+
+        def child(self, name, t0, t1, attrs, parent=None, span_id=None):
+            spans.append((name, dict(attrs)))
+            return b"s"
+
+    fe = _dispatcher()
+    try:
+        jobs = []
+        for placement, warm in (("steal", True), ("steal", False),
+                                ("own", False), ("unowned", False)):
+            j = _job(key="b")
+            j.placement, j.warm, j.worker = placement, warm, "w2"
+            j.started_wall, j.handed_wall, j.done_at = 1.0, 1.5, 2.0
+            jobs.append(j)
+        fe._emit_self_trace(jobs, _Trace())
+    finally:
+        fe.stop()
+    disp = [a for n, a in spans if n == "job:dispatch"]
+    assert [(a["placement"], a.get("warm")) for a in disp] == [
+        ("stolen", True), ("stolen", False), ("owner", None), ("unowned", None)]
+
+
+def test_querier_polls_say_what_it_holds(monkeypatch):
+    """A querier reports ops/stage's block ids with every poll, next to
+    `device`, and the frontend's poll route passes them on."""
+    from tempo_tpu.ops import stage
+    from tempo_tpu.services import worker as W
+    from tempo_tpu.transport import client as TC
+
+    monkeypatch.setattr(stage, "staged_block_ids",
+                        lambda: frozenset({"blk-b", "blk-a"}))
+    w = W.QuerierWorker(_StubQuerier(), ["http://frontend.invalid"],
+                        concurrency=1, worker_id="querier-1",
+                        device={"platform": "cpu"})
+    polls = []
+
+    def post(addr, path, payload, timeout):
+        polls.append((path, payload))
+        w.stop()
+
+    w._post = post
+    w._loop("http://frontend.invalid")
+    assert polls == [("/internal/jobs/poll", {
+        "wait_s": w.poll_wait_s, "worker_id": "querier-1",
+        "device": {"platform": "cpu"}, "staged_blocks": ["blk-a", "blk-b"]})]
+
+    fe = _dispatcher()
+    try:
+        app = SimpleNamespace(frontend=fe, cfg=SimpleNamespace(target="all"))
+        payload = dict(polls[0][1], wait_s=0.01)
+        assert TC.handle_internal(app, "/internal/jobs/poll", payload) == (200, {})
+        assert fe._remote_holds["querier-1"] == frozenset({"blk-a", "blk-b"})
+        assert fe.attached_workers() == {"querier-1": {"platform": "cpu"}}
+    finally:
+        fe.stop()
+
+
 # ----------------------------------------------------------- per-tenant QoS
 
 

@@ -450,6 +450,7 @@ def test_dispatch_counters_and_leases_hold_under_threads():
     fe._lease_lock = threading.Lock()
     fe._leases, fe._lease_workers = {}, {}
     fe._remote_workers, fe._remote_devices, fe._lost_at = {}, {}, {}
+    fe._remote_holds = {}
     fe.worker_expiry_s = 60.0
     requeued = []
 
@@ -470,6 +471,7 @@ def test_dispatch_counters_and_leases_hold_under_threads():
             with fe._lease_lock:
                 fe._remote_workers[w] = time.monotonic()
                 fe._remote_devices[w] = {"count": 1}
+                fe._remote_holds[w] = frozenset({jid})
                 fe._leases[jid] = ([("t", _Job("k", {}, None, ()))],
                                    time.monotonic() + 60, [1])
                 fe._lease_workers[jid] = w
@@ -492,6 +494,7 @@ def test_dispatch_counters_and_leases_hold_under_threads():
     # every lease of a lost worker went back to the queue, exactly once
     assert fe._leases == {} and fe._lease_workers == {}
     assert len(requeued) == n_threads * per and fe.attached_workers() == {}
+    assert fe._remote_holds == {}  # what a lost worker held goes with it
     TEL.reset()
 
 

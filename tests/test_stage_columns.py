@@ -317,6 +317,38 @@ def test_budget_eviction_drops_single_columns(pool, monkeypatch):
     assert is_staged(blk, needed)
 
 
+def test_staged_block_ids_follow_admit_and_eviction():
+    """What a querier tells the frontend it holds (services/frontend
+    `_claimer`): a block is in the set from its first admitted column to
+    the eviction of its last, whatever the columns are."""
+    stage.set_staged_cache_budget(1)  # other tests' blocks: out of the way
+    stage.set_staged_cache_budget(4 << 30)
+    _, meta_a, blk_a = _open(seed=41)
+    _, meta_b, blk_b = _open(seed=43)
+    assert not {meta_a.block_id, meta_b.block_id} & stage.staged_block_ids()
+    stage_block(blk_a, ["sattr.key_id"])
+    assert meta_a.block_id in stage.staged_block_ids()
+    assert meta_b.block_id not in stage.staged_block_ids()
+    needed, _ = _shape(blk_b, "attr_eq")
+    view_b = stage_block(blk_b, needed)
+    assert {meta_a.block_id, meta_b.block_id} <= stage.staged_block_ids()
+    # room for b's columns alone: a's one column is the coldest and goes
+    stage.set_staged_cache_budget(sum(a.nbytes for a in view_b.cols.values()))
+    assert not is_staged(blk_a, ["sattr.key_id"]) and is_staged(blk_b, needed)
+    assert meta_a.block_id not in stage.staged_block_ids()
+    assert meta_b.block_id in stage.staged_block_ids()
+    # a part of a block's columns still counts as holding it
+    keys = stage.column_keys(blk_b, needed, None)
+    one = min(view_b.cols[keys[n][0]].nbytes for n in needed)
+    stage.set_staged_cache_budget(one)
+    assert not is_staged(blk_b, needed)
+    assert meta_b.block_id in stage.staged_block_ids()
+    # a block that died leaves the set with its weakref
+    del view_b, blk_b
+    gc.collect()
+    assert meta_b.block_id not in stage.staged_block_ids()
+
+
 def test_concurrent_lookups_keep_the_books():
     """More threads than cores stage overlapping requests of one block
     while the budget flips under them: every view is whole, and
