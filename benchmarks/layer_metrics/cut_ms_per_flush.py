@@ -1,6 +1,8 @@
-"""Host time of one block cut: the `ingest:cut` stage (cut set -> traces, WAL
-rotation, under the instance lock) plus `ingest:flush` (build + write the
-block; the `cut:*` stages nest inside it), per flush in the window."""
+"""Host time of one block cut: the `ingest:cut` stage (the cut snapshot
+decoded to traces) plus `ingest:flush` (build + write the block; the `cut:*`
+stages nest inside it), per flush in the window. Since PR 37 both run
+outside the instance lock, beside the pushes; what a cut still does under
+the lock is `ingest:swap` (`cut_lock_hold_ms`)."""
 from benchmarks.lib import stages
 
 

@@ -1,6 +1,8 @@
 """How unevenly the window's jobs kept the workers busy: the busiest
-worker's busy seconds over the mean (`dispatch.by_worker` of /status/kernels,
-hand-off to result; `local` is the serving process's own threads). 1.0 is an
+worker's busy seconds over the mean (`dispatch.by_worker` of /status/kernels:
+a remote worker's run from hand-off to result, so two queued pulls overlap;
+`local` is the serving process's own threads and, since PR 38, the `seconds`
+of their `run:*` stages). 1.0 is an
 even load; the worker count is the ceiling (one worker did everything)."""
 
 

@@ -146,6 +146,20 @@ class CellPass:
         cl.close()
         return out or {}
 
+    def blocks_compacted(self) -> int | None:
+        """Input blocks the served compactor has merged away so far, by
+        either of its drivers (`/metrics` `tempo_compactor_blocks_compacted_total`:
+        `/status/kernels` `compaction.jobs` counts the pipeline's jobs only,
+        and the default driver is not the pipeline). None where the process
+        runs no compactor."""
+        cl = Client(self.port, timeout=60)
+        status, text = cl.request("GET", "/metrics")
+        cl.close()
+        if status != 200:
+            raise ServerFailure(f"/metrics answered HTTP {status}")
+        return R.metrics_family_total(text.decode("utf-8", "replace"),
+                                      "tempo_compactor_blocks_compacted_total")
+
     def warm_up(self) -> None:
         self.warm = H.warm_up(self.streams, self.mix, self.env, self.port,
                               self.kernels)
@@ -196,11 +210,16 @@ class CellPass:
         if os.path.exists(self.trace_zip):
             os.remove(self.trace_zip)
         self.kernels_before = self.kernels()
+        compacted_before = self.blocks_compacted()
         self.window_unix = time.time()
         extra = [self._capture_trace] if self.trace else []
         self.t0, self.t_end, self.events = H.run_window(
             self.streams, self.mix, self.port, self.seconds, phase, extra)
         self.kernels_after = self.kernels()
+        compacted_after = self.blocks_compacted()
+        self.blocks_compacted_in_window = (
+            None if None in (compacted_before, compacted_after)
+            else compacted_after - compacted_before)
         self.cost_after = self.cost()
 
     # -------------------------------------------------------------- after
@@ -254,6 +273,7 @@ class CellPass:
             "manifest": self.manifest, "setup_s": setup_s,
             "kernels_before": self.kernels_before,
             "kernels_after": self.kernels_after, "cost_after": self.cost_after,
+            "blocks_compacted_in_window": self.blocks_compacted_in_window,
             "selftrace": self.selftraces, "trace": trace,
             "trace_span": self.trace_span,
             "extras": self.extras,
@@ -285,8 +305,10 @@ def read_metrics(bench: dict, cell: dict, group: str, ctx: dict) -> dict:
 
 
 def result_line(problems: list, attempted: int, failed: int, metrics: dict,
-                device: dict, memory_peak_bytes: int, trace: dict | None) -> dict:
-    """The run's last stdout line: the contract's keys and no others."""
+                device: dict, memory_peak_bytes: int, trace: dict | None,
+                checks: dict | None = None) -> dict:
+    """The run's last stdout line: the contract's keys and, last, `checks`:
+    every number `correct` was decided from beside its limit."""
     dev = {"platform": device["platform"], "kind": device["device_kind"],
            "count": device["count"], "memory_peak_bytes": memory_peak_bytes}
     line = {"correct": not problems, "attempted": attempted, "failed": failed,
@@ -296,6 +318,7 @@ def result_line(problems: list, attempted: int, failed: int, metrics: dict,
         dev["window_s"] = trace["window_s"]
         line["breakdown"] = {"device_ops": trace["device_ops"][:10],
                              "idle_gaps": trace["idle_gaps"][:10]}
+    line["checks"] = checks or {}
     return line
 
 
@@ -308,14 +331,25 @@ def summarize(ctx: dict) -> dict:
         for r in st["results"]:
             s = shapes.setdefault(r["op"]["shape"], [])
             s.append(r)
-        out[name] = {
-            sh: {"n": len(rs), "failed": sum(not R.good(r) for r in rs),
-                 **{f"p{int(q * 100)}_ms": stats.percentile(
-                     [(r["t_done"] - r["t_due"]) * 1e3 for r in rs], q)
-                    for q in (0.5, 0.9, 0.99)},
-                 "max_ms": max((r["t_done"] - r["t_send"]) * 1e3 for r in rs)}
-            for sh, rs in shapes.items()}
+        out[name] = {sh: _latencies(rs) for sh, rs in shapes.items()}
     return out
+
+
+def _latencies(rs: list[dict]) -> dict:
+    """One shape's answers of a window, from when each was due: enough
+    percentiles (and the mean) to weigh a statistic's spread between runs
+    without another run."""
+    ms = [(r["t_done"] - r["t_due"]) * 1e3 for r in rs]
+    # an open loop's generator stamps when it let a request go: how late, at
+    # the worst, tells a stall of the generator (or of the host under it)
+    # from one of the server, which `max_ms` (from the send) bounds
+    late = [(r["t_disp"] - r["t_due"]) * 1e3 for r in rs if "t_disp" in r]
+    return {"n": len(rs), "failed": sum(not R.good(r) for r in rs),
+            **{f"p{int(q * 100)}_ms": stats.percentile(ms, q)
+               for q in (0.5, 0.75, 0.9, 0.95, 0.99)},
+            "mean_ms": sum(ms) / len(ms),
+            "max_ms": max((r["t_done"] - r["t_send"]) * 1e3 for r in rs),
+            "late_max_ms": max(late, default=None)}
 
 
 def write_json(path: str, obj) -> None:

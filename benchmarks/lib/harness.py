@@ -67,12 +67,23 @@ class Env:
                     self.manifest["blocks"][block]["oracle"] + "/ids.npy")
             return self._ids[block]
 
+    def blocks(self, tenant: str | None = None) -> list[dict]:
+        """One tenant's entries of the manifest, newest first, each under
+        its own `index` into `manifest["blocks"]`: the tenant named, else
+        the one a request that names none addresses (the corpus's first;
+        every block where the configuration lists no tenants)."""
+        first = self.manifest.get("tenant")
+        tenant = tenant or first
+        return [b for b in self.manifest["blocks"]
+                if b.get("tenant", first) == tenant]
+
     def spans_covered(self, op: dict) -> int:
         """Spans of the blocks a request's start/end covers, by the corpus
-        manifest (not by the server's word)."""
+        manifest (not by the server's word): the blocks of the tenant the
+        request names in `op["tenant"]`, if it names one."""
         if "start" not in op:
             return 0
-        return sum(b["n_spans"] for b in self.manifest["blocks"]
+        return sum(b["n_spans"] for b in self.blocks(op.get("tenant"))
                    if b["start_s"] <= op["end"] and b["end_s"] >= op["start"])
 
 
@@ -298,7 +309,6 @@ def warm_up(streams: dict[str, StreamState], mix: dict, env: Env, port: int,
     """The mix file's warm-up steps, in order. Every answer is checked like
     a window's; the results come back tagged phase='warm'."""
     out: list[dict] = []
-    n_blocks = len(env.manifest["blocks"])
     mix_name = mix["name"]
 
     def one(op, cl):
@@ -312,26 +322,33 @@ def warm_up(streams: dict[str, StreamState], mix: dict, env: Env, port: int,
     for step in mix.get("warmup", []):
         kind = step["step"]
         if kind == "per_block":
-            # each listed shape once over each block, the blocks side by side
+            # each listed shape once over each block (of the tenant the
+            # step names, if any), the blocks side by side: all of them at
+            # once, or `max_parallel` at a time where a long blocklist says so
             rnd = random.Random(f"{env.seed}-{mix_name}-warm")
-            per_block: list[list[dict]] = [[] for _ in range(n_blocks)]
+            indices = [b["index"] for b in env.blocks(step.get("tenant"))]
+            per_block: list[list[dict]] = [[] for _ in indices]
             for spec in step["shapes"]:
                 mod = load_plugin("shapes", spec["shape"])
-                for b in range(n_blocks):
+                for k, b in enumerate(indices):
                     env.force_block = b
                     op = mod.build(rnd, env, spec.get("params", {}))
                     op.update(shape=spec["shape"], i=-1)
-                    per_block[b].append(op)
+                    per_block[k].append(op)
             env.force_block = None
 
-            def block_loop(ops):
+            def block_loop(lists):
                 cl = Client(port, timeout=600)
-                for op in ops:
-                    one(op, cl)
+                for ops in lists:
+                    for op in ops:
+                        one(op, cl)
                 cl.close()
 
-            threads = [threading.Thread(target=block_loop, args=(ops,))
-                       for ops in per_block]
+            n_threads = min(len(per_block),
+                            step.get("max_parallel") or len(per_block))
+            threads = [threading.Thread(target=block_loop,
+                                        args=(per_block[i::n_threads],))
+                       for i in range(n_threads)]
             for t in threads:
                 t.start()
             for t in threads:

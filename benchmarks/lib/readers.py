@@ -5,10 +5,13 @@ leaves the metric out of the line).
 
 ctx keys: seconds, t0, t_end, streams {name: {"spec", "results"}}, config,
 mix, manifest, setup_s, kernels_before, kernels_after, cost_after,
-selftrace (list of traces, each a list of spans) or None, trace (the reduced
-device trace) or None, trace_span (t_start, t_end of the traced interval on
-the client's clock) or None, peaks, extras (what after-window checks
-measured), env.
+blocks_compacted_in_window (the served compactor's, by either of its
+drivers; None where the process runs none), selftrace (list of traces, each
+a list of spans) or None, trace (the reduced device trace) or None,
+trace_span (t_start, t_end of the traced interval on the client's clock) or
+None, extras (what after-window checks measured), device (the server's own
+word: platform, device_kind, count), module_ops (lib/module_ops.json: which
+device modules belong to which op), env.
 """
 
 from __future__ import annotations
@@ -78,6 +81,17 @@ def delta(ctx: dict, *path, source: str = "kernels"):
     if b is None:
         return None
     return b - a
+
+
+def metrics_family_total(text: str, family: str) -> int | None:
+    """Sum of a counter family's samples in a `/metrics` page (labels or
+    none); None where the page does not have the family."""
+    total = None
+    for line in text.splitlines():
+        name, _, rest = line.partition(" ")
+        if name.split("{")[0] == family and rest:
+            total = (total or 0) + int(float(rest.split()[0]))
+    return total
 
 
 def routing_delta(ctx: dict) -> dict:

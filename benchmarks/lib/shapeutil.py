@@ -7,14 +7,16 @@ import json
 import urllib.parse
 
 
-def draw_block(rnd, env) -> int:
-    """Index of a block, by the configuration's popularity (newest first)."""
+def draw_block(rnd, env, tenant=None) -> int:
+    """Index of a block of one tenant (`Env.blocks`: the first, where none
+    is named), by the configuration's popularity (newest first)."""
     if env.force_block is not None:  # warm-up touches each block in turn
         return env.force_block
     pop = env.config["corpus"]["block_popularity"]
-    n = len(env.manifest["blocks"])
+    indices = [b["index"] for b in env.blocks(tenant)]
+    n = len(indices)
     weights = (pop + [pop[-1]] * n)[:n]
-    return rnd.choices(range(n), weights=weights)[0]
+    return rnd.choices(indices, weights=weights)[0]
 
 
 def draw_unique(rnd, env, key, n: int) -> int:
@@ -33,14 +35,22 @@ def draw_unique(rnd, env, key, n: int) -> int:
 
 
 def window(env, block: int) -> dict:
-    """start/end (unix seconds) that select exactly this block: its own
-    first and last second, no padding."""
+    """start/end (unix seconds) that select this block whole and nothing
+    beside its compaction window: the first and last second of the blocks
+    that share the window with it, no padding. With one block a window
+    (the default) that is the block's own first and last second and selects
+    exactly it; where `blocks_per_window` puts several there, every one of
+    them is covered whole and `union` answers for all."""
     b = env.manifest["blocks"][block]
-    return {"start": b["start_s"], "end": b["end_s"]}
+    mates = [m for m in env.blocks(b.get("tenant"))
+             if m.get("window", m["index"]) == b.get("window", b["index"])]
+    return {"start": min(m["start_s"] for m in mates),
+            "end": max(m["end_s"] for m in mates)}
 
 
-def blocks_overlapping(env, start: int, end: int) -> list[int]:
-    return [b["index"] for b in env.manifest["blocks"]
+def blocks_overlapping(env, start: int, end: int, tenant=None) -> list[int]:
+    """Indices of one tenant's blocks (`Env.blocks`) the window overlaps."""
+    return [b["index"] for b in env.blocks(tenant)
             if b["start_s"] <= end and b["end_s"] >= start]
 
 
@@ -59,10 +69,11 @@ def search_ids(status: int, body: bytes):
         return None, f"unreadable answer: {e}"
 
 
-def union(env, start: int, end: int, fn) -> set:
-    """The oracle's answer over every block the window overlaps."""
+def union(env, start: int, end: int, fn, tenant=None) -> set:
+    """The oracle's answer over every block of the tenant that the window
+    overlaps."""
     out: set = set()
-    for b in blocks_overlapping(env, start, end):
+    for b in blocks_overlapping(env, start, end, tenant):
         out |= fn(env.oracle(b))
     return out
 

@@ -1,6 +1,7 @@
 """What the program's one span primitive (kerneltel `TEL.stage`) publishes,
 as the readers of PR 23 take it: the cumulative `stages` table of
-/status/kernels (name -> {count, seconds}; read as the difference of the two
+/status/kernels (name -> {count, seconds and, since PR 38, cpu_seconds:
+`lib/cpu.py` reads that one; each read as the difference of the two
 snapshots around the window) and the same stages as self-trace spans.
 A program without the table or the span (the parent of PR 23) gives None
 everywhere, and the metric is left out of the line."""

@@ -10,7 +10,9 @@ A cell is an entry of `workloads` in BENCHMARK.json: a configuration
 the shapes the mix uses, measures for --seconds from the client's side of
 the HTTP API, checks every answer against a numpy oracle off the clock, and
 prints as the LAST line of stdout one JSON object with the keys `correct`,
-`attempted`, `failed`, `metrics`, `device` (and `breakdown` with --trace 1).
+`attempted`, `failed`, `metrics`, `device` (and `breakdown` with --trace 1),
+then `checks`: each number `correct` was decided from, beside its limit (the
+same as the last lines of stderr).
 Everything else goes to stderr, to earlier stdout lines and to chiprun_out/.
 
 This process never imports jax: the server child owns the chip. A server
@@ -147,26 +149,52 @@ def main(argv=None) -> int:
                 for r in p.warm + judged if not r["ok"]]
     problems += [f"event {e['event']['path']}: HTTP {e['status']}"
                  for e in p.events if not e["ok"]]
+    unanswered = attempted - len(judged)
+    if unanswered:
+        problems.append(f"{unanswered} requests had no answer {H.DRAIN_S} s "
+                        "after the window")
     if rc != 0:
         problems.append(f"server exit code {rc} on SIGTERM")
-    # a window holds no compaction (ROADMAP A12) and, untraced, no compile:
-    # either makes the run's numbers another system's. A traced run compiles
-    # what self-tracing's own pushes reach, and a mix may say why its
-    # programs' sizes follow the traffic (`compiles_allowed`)
-    compactions = R.delta(ctx, "compaction", "jobs")
-    if compactions:
-        problems.append(f"{compactions} compaction jobs ran inside the window")
+    # a window holds no compaction (ROADMAP A12: a `rate()` counts double
+    # after one) and, untraced, no compile: either makes the run's numbers
+    # another system's. Blocks the served compactor merged away count, by
+    # either of its drivers, and the pipeline's own job counter beside them;
+    # a mix whose deployment compacts while it serves says `compactions_allowed`.
+    # A traced run compiles what self-tracing's own pushes reach, and a mix
+    # may say why its programs' sizes follow the traffic (`compiles_allowed`)
+    compactions = max(ctx["blocks_compacted_in_window"] or 0,
+                      R.delta(ctx, "compaction", "jobs") or 0)
+    if compactions and not mix.get("compactions_allowed"):
+        problems.append(f"{compactions} blocks were compacted inside the window")
     compiles = R.delta(ctx, "compile_cache", "disk_misses")
     if compiles and not args.trace and not mix.get("compiles_allowed"):
         problems.append(f"{compiles} programs compiled inside the window")
     on_chip = p.device["platform"] == "tpu"
+    # every number `correct` is decided from, beside its limit: answers are
+    # compared with the oracle one by one and exactly, so every limit is 0
+    # (compactions and compiles have no limit where the mix allows them,
+    # compiles none in a traced run either)
+    bad = [r for r in p.warm + judged if not r["ok"]]
+    checks = {
+        "answers_compared": {"value": len(p.warm) + len(judged), "limit": None},
+        "answers_wrong_or_failed": {"value": len(bad), "limit": 0},
+        "of_them_in_warm_up": {"value": sum(r["phase"] == "warm" for r in bad), "limit": 0},
+        "requests_without_answer": {"value": unanswered, "limit": 0},
+        "events_failed": {"value": sum(not e["ok"] for e in p.events), "limit": 0},
+        "server_exit_code": {"value": rc, "limit": 0},
+        "compactions_in_window": {"value": compactions, "limit": None if (
+            mix.get("compactions_allowed")) else 0},
+        "compiles_in_window": {"value": compiles or 0, "limit": None if (
+            args.trace or mix.get("compiles_allowed")) else 0},
+    }
 
     group = "per_layer" if args.trace else "end_to_end"
     metrics = C.read_metrics(bench, cell, group, ctx)
     peaks = [d.get("peak_bytes_in_use") or 0 for d in
              (ctx["cost_after"].get("hbm", {}).get("per_device_memory_stats") or [])]
     line = C.result_line(problems, attempted, failed, metrics, p.device,
-                         max(peaks, default=0), trace if args.trace else None)
+                         max(peaks, default=0), trace if args.trace else None,
+                         checks)
     info = {
         "workload": cell["name"], "seed": args.seed, "seconds": seconds,
         "trace": args.trace, "setup_s": setup_s,
@@ -197,9 +225,15 @@ def main(argv=None) -> int:
         print("CPU DRY RUN -- not a chip result; metrics a reader could fill: "
               + ", ".join(sorted(metrics)), flush=True)
         line["metrics"] = {}
-        line["cpu_dry_run"] = True
         line.pop("breakdown", None)
+        line = {**{k: v for k, v in line.items() if k != "checks"},
+                "cpu_dry_run": True, "checks": line["checks"]}
     print(json.dumps(line), flush=True)
+    for why in problems[:5]:
+        print(f"not correct: {why[:300]}", file=sys.stderr)
+    for name, c in checks.items():
+        print(f"check {name}: {c['value']} (limit {c['limit']})", file=sys.stderr)
+    sys.stderr.flush()
     return 0 if line["correct"] else 1
 
 

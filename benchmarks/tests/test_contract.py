@@ -105,13 +105,16 @@ def test_last_line_has_the_contracts_keys():
     device = {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1}
     line = result_line([], 10, 0, {"setup_s": {"value": 1.5, "unit": "s"}},
                        device, 123, None)
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device", "checks"]
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     trace = {"devices": [{}], "busy_s": 0.5, "window_s": 8.0,
              "device_ops": [["a", 0.1]], "idle_gaps": [["b", 0.2]]}
-    line = result_line(["x"], 10, 1, {}, device, 123, trace)
+    checks = {"answers_wrong_or_failed": {"value": 1, "limit": 0}}
+    line = result_line(["x"], 10, 1, {}, device, 123, trace, checks)
     assert line["correct"] is False
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device", "breakdown"}
+    assert set(line) == {"correct", "attempted", "failed", "metrics", "device",
+                         "breakdown", "checks"}
+    assert list(line)[-1] == "checks" and line["checks"] == checks  # last, as compared
     assert {"busy_s", "window_s"} <= set(line["device"])
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     json.dumps(line)

@@ -26,3 +26,14 @@ def test_percentiles_of_a_traced_run_leave_out_what_the_profiler_touched():
     assert len(R.untraced(ctx, res)) == 20  # due before 19.5 s
     assert abs(find_p90_ms.read(ctx) - 20.0) < 1e-6
     assert abs(find_p50_ms.read(ctx) - 20.0) < 1e-6
+
+
+def test_a_counter_family_is_summed_from_the_metrics_page():
+    page = ("# TYPE tempo_compactor_runs_total counter\n"
+            "tempo_compactor_runs_total 4\n"
+            "tempo_compactor_blocks_compacted_total 6\n"
+            "tempo_compactor_blocks_compacted_totals 99\n"
+            'tempo_x_total{tenant="a"} 2\ntempo_x_total{tenant="b"} 3.0\n# EOF\n')
+    assert R.metrics_family_total(page, "tempo_compactor_blocks_compacted_total") == 6
+    assert R.metrics_family_total(page, "tempo_x_total") == 5
+    assert R.metrics_family_total(page, "tempo_absent_total") is None
