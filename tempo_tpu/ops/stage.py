@@ -78,8 +78,16 @@ def staged_cache_stats(max_entries: int = 32) -> dict:
             "groups": list(groups) if groups is not None else None,
             "nbytes": int(nbytes),
         })
+    from ..util.kerneltel import TEL
+
+    # evictions / evicted_bytes: cumulative, one a column the LRU popped
+    # to get back under the budget (a column of a dead reader is swept,
+    # not evicted, and counts nowhere)
     return {"entries": len(items), "bytes": int(total),
-            "budget_bytes": int(budget), "hottest": hottest}
+            "budget_bytes": int(budget),
+            "evictions": int(TEL.staged_cache_evictions.get()),
+            "evicted_bytes": int(TEL.staged_cache_evicted_bytes.get()),
+            "hottest": hottest}
 
 
 def staged_block_ids() -> frozenset[str]:
@@ -156,10 +164,15 @@ def _admit(blk, fresh: dict) -> None:
 
 def _evict_over_budget_locked() -> None:
     global _lru_bytes
+    from ..util.kerneltel import TEL
+
     _sweep_dead_locked()  # freed arrays must not force live evictions
+    before = _lru_bytes
+    evicted = 0
     while _lru_bytes > _GLOBAL_CACHE_BUDGET and len(_lru) > 1:
         (_bid, key), (wr, nbytes) = _lru.popitem(last=False)
         _lru_bytes -= nbytes
+        evicted += 1
         blk = wr()
         if blk is None:
             continue
@@ -170,6 +183,9 @@ def _evict_over_budget_locked() -> None:
             # exists here -- park it for the post-lock compress instead
             # of discarding
             _pending_demote.append((block_id, key, arr))
+    if evicted:
+        TEL.staged_cache_evictions.inc(evicted)
+        TEL.staged_cache_evicted_bytes.inc(before - _lru_bytes)
 
 
 def _pool_key(key: tuple) -> tuple:
@@ -190,10 +206,15 @@ def _drain_demotions() -> None:
         _pending_demote.clear()
     if not victims:
         return
+    from ..util.kerneltel import TEL
     from . import chunkpool
 
-    for block_id, key, arr in victims:
-        chunkpool.demote(block_id, _pool_key(key), arr)
+    # the device -> host pull and the codec of every victim, on the thread
+    # of whichever lookup admitted a column (a hit's neighbour pays it)
+    with TEL.stage("stage:demote", columns=len(victims),
+                   bytes=sum(int(arr.nbytes) for _, _, arr in victims)):
+        for block_id, key, arr in victims:
+            chunkpool.demote(block_id, _pool_key(key), arr)
 
 # absolute-seconds origin (2020-01-01 UTC) for the derived trace@gkey_s
 # column: a global trace start time in int32 seconds (valid until 2088)
@@ -411,11 +432,16 @@ def stage_block(
         else:
             cols[key[0]] = arr
             resident.append(key)
+    fresh: dict[tuple, jnp.ndarray] = {}
     if cache:
         reason = "" if not missing else (
             "partial_columns" if cols else "not_staged")
+        # resident / pool / backend: where the lookup's columns are when
+        # it asks -- on the device, in the host chunk pool, or nowhere
+        # (read, assemble, upload); the two on a miss are filled below
         with TEL.stage("stage:lookup", hit=not missing, reason=reason,
-                       missing=len(missing)):
+                       missing=len(missing), resident=len(resident),
+                       pool=0, backend=0) as lookup:
             (TEL.staged_cache_misses if missing else TEL.staged_cache_hits).inc()
             # attribute the lookup to the dequeue placement of the job
             # asking (own/steal/unowned): the affinity scheduler's
@@ -423,22 +449,22 @@ def stage_block(
             TEL.record_staged_lookup(not missing)
             TEL.record_staged_columns(
                 len(resident), len(missing), _lru_touch(blk, resident))
-    fresh: dict[tuple, jnp.ndarray] = {}
-    block_id = getattr(blk.meta, "block_id", "") or ""
-    if cache and missing and block_id:
-        # Tier B probe, per column: a previous HBM eviction may have
-        # demoted it into the host chunk pool -- restaging from there
-        # skips the backend ranged read, the column decode AND the
-        # pad/assemble phase
-        from . import chunkpool
+            block_id = getattr(blk.meta, "block_id", "") or ""
+            if missing and block_id:
+                # Tier B probe, per column: a previous HBM eviction may have
+                # demoted it into the host chunk pool -- restaging from there
+                # skips the backend ranged read, the column decode AND the
+                # pad/assemble phase
+                from . import chunkpool
 
-        pool_keys = {_pool_key(keys[n]): n for n in missing}
-        for pk in pool_keys:
-            chunkpool.note_stage(block_id, pk)
-        for pk, arr in chunkpool.restage(block_id, list(pool_keys)).items():
-            n = pool_keys[pk]
-            fresh[keys[n]] = cols[keys[n][0]] = arr
-            missing.remove(n)
+                pool_keys = {_pool_key(keys[n]): n for n in missing}
+                for pk in pool_keys:
+                    chunkpool.note_stage(block_id, pk)
+                for pk, arr in chunkpool.restage(block_id, list(pool_keys)).items():
+                    n = pool_keys[pk]
+                    fresh[keys[n]] = cols[keys[n][0]] = arr
+                    missing.remove(n)
+                lookup.attrs.update(pool=len(fresh), backend=len(missing))
     glist = _group_list(blk, groups)
     n_res = _n_res(blk, needed)
     if missing:

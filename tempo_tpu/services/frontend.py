@@ -330,7 +330,6 @@ class _Job:
     tries: int = 0
     cancelled: bool = False
     hedged: bool = False
-    enqueued_at: float = 0.0  # monotonic (hedging)
     started_wall: float = 0.0  # wall clock (self-trace spans)
     done_at: float = 0.0  # wall clock
     batch_cv: threading.Condition | None = None
@@ -375,7 +374,8 @@ class _Job:
     lease_redispatched: bool = False  # re-enqueued by lease expiry
     # job dispatch: who had the job in hand ("local" = this process's
     # threads, else the remote querier's id), when (wall clock; for a
-    # remote leg the querier's own stamp of receiving it), and when its
+    # remote leg the querier's own stamp of receiving it; the hedge clock
+    # runs from here, so queue wait never hedges a job), and when its
     # result was posted and merged -- the `job:dispatch` / `job:result`
     # spans of the timeline (the last hand-off, for a hedged job)
     worker: str = ""
@@ -1260,8 +1260,9 @@ class Frontend:
     def _run_jobs(self, tenant: str, jobs: list[_Job], early_exit=None,
                   timeout: float = 60.0) -> None:
         """Enqueue with bounded in-flight jobs, reap completions in ANY
-        order (one slow shard no longer stalls dispatch), hedge jobs
-        stuck past hedge_after_s, and cancel everything at the deadline
+        order (one slow shard no longer stalls dispatch), hedge jobs a
+        worker has had in hand for hedge_after_s where another worker
+        could take the twin, and cancel everything at the deadline
         so late workers see job.cancelled and skip. Every job shares
         one RetryBudget (total retries per QUERY, not per job) and
         carries the wall-clock deadline so remote workers skip jobs
@@ -1284,7 +1285,6 @@ class Frontend:
                 pending = []
             while pending and len(inflight) < self.concurrent_jobs:
                 j = pending.pop(0)
-                j.enqueued_at = time.monotonic()
                 j.started_wall = time.time()
                 self.queue.enqueue(tenant, j)
                 inflight.append(j)
@@ -1299,9 +1299,19 @@ class Frontend:
                     j.finish()  # stamps done_at: the slow job must show
                     # up in self-traces -- it IS the pathology
                 break
-            if self.hedge_after_s > 0:
-                for j in inflight:
-                    if not j.hedged and now - j.enqueued_at > self.hedge_after_s:
+            # a job some worker has had in hand for hedge_after_s gets a
+            # twin if a live cache domain other than that worker's could
+            # take it. The single binary has one domain and never hedges:
+            # a twin there shares the original's interpreter, stage lock
+            # and chip, and a job that is slow because the process is busy
+            # only gets slower
+            late = time.time() - self.hedge_after_s
+            overdue = [j for j in inflight if not j.hedged
+                       and 0 < j.handed_wall < late] if self.hedge_after_s > 0 else []
+            if overdue:
+                domains = {d.instance_id for d in self._affinity_members()}
+                for j in overdue:
+                    if domains - {j.worker}:
                         j.hedged = True  # re-enqueue; first completion wins
                         try:
                             self.queue.enqueue(tenant, j)

@@ -315,7 +315,8 @@ def sum_status(parts: list):
 SUMMED_SECTIONS = ("kernels", "jit_cache", "staging", "routing", "batching",
                    "compile_cache", "staged_cache", "stages",
                    "stages_at_session", "affinity", "hedging", "retries",
-                   "dispatch", "range", "stream", "mesh_batch", "interp")
+                   "dispatch", "range", "stream", "mesh_batch", "interp",
+                   "caching")
 
 
 def tree_kernel_status(own: dict, others: list[tuple[dict, dict]]) -> dict:

@@ -232,6 +232,13 @@ class KernelTelemetry:
         self.staged_cache_misses = Counter(
             "tempo_stage_cache_misses_total",
             help="staged-column device cache misses (uploads)")
+        self.staged_cache_evictions = Counter(
+            "tempo_stage_cache_evictions_total",
+            help="staged columns the device cache's LRU evicted to stay "
+                 "under its byte budget")
+        self.staged_cache_evicted_bytes = Counter(
+            "tempo_stage_cache_evicted_bytes_total",
+            help="device bytes of the staged columns the LRU evicted")
         self.staged_column_hits = Counter(
             "tempo_stage_column_hits_total",
             help="columns a staged-cache lookup found resident on the "
@@ -504,7 +511,8 @@ class KernelTelemetry:
             self.compiles, self.cache_hits, self.device_time,
             self.transfer_bytes, self.staged_rows_real,
             self.staged_rows_padded, self.staged_cache_hits,
-            self.staged_cache_misses, self.staged_column_hits,
+            self.staged_cache_misses, self.staged_cache_evictions,
+            self.staged_cache_evicted_bytes, self.staged_column_hits,
             self.staged_column_misses, self.staged_bytes_reused,
             self.routing,
             self.batch_groups, self.batch_queries,

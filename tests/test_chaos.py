@@ -330,6 +330,9 @@ def test_hedge_telemetry_win(tmp_path):
 
     fe, db = _frontend(tmp_path, hedge_after_s=0.05)
     try:
+        # a second cache domain (a querier that has polled): a twin has
+        # somewhere to go other than the original's worker, as in a tree
+        assert fe.poll_job(wait_s=0.0, worker_id="querier-1") is None
         state = {"calls": 0}
 
         def slow_then_fast():
@@ -346,6 +349,50 @@ def test_hedge_telemetry_win(tmp_path):
         assert TEL.hedge_stats().get("win", 0) >= 1
         assert "hedging" in TEL.snapshot()
         assert any("tempo_hedge_total" in ln for ln in TEL.metrics_lines())
+    finally:
+        fe.stop()
+        db.close()
+
+
+@pytest.mark.parametrize("domains", ["one_process", "two_workers"])
+def test_a_twin_needs_another_worker_and_a_job_in_hand(tmp_path, domains):
+    """The hedge clock runs from the hand-off, not from the enqueue, and a
+    twin is enqueued only where a worker other than the original's could
+    take it: in a one-process frontend a job older than hedge_after_s gets
+    no twin while it is queued behind another and none while its only
+    worker runs it; with a second cache domain it gets one, once its
+    worker has had it for hedge_after_s."""
+    from tempo_tpu.services.frontend import _Job
+
+    fe, db = _frontend(tmp_path, n_workers=1, hedge_after_s=0.1)
+    try:
+        if domains == "two_workers":
+            assert fe.poll_job(wait_s=0.0, worker_id="querier-1") is None
+        before = sum(TEL.hedge_stats().values())
+        seen = {}
+
+        def first():
+            time.sleep(0.5)  # holds the one worker: `second` waits queued
+            seen["second_queued"] = (second.hedged, second.handed_wall)
+            return "a"
+
+        def work():
+            time.sleep(0.5)
+            return "b"
+
+        head = _Job(kind="search_blocks", payload={}, fn=first, args=())
+        second = _Job(kind="search_blocks", payload={}, fn=work, args=())
+        fe._run_jobs("t", [head, second], timeout=10.0)
+        assert (head.result, second.result) == ("a", "b")
+        # 0.5 s in the queue, five times hedge_after_s: no twin, either way
+        assert seen["second_queued"] == (False, 0.0)
+        assert second.exec_seq == 1  # nothing ran it a second time
+        hedges = sum(TEL.hedge_stats().values()) - before
+        if domains == "one_process":
+            assert not head.hedged and not second.hedged and hedges == 0
+        else:
+            assert head.hedged and second.hedged and hedges == 2
+            assert second.hedge_outcome == "unneeded"  # the original won
     finally:
         fe.stop()
         db.close()
