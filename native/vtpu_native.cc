@@ -1246,6 +1246,63 @@ void vtpu_span_metrics(const int32_t* sid, const float* dur, int64_t n,
   }
 }
 
+// ------------------------------------------------- slot-major placement
+
+// One generic-attribute value column of a staged slice, placed
+// slot-major in one pass (ops/stage._SlotLayout.place): `out` is K
+// planes of n_spans_b -- plane j holds every span's j-th row -- and
+// then the rows beyond a span's K-th in row order (the overflow).
+// Rows are grouped by owner in ascending span order; an owner outside
+// [span_base, span_base + n_spans) belongs to the edge span. Every
+// element of `out` is written exactly once: a row's value, or `pad`
+// where no row lands (a span's missing slots, the spans after n_spans,
+// the overflow's tail). Elements are 4 bytes whatever their type.
+// Returns the overflow rows written, or -1 (out is then unspecified,
+// the caller places with numpy) when owners descend or the arguments
+// do not fit `out`.
+int64_t vtpu_slot_place_u32(const int32_t* owners, int64_t n_rows,
+                            int64_t span_base, int64_t n_spans,
+                            int64_t n_spans_b, int64_t k,
+                            const uint32_t* src, uint32_t* out,
+                            int64_t out_len, uint32_t pad) {
+  if (k < 0 || n_spans_b < 1 || n_spans > n_spans_b ||
+      k * n_spans_b > out_len)
+    return -1;
+  const int64_t hi = n_spans > 0 ? n_spans - 1 : 0;
+  uint32_t* tail = out + k * n_spans_b;
+  const int64_t tail_len = out_len - k * n_spans_b;
+  int64_t n_over = 0;
+  // the span whose run is open and the rows of it seen: plane j is
+  // written up to (excluding) span cur + 1 where j < slot, else cur
+  int64_t cur = 0, slot = 0;
+  auto pad_until = [&](int64_t span) {
+    for (int64_t j = 0; j < k; j++) {
+      uint32_t* plane = out + j * n_spans_b;
+      std::fill(plane + (j < slot ? cur + 1 : cur), plane + span, pad);
+    }
+  };
+  for (int64_t i = 0; i < n_rows; i++) {
+    int64_t o = (int64_t)owners[i] - span_base;
+    o = o < 0 ? 0 : (o > hi ? hi : o);
+    if (o != cur) {
+      if (o < cur) return -1;
+      pad_until(o);
+      cur = o;
+      slot = 0;
+    }
+    if (slot < k) {
+      out[slot * n_spans_b + o] = src[i];
+    } else {
+      if (n_over >= tail_len) return -1;
+      tail[n_over++] = src[i];
+    }
+    slot++;
+  }
+  pad_until(n_spans_b);
+  std::fill(tail + n_over, tail + tail_len, pad);
+  return n_over;
+}
+
 // ------------------------------------------------------- dictionary union
 
 // K-way merge of K SORTED string tables (compaction's dictionary union,

@@ -128,6 +128,12 @@ def _bind(lib):
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
         ctypes.c_int64, ctypes.c_void_p,
     ]
+    lib.vtpu_slot_place_u32.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_uint32,
+    ]
+    lib.vtpu_slot_place_u32.restype = ctypes.c_int64
     lib.vtpu_seg_weighted_count.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
@@ -639,6 +645,28 @@ def seg_weighted_count(mask: np.ndarray, weights: np.ndarray,
                                 span_off.ctypes.data, n_traces, n_spans,
                                 out.ctypes.data)
     return out
+
+
+def slot_place(owners: np.ndarray, span_base: int, n_spans: int, n_spans_b: int,
+               k: int, src: np.ndarray, out: np.ndarray, pad) -> bool:
+    """One generic-attribute value column placed slot-major into `out`
+    (k planes of n_spans_b, then the overflow rows; every element
+    written once, `pad` where no row lands): the native pass of
+    ops/stage._SlotLayout.place, GIL released for its length. `owners`
+    are the rows' span rows, ascending; `src` and `out` share a 4-byte
+    dtype. False -> `out` holds nothing to keep and the caller places
+    with numpy."""
+    lib = _load()
+    if (lib is None or owners.dtype != np.int32 or src.dtype.itemsize != 4
+            or out.dtype != src.dtype or src.ndim != 1 or out.ndim != 1
+            or src.shape != owners.shape
+            or not (owners.flags.c_contiguous and src.flags.c_contiguous
+                    and out.flags.c_contiguous)):
+        return False
+    pad_bits = int(np.asarray(pad, dtype=src.dtype).view(np.uint32))
+    return lib.vtpu_slot_place_u32(
+        owners.ctypes.data, owners.shape[0], span_base, n_spans, n_spans_b, k,
+        src.ctypes.data, out.ctypes.data, out.shape[0], pad_bits) >= 0
 
 
 def lex_bisect16(ids: np.ndarray, queries: np.ndarray) -> np.ndarray | None:

@@ -64,11 +64,15 @@ def bucket(n: int) -> int:
 
 
 def pad_rows(arr: np.ndarray, n: int, fill) -> np.ndarray:
-    """Pad axis 0 to n rows with `fill`."""
-    if arr.shape[0] == n:
+    """Pad axis 0 to n rows with `fill` (`arr` itself when it has n):
+    one allocation, the rows copied once, the tail filled once."""
+    rows = arr.shape[0]
+    if rows == n:
         return arr
-    pad_shape = (n - arr.shape[0],) + arr.shape[1:]
-    return np.concatenate([arr, np.full(pad_shape, fill, dtype=arr.dtype)])
+    out = np.empty((n,) + arr.shape[1:], dtype=arr.dtype)
+    out[:rows] = arr
+    out[rows:] = fill
+    return out
 
 
 def pad_columns(

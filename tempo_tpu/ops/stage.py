@@ -15,7 +15,7 @@ res- and rattr-axis columns and `trace@gkey_s` do not depend on the
 range and key on None, so row-group shards and whole-block requests
 share them. `column_keys` is the one place a request name becomes a
 device name. A slice's generic span-attribute columns (`sattr.*`) reach
-the device slot-major (`_assemble`): one array a column, `K` planes of
+the device slot-major (`_SlotLayout`): one array a column, `K` planes of
 `n_spans_b` (plane `j` = every span's `j`-th attribute row) and then
 the rows beyond a span's `K`-th, whose owners are `sattr.over`; the
 kernel ORs over the planes and scatters only the overflow rows
@@ -34,7 +34,7 @@ from __future__ import annotations
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from functools import lru_cache
+from functools import cached_property, lru_cache
 
 import jax
 import jax.numpy as jnp
@@ -57,8 +57,8 @@ _lru_bytes = 0
 
 # HBM-evicted columns awaiting demotion into the host chunk pool
 # (ops/chunkpool): collected under _lru_lock, compressed OUTSIDE it --
-# the D2H pull + codec work is milliseconds, the lock guards
-# microsecond bookkeeping
+# the D2H pull + codec work is ~0.14 s a column at the cells' sizes
+# (`demote_ms_per_eviction`), the lock guards microsecond bookkeeping
 _pending_demote: list[tuple[str, tuple, object]] = []
 
 
@@ -366,6 +366,92 @@ def _head_planes(cnt: np.ndarray, n_rows: int, n_spans_b: int) -> int:
     return min(int(cnt.max()), bucket(max(n_rows, 1)) // n_spans_b)
 
 
+class _SlotLayout:
+    """Where the generic-attribute rows of one staged slice go, worked
+    out once a miss from the slice's per-span counts and shared by every
+    value column. Slot-major: one array a value column -- K planes of
+    n_spans_b, plane j holding every span's j-th attribute row and
+    PAD_I32 where a span has fewer (a PAD key matches no key code, so an
+    empty slot can never hit), then the overflow rows (a span's rows
+    from its K-th on, in row order) padded to their bucket, their owners
+    in `over_owners` (padded with n_spans_b = no span: the kernel drops
+    it). The kernel ORs over the planes and scatters only the overflow
+    (ops/filter._cond_mask): no cumsum over attribute rows, no
+    span-length gather. Rows are grouped by owner in span order (the
+    j-th row of a span's run is slot j); a row whose owner lies outside
+    the slice belongs to the edge span."""
+
+    def __init__(self, owners: np.ndarray, span_base: int, n_spans: int,
+                 n_spans_b: int):
+        self.owners, self.span_base, self.n_spans = owners, span_base, n_spans
+        self.n_spans_b = n_spans_b
+        self.n_rows = int(owners.shape[0])
+        hi = max(n_spans, 1) - 1
+        rel = owners - span_base
+        self.cnt = cnt = np.bincount(np.clip(rel, 0, hi, out=rel), minlength=hi + 1)
+        self.k = k = _head_planes(cnt, self.n_rows, n_spans_b)
+        # the spans with rows beyond their K-th, and how many each
+        self._long = long = np.flatnonzero(cnt > k)
+        self._extra = extra = cnt[long] - k
+        self.n_over = int(extra.sum())
+        self.over_b = bucket(self.n_over) if self.n_over else 0
+        self.over_owners = np.repeat(long, extra).astype(np.int32)
+        self.numpy_columns = 0  # value columns the native pass did not place
+
+    def place(self, arr: np.ndarray) -> np.ndarray:
+        """One value column in the slice's layout: every element of the
+        result written once."""
+        from .. import native
+
+        out = np.empty(self.k * self.n_spans_b + self.over_b, dtype=arr.dtype)
+        pad = arr.dtype.type(PAD_I32)
+        if not native.slot_place(self.owners, self.span_base, self.n_spans,
+                                 self.n_spans_b, self.k, arr, out, pad):
+            self.numpy_columns += 1
+            self._place_numpy(arr, out, pad)
+        return out
+
+    @cached_property
+    def _starts(self) -> np.ndarray:
+        return np.cumsum(self.cnt) - self.cnt
+
+    @cached_property
+    def _plane_rows(self) -> list[tuple]:
+        """Per plane j: (the spans that own a j-th row, or None when
+        every span does; the rows that hold them)."""
+        planes, fewest = [], int(self.cnt.min())
+        for j in range(self.k):
+            if fewest > j:
+                planes.append((None, self._starts + j))
+            else:
+                has = np.flatnonzero(self.cnt > j)
+                planes.append((has, self._starts[has] + j))
+        return planes
+
+    @cached_property
+    def _over_rows(self) -> np.ndarray:
+        """The overflow rows, in row order."""
+        first = np.cumsum(self._extra) - self._extra
+        return (np.repeat(self._starts[self._long] + self.k - first, self._extra)
+                + np.arange(self.n_over))
+
+    def _place_numpy(self, arr, out, pad) -> None:
+        """place() without the library, and its twin in the tests: plane
+        by plane from the n_spans-long starts."""
+        n, nsb = self.cnt.shape[0], self.n_spans_b
+        for j, (has, rows) in enumerate(self._plane_rows):
+            plane = out[j * nsb:(j + 1) * nsb]
+            if has is None:
+                np.take(arr, rows, out=plane[:n], mode="clip")
+            else:
+                plane[:n] = pad
+                plane[has] = arr[rows]
+            plane[n:] = pad
+        tail = out[self.k * nsb:]
+        tail[:self.n_over] = arr[self._over_rows]
+        tail[self.n_over:] = pad
+
+
 @lru_cache(maxsize=1024)
 def _device_column(name: str) -> tuple[str, bool, str | None]:
     """What column_keys knows from a request name alone -> (device
@@ -480,7 +566,7 @@ def stage_block(
         # nothing to read or upload: assembling the view is all the
         # staging this request costs, and it is timed as that (a traced
         # search's staging time then reads ~0 rather than not at all)
-        with TEL.stage("stage:assemble", block=blk.meta.block_id[:8]):
+        with TEL.stage("stage:assemble", block=blk.meta.block_id[:8], rows=0):
             view = _dims(blk, glist, n_res)
             view.cols = cols
     if fresh and cache:
@@ -525,8 +611,8 @@ def assemble_stage(blk: BackendBlock, plan: StagePlan, groups: list[int],
     bucket padding. Pure host numpy -- no IO, no device."""
     from ..util.kerneltel import TEL
 
-    with TEL.stage("stage:assemble", block=blk.meta.block_id[:8]):
-        return _assemble(blk, plan, groups, host, n_res)
+    with TEL.stage("stage:assemble", block=blk.meta.block_id[:8]) as span:
+        return _assemble(blk, plan, groups, host, n_res, span.attrs)
 
 
 def _dims(blk: BackendBlock, groups: list[int], n_res: int) -> StagedBlock:
@@ -548,7 +634,9 @@ def _dims(blk: BackendBlock, groups: list[int], n_res: int) -> StagedBlock:
     )
 
 
-def _assemble(blk, plan, groups, host, n_res):
+def _assemble(blk, plan, groups, host, n_res, attrs: dict | None = None):
+    """-> (the slice's dims, the padded host columns, their real rows);
+    `attrs` (the `stage:assemble` span's) is told what was placed."""
     host = dict(host)  # owner-offset transforms mutate; callers may retry
     staged = _dims(blk, groups, n_res)
     span_base, n_spans = staged.span_base, staged.n_spans
@@ -562,37 +650,14 @@ def _assemble(blk, plan, groups, host, n_res):
     # rows of every child table are grouped by owner; the owner row
     # columns themselves never need to reach the device
     real_rows: dict[str, int] = {}  # pre-padding lengths (telemetry)
+    layout = None
     if "sattr.span" in host:
-        # slot-major: one array a value column -- K planes of n_spans_b,
-        # plane j holding every span's j-th attribute row and PAD_I32
-        # where a span has fewer (a PAD key matches no key code, so an
-        # empty slot can never hit), then the overflow rows (a span's
-        # rows from its K-th on) padded to their bucket, their owners in
-        # `sattr.over` (n_spans_b = no span: the kernel drops it). The
-        # kernel ORs over the planes and scatters only the overflow
-        # (ops/filter._cond_mask): no cumsum over attribute rows, no
-        # span-length gather.
-        owners = np.clip(host.pop("sattr.span") - span_base, 0, max(n_spans, 1) - 1)
-        cnt = np.bincount(owners, minlength=max(n_spans, 1))
-        n_rows = int(owners.shape[0])
-        k = _head_planes(cnt, n_rows, n_spans_b)
-        # where each row goes: its span's place in the plane of its slot
-        # (rows are grouped by owner: the j-th row of a span is slot j),
-        # or the next free overflow row
-        slot = np.arange(n_rows, dtype=np.int32) - np.repeat(
-            (np.cumsum(cnt) - cnt).astype(np.int32), cnt)
-        dest = slot.astype(np.intp) * n_spans_b + owners
-        over = np.flatnonzero(slot >= k)
-        n_over = int(over.shape[0])
-        over_b = bucket(n_over) if n_over else 0
-        dest[over] = k * n_spans_b + np.arange(n_over)
+        layout = _SlotLayout(host.pop("sattr.span"), span_base, n_spans, n_spans_b)
         for name in [n for n in host if n.startswith("sattr.")]:
-            arr = host[name]
-            host[name] = np.full(k * n_spans_b + over_b, PAD_I32, dtype=arr.dtype)
-            host[name][dest] = arr
-            real_rows[name] = n_rows
-        real_rows["sattr.over"] = n_over
-        host["sattr.over"] = pad_rows(owners[over], over_b, n_spans_b)
+            host[name] = layout.place(host[name])
+            real_rows[name] = layout.n_rows
+        real_rows["sattr.over"] = layout.n_over
+        host["sattr.over"] = pad_rows(layout.over_owners, layout.over_b, n_spans_b)
     if "rattr.res" in host:
         owners = np.clip(host["rattr.res"], 0, max(n_res, 1) - 1)
         cnt = np.bincount(owners, minlength=max(n_res, 1)) if owners.size else np.zeros(
@@ -637,6 +702,19 @@ def _assemble(blk, plan, groups, host, n_res):
     # complete the per-column real (pre-padding) row counts for the
     # upload phase's padding-waste telemetry
     real_full = {n: real_rows.get(n, int(host[n].shape[0])) for n in padded}
+    if attrs is not None:
+        from .. import native
+
+        # rows / planes / overflow_rows: the generic-attribute rows placed
+        # slot-major, the K they got and the rows beyond it; path: what
+        # placed them (`numpy` once any column took the fallback)
+        rows, planes, n_over, fell_back = (
+            (layout.n_rows, layout.k, layout.n_over, layout.numpy_columns)
+            if layout else (0, 0, 0, 0))
+        attrs.update(
+            rows=rows, planes=planes, overflow_rows=n_over, columns=len(padded),
+            bytes=sum(int(a.nbytes) for a in padded.values()),
+            path="native" if native.available() and not fell_back else "numpy")
     return staged, padded, real_full
 
 

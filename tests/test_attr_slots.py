@@ -6,8 +6,11 @@ rows behind `sattr.off` and with the numpy twin over raw columns
 (ops/hostfilter) on the trace mask and on every trace's matched-span
 count, for every op and value kind; `{ span.k = v } | rate()` folds the
 same span mask. And at the benchmark's buckets the slot-major programs
-hold no span-length gather, scatter or cumsum. CPU, tiny data; the one
-real-size test traces abstract values only."""
+hold no span-length gather, scatter or cumsum. And the placement itself
+(ops/stage._SlotLayout, native pass and numpy fallback alike) stages the
+bytes the per-row scatter it replaced staged, which is kept here as its
+independent twin. CPU, tiny data; the one real-size test traces abstract
+values only."""
 
 from __future__ import annotations
 
@@ -17,8 +20,9 @@ import jax
 import numpy as np
 import pytest
 
+from tempo_tpu import native
 from tempo_tpu.ops import stage
-from tempo_tpu.ops.device import PAD_I32
+from tempo_tpu.ops.device import PAD_I32, bucket, pad_rows
 from tempo_tpu.ops.filter import (
     OPS, T_SATTR, T_SPAN, T_TRACE, Cond, Operands, _compiled, eval_block,
     normalize_tree,
@@ -240,6 +244,154 @@ def test_each_launch_counts_its_reduction(monkeypatch):
     launch(query, {**flat, "sattr.span": owners}, st)
     assert rows().get(("offsets", "no_offsets"), 0) - before.get(
         ("offsets", "no_offsets"), 0) == 1
+
+
+# ------------------------------------------- the placement, byte for byte
+
+
+def _scatter_reference(raw_owners, span_base, n_spans, n_spans_b, cols):
+    """The placement as ops/stage._assemble did it until PR 43 -- a
+    destination for every row, one fancy-index scatter a column -- ->
+    (staged sattr.* columns and `sattr.over`, K, overflow rows)."""
+    owners = np.clip(raw_owners - span_base, 0, max(n_spans, 1) - 1)
+    cnt = np.bincount(owners, minlength=max(n_spans, 1))
+    n_rows = int(owners.shape[0])
+    k = min(int(cnt.max()), bucket(max(n_rows, 1)) // n_spans_b)
+    slot = np.arange(n_rows, dtype=np.int32) - np.repeat(
+        (np.cumsum(cnt) - cnt).astype(np.int32), cnt)
+    dest = slot.astype(np.intp) * n_spans_b + owners
+    over = np.flatnonzero(slot >= k)
+    n_over = int(over.shape[0])
+    over_b = bucket(n_over) if n_over else 0
+    dest[over] = k * n_spans_b + np.arange(n_over)
+    out = {}
+    for name, arr in cols.items():
+        out[name] = np.full(k * n_spans_b + over_b, PAD_I32, dtype=arr.dtype)
+        out[name][dest] = arr
+    out["sattr.over"] = np.concatenate(
+        [owners[over], np.full(over_b - n_over, n_spans_b, dtype=owners.dtype)])
+    return out, k, n_over
+
+
+def _placement_case(name: str):
+    """-> (span rows a row group, the groups staged, raw `sattr.span`,
+    value columns): counts per span drawn for the case, owners as the
+    block stores them (global span rows, grouped by owner in span order)."""
+    rng = np.random.default_rng(43)
+    group_spans, groups = [1500], [0]
+    cnt = np.full(1500, 2)
+    if name == "geometric":  # a long tail: rows beyond K overflow
+        cnt = rng.geometric(0.4, 1500) - 1
+    elif name == "one_long_span":
+        cnt[700] = 40
+    elif name == "k0":  # attributes rarer than spans: every row overflows
+        cnt = (rng.random(1500) < 0.2).astype(np.int64)
+    elif name == "no_rows":
+        cnt = np.zeros(1500, np.int64)
+    elif name in ("row_group_range", "clipped_edges"):
+        group_spans, groups = [400, 900, 300, 600], [1, 2]
+        cnt = rng.integers(0, 5, sum(group_spans))
+    owners = np.repeat(np.arange(cnt.shape[0]), cnt).astype(np.int32)
+    if name == "row_group_range":
+        owners = owners[(owners >= 400) & (owners < 1600)]
+    elif name == "clipped_edges":  # rows of the spans on either side too
+        owners = owners[(owners >= 390) & (owners < 1612)]
+    n_rows = owners.shape[0]
+    cols = {"sattr.key_id": rng.integers(0, 9, n_rows).astype(np.int32),
+            "sattr.int32": rng.integers(-_I32_MAX, _I32_MAX, n_rows).astype(np.int32)}
+    if name == "float32":
+        cols["sattr.f32"] = rng.normal(size=n_rows).astype(np.float32)
+    return group_spans, groups, owners, cols
+
+
+PLACEMENT_CASES = ["uniform", "geometric", "one_long_span", "k0", "no_rows",
+                   "row_group_range", "clipped_edges", "float32"]
+
+
+def _place(case: str, path: str, monkeypatch):
+    """The case through ops/stage._assemble on the native pass or the
+    numpy fallback -> (padded columns, real rows, the span's attributes,
+    the twin's result)."""
+    if path == "native" and not native.available():
+        pytest.skip("native library not built")
+    if path == "numpy":
+        monkeypatch.setattr(native, "slot_place", lambda *a: False)
+    group_spans, groups, owners, cols = _placement_case(case)
+    offsets = np.concatenate([[0], np.cumsum(group_spans)]).tolist()
+    blk = SimpleNamespace(
+        pack=SimpleNamespace(axes={"span": SimpleNamespace(
+            offsets=offsets, n_groups=len(group_spans), n_rows=offsets[-1])}),
+        meta=SimpleNamespace(total_traces=7, block_id="placement-case"))
+    host = {"sattr.span": owners, **cols}
+    attrs: dict = {}
+    view, padded, real_rows = stage._assemble(
+        blk, stage.plan_stage(list(host)), groups, host, 0, attrs)
+    assert host["sattr.span"] is owners and set(host) == {"sattr.span", *cols}
+    assert attrs["path"] == path and attrs["columns"] == len(padded)
+    assert attrs["bytes"] == sum(a.nbytes for a in padded.values())
+    want = _scatter_reference(owners, view.span_base, view.n_spans,
+                              view.n_spans_b, cols)
+    return padded, real_rows, attrs, want
+
+
+@pytest.mark.parametrize("path", ["native", "numpy"])
+@pytest.mark.parametrize("case", PLACEMENT_CASES)
+def test_placement_equals_the_row_scatter(case, path, monkeypatch):
+    """Value columns, `sattr.over`, K and the real row counts of both
+    paths against the per-row scatter, to the byte."""
+    padded, real_rows, attrs, (want, k, n_over) = _place(case, path, monkeypatch)
+    assert set(padded) == set(want)
+    n_rows = _placement_case(case)[2].shape[0]
+    assert (attrs["rows"], attrs["planes"], attrs["overflow_rows"]) == (n_rows, k, n_over)
+    assert (k == 0) == (case in ("k0", "no_rows"))
+    assert (n_over > 0) == (case in ("geometric", "one_long_span", "k0",
+                                     "row_group_range", "clipped_edges"))
+    for name, ref in want.items():
+        got = padded[name]
+        assert (got.dtype, got.shape) == (ref.dtype, ref.shape), name
+        assert got.tobytes() == ref.tobytes(), name
+        assert real_rows[name] == (n_over if name == "sattr.over" else n_rows)
+
+
+@pytest.mark.parametrize("case", PLACEMENT_CASES)
+def test_native_pass_equals_numpy_fallback(case, monkeypatch):
+    """One layout, each value column through the native pass and through
+    the fallback (the library absent: skipped, not failed)."""
+    if not native.available():
+        pytest.skip("native library not built")
+    group_spans, groups, owners, cols = _placement_case(case)
+    base = sum(group_spans[:groups[0]])
+    n_spans = sum(group_spans[g] for g in groups)
+    layout = stage._SlotLayout(owners, base, n_spans, bucket(n_spans))
+    for name, arr in cols.items():
+        got = layout.place(arr)
+        twin = np.empty_like(got)
+        layout._place_numpy(arr, twin, arr.dtype.type(PAD_I32))
+        assert got.tobytes() == twin.tobytes(), name
+    assert layout.numpy_columns == 0
+    # descending owners are not a block's: the pass refuses, numpy places
+    if owners.shape[0] > 1 and owners[0] != owners[-1]:
+        arr = cols["sattr.key_id"]
+        out = np.empty(layout.k * layout.n_spans_b + layout.over_b, np.int32)
+        assert not native.slot_place(owners[::-1].copy(), base, n_spans,
+                                     layout.n_spans_b, layout.k, arr, out, PAD_I32)
+
+
+@pytest.mark.parametrize("fill", [PAD_I32, 0, -1, np.int32(7), np.float32(0), False],
+                         ids=repr)
+@pytest.mark.parametrize("shape", [(0,), (5,), (5, 3), (8,), (8, 2)], ids=str)
+def test_pad_rows_keeps_its_results(shape, fill):
+    """ops/device.pad_rows against the concatenate it was: an array that
+    already has n rows comes back itself; 1-D and 2-D; scalar fills."""
+    dtype = (np.float32 if isinstance(fill, np.floating)
+             else bool if fill is False else np.int32)
+    arr = (np.arange(int(np.prod(shape))).reshape(shape) % 5).astype(dtype)
+    got = pad_rows(arr, 8, fill)
+    assert (got is arr) == (shape[0] == 8)
+    want = np.concatenate([arr, np.full((8 - shape[0],) + shape[1:], fill, dtype)])
+    assert (got.dtype, got.shape) == (want.dtype, want.shape)
+    assert got.tobytes() == want.tobytes()
+    assert got.flags.c_contiguous
 
 
 # ------------------------------------------------ the cells' shapes, abstract

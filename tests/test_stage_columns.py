@@ -664,3 +664,51 @@ def test_slot_major_columns_come_back_from_the_pool(monkeypatch):
         again = stage_block(blk, _ATTR_NEEDED, SHARD)
         assert not reads and chunkpool.stats()["hits"] - d0["hits"] == len(gone)
         _assert_same(again, view)
+
+
+def _assemble_spans(body) -> list[dict]:
+    """The attributes of every `stage:assemble` span `body` records in a
+    self-trace, in order."""
+    from tempo_tpu.services.selftrace import SelfTracer
+
+    shipped: list = []
+    tracer = SelfTracer(push=lambda tenant, rs: shipped.append(rs))
+    with tracer.trace("frontend.search", {"tenant": TENANT}) as t:
+        tok = TEL.set_active_trace(t)
+        try:
+            body()
+        finally:
+            TEL.reset_active_trace(tok)
+    tracer.flush()
+    spans = [sp for rs_list in shipped for rs in rs_list
+             for ss in rs.scope_spans for sp in ss.spans
+             if sp.name == "stage:assemble"]
+    return [dict(sp.attrs) for sp in sorted(spans, key=lambda sp: sp.start_unix_nano)]
+
+
+def test_assemble_span_says_what_it_placed():
+    """A miss's `stage:assemble` span carries the generic-attribute rows
+    it placed, their planes, the overflow rows, the columns and bytes it
+    padded and which path placed them; a miss that stages no attribute
+    column places none; a full hit's span carries rows=0 and nothing else
+    of them."""
+    from tempo_tpu import native
+
+    blk = _open_skewed()
+    _, owners, _ = _slice_rows(blk, SHARD)
+    views = []
+    miss, later, hit = _assemble_spans(lambda: views.extend([
+        stage_block(blk, _ATTR_NEEDED, SHARD),
+        stage_block(blk, _ATTR_NEEDED + ["span.dur_us"], SHARD),
+        stage_block(blk, _ATTR_NEEDED, SHARD)]))
+    view = views[0]
+    n_over = int(np.count_nonzero(np.asarray(view.cols["sattr.over"]) < view.n_spans_b))
+    k = (view.cols["sattr.key_id"].shape[0] - view.cols["sattr.over"].shape[0]) // view.n_spans_b
+    assert n_over > 0 and k > 0
+    assert (miss["rows"], miss["planes"], miss["overflow_rows"]) == (owners.shape[0], k, n_over)
+    assert miss["path"] == ("native" if native.available() else "numpy")
+    assert miss["columns"] == len(view.cols)
+    assert miss["bytes"] == sum(int(a.nbytes) for a in view.cols.values())
+    assert (later["rows"], later["planes"], later["overflow_rows"], later["columns"]) == (0, 0, 0, 1)
+    assert later["bytes"] == views[1].cols["span.dur_us"].nbytes and later["path"] == miss["path"]
+    assert hit["rows"] == 0 and not {"planes", "overflow_rows", "path", "bytes"} & set(hit)
